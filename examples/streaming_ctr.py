@@ -46,8 +46,8 @@ def main() -> None:
         chunk_rows=1 << 15, label_in_chunk=True, step_size=0.05,
         # defer_epoch1: the streaming pass is pure ingest and ALL epochs
         # train inside the fused replay program — bit-identical to the
-        # interleaved schedule, but zero per-chunk step dispatches (each
-        # costs ~an RTT on tunneled hosts). replay_granularity='epoch'
+        # interleaved schedule, but zero per-chunk step dispatches.
+        # replay_granularity='epoch'
         # (one dispatch per epoch) additionally composes with a
         # StreamCheckpointer for kill-and-resume at epoch boundaries.
         defer_epoch1=True,

@@ -103,21 +103,19 @@ class TpuSession:
             jax.distributed.initialize(**kwargs)
 
     @staticmethod
-    def enable_compilation_cache(cache_dir: str | None = None) -> dict:
+    def enable_compilation_cache() -> dict:
         """Persist compiled XLA programs across processes (Spark has no
         analogue — its tasks are interpreted; our "tasks" cost minutes of
-        XLA compile, paid once per PROCESS without this). Points
-        ``jax_compilation_cache_dir`` at ``cache_dir`` (default: a per-user
-        dir, overridable with ``OTPU_COMPILE_CACHE``; "0" disables) so the
-        bench's replay scan / L-BFGS / eval programs load from disk on
-        every run after the first. Returns the info dict for
+        XLA compile, paid once per PROCESS without this). The cache lives
+        where ``JAX_COMPILATION_CACHE_DIR`` says, else at the fixed
+        ``<repo>/.jax_cache``. Returns the info dict for
         ``exec.compile_cache.cache_report`` (the bench line's ``cache_hit``
-        field). Session-level knob: call once, before the first jit."""
+        field). Call once, before the first jit."""
         from orange3_spark_tpu.exec.compile_cache import (
             enable_compilation_cache,
         )
 
-        return enable_compilation_cache(cache_dir)
+        return enable_compilation_cache()
 
     # ------------------------------------------------------------- shardings
     @property

@@ -20,6 +20,15 @@ reap an escaped subtree). A monitor thread polls the children:
 Ports are stable across restarts (replica i keeps its port), so a
 router's endpoint table never changes — a restarted replica re-admits
 itself through the router's /readyz polling + breaker half-open probe.
+
+Devices: every replica is its own OS process and inherits this process's
+environment, and an accelerator belongs to ONE process at a time. On a
+chip the subprocess fleet is therefore "parent stays off jax, one replica
+per chip"; a front end that has already touched jax on the chip (fitted a
+model, say) serves through ``FleetFrontend``'s in-process lanes
+(``fleet/inproc.py``, one lane per ``jax.devices()[i]``) instead.
+``_spawn`` raises rather than start a replica that would hang at device
+start-up; the CPU drills pin ``JAX_PLATFORMS=cpu`` in the replica env.
 """
 
 from __future__ import annotations
@@ -36,7 +45,9 @@ import time
 from orange3_spark_tpu.obs import trace
 from orange3_spark_tpu.obs.registry import REGISTRY
 from orange3_spark_tpu.utils import knobs
-from orange3_spark_tpu.utils.procs import kill_process_group
+from orange3_spark_tpu.utils.procs import (
+    kill_process_group, require_free_accelerator,
+)
 
 __all__ = ["ReplicaHandle", "ReplicaManager", "free_port"]
 
@@ -148,6 +159,7 @@ class ReplicaManager:
         env["PYTHONPATH"] = repo + (os.pathsep + prev if prev else "")
         env.update(self.env)
         env.update(self.per_replica_env.get(handle.replica_id, {}))
+        require_free_accelerator(env, "fleet replica spawn")
         logf = open(os.path.join(
             self.log_dir, f"replica-{handle.replica_id}.log"), "ab")
         try:

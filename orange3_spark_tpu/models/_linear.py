@@ -25,10 +25,6 @@ import optax.tree_utils as otu
 
 from orange3_spark_tpu.exec.donate import donating_jit, donation_enabled
 
-# optax 0.2.4 renamed tree_l2_norm -> tree_norm; container pins vary, so
-# accept either (same quantity: the global L2 norm of the pytree)
-_tree_norm = getattr(otu, "tree_norm", None) or otu.tree_l2_norm
-
 
 class LinearFitResult(NamedTuple):
     coef: jax.Array       # [d, k]
@@ -61,7 +57,7 @@ def lbfgs_minimize(value_fn, theta0, tol, max_iter, *, memory_size: int = 10):
         _, state = carry
         count = otu.tree_get(state, "count")
         grad = otu.tree_get(state, "grad")
-        gnorm = _tree_norm(grad)
+        gnorm = otu.tree_norm(grad)
         # first iteration always runs (grad in fresh state is zero), but
         # max_iter=0 must return the zero init, matching MLlib maxIter=0
         return (max_iter > 0) & ((count == 0) | ((count < max_iter) & (gnorm > tol)))

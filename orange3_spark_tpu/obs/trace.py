@@ -57,6 +57,8 @@ import threading
 import time
 from typing import Iterable, Iterator
 
+import jax
+
 from orange3_spark_tpu.obs import context as _context
 from orange3_spark_tpu.utils import knobs
 
@@ -88,14 +90,8 @@ _seq = itertools.count()
 #: span ids are their own sequence (ring slots recycle, identities don't)
 _span_ids = itertools.count(1)
 
-# TraceAnnotation is a cheap TraceMe when no profiler is active; resolved
-# once so a jax build without it degrades to pure-host spans
-try:
-    import jax
-
-    _ANNOTATION = getattr(jax.profiler, "TraceAnnotation", None)
-except Exception:  # noqa: BLE001 - obs must import anywhere
-    _ANNOTATION = None
+# TraceAnnotation is a cheap TraceMe when no profiler is active
+_ANNOTATION = jax.profiler.TraceAnnotation
 
 
 def enabled() -> bool:
@@ -259,12 +255,8 @@ class _Span:
         self.parent_id = st[-1].span_id if st else None
         self.span_id = next(_span_ids)
         st.append(self)
-        if _ANNOTATION is not None:
-            try:
-                self.ann = _ANNOTATION(self.name)
-                self.ann.__enter__()
-            except Exception:  # noqa: BLE001 - annotation is best-effort
-                self.ann = None
+        self.ann = _ANNOTATION(self.name)
+        self.ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 

@@ -66,8 +66,6 @@ class Window:
         is_start = jnp.concatenate(
             [jnp.asarray([True]), self._part_s[1:] != self._part_s[:-1]]
         )
-        # lax.cummax, not jnp.maximum.accumulate: the ufunc .accumulate
-        # methods don't exist on every pinned jax (absent in 0.4.x)
         self._seg_start = jax.lax.cummax(jnp.where(is_start, pos, 0))
         self._pos = pos
 

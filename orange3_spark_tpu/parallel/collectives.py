@@ -26,11 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # newer jax exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from orange3_spark_tpu.core.session import TpuSession
 
 
@@ -54,7 +49,7 @@ def tree_aggregate(
 
     specs = tuple(P(axis) if a.ndim == 1 else P(axis, *(None,) * (a.ndim - 1))
                   for a in arrays)
-    return _shard_map(
+    return jax.shard_map(
         shard_fn, mesh=session.mesh, in_specs=specs, out_specs=P()
     )(*arrays)
 

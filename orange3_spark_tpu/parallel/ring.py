@@ -28,11 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # newer jax exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _online_block(q, k, v, m, l, o, mask):
     """Fold one K/V block into the flash accumulator (q: [B,Sq,H,Dh])."""
@@ -66,12 +61,9 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp", *, causal: bool = Fals
         idx = jax.lax.axis_index(axis)
         b, s_loc, h, dh = qb.shape
         # mark the accumulators device-varying for the manual-axes carry check
-        # (they start as replicated literals but each device's values diverge);
-        # older jax has neither pcast nor the check — pass through unchanged
-        _pcast = getattr(jax.lax, "pcast", None)
-
+        # (they start as replicated literals but each device's values diverge)
         def _varying(x):
-            return _pcast(x, (axis,), to="varying") if _pcast else x
+            return jax.lax.pcast(x, (axis,), to="varying")
 
         m = _varying(jnp.full((b, h, s_loc), -jnp.inf, jnp.float32))
         l = _varying(jnp.zeros((b, h, s_loc), jnp.float32))
@@ -105,7 +97,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp", *, causal: bool = Fals
         out = o / jnp.maximum(l[..., None], 1e-30)           # [B,H,Sq,Dh]
         return out.transpose(0, 2, 1, 3)                     # [B,Sq,H,Dh]
 
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )(q, k, v)
 
@@ -136,7 +128,7 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp", *,
         qh, kh, vh = seq_to_heads(qb), seq_to_heads(kb), seq_to_heads(vb)
         return heads_to_seq(_dense_attention(qh, kh, vh, causal=causal))
 
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )(q, k, v)
 

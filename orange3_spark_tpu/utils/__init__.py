@@ -1,4 +1,4 @@
-from orange3_spark_tpu.utils.checkpoint import load_model, save_model
-from orange3_spark_tpu.utils.profiling import debug_unjitted, profile_trace, timed
-
-__all__ = ["load_model", "save_model", "debug_unjitted", "profile_trace", "timed"]
+"""Leaf helpers. Imports nothing from the package: `utils.knobs` is imported
+by nearly every module, so anything pulled in here (the model checkpointing
+in `utils.checkpoint` reaches workflow → widgets → every model) closes an
+import cycle. Import the submodule you need."""

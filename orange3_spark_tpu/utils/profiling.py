@@ -260,18 +260,14 @@ def _on_compile_event(key: str, _dur: float, **_kw) -> None:
 
 
 def install_compile_counter() -> bool:
-    """Register the backend-compile listener (idempotent). Returns whether
-    the counter is live (False on jax builds without jax.monitoring)."""
+    """Register the backend-compile listener (idempotent). Returns True
+    (the counter is live)."""
     global _compile_listener_installed
-    if _compile_listener_installed:
-        return True
-    try:
+    if not _compile_listener_installed:
         jax.monitoring.register_event_duration_secs_listener(
             _on_compile_event
         )
-    except Exception:  # noqa: BLE001 - counter is best-effort diagnostics
-        return False
-    _compile_listener_installed = True
+        _compile_listener_installed = True
     return True
 
 

@@ -176,7 +176,10 @@ def test_compilation_cache_roundtrip(tmp_path, monkeypatch):
     import jax.numpy as jnp
 
     d = str(tmp_path / "cc")
-    info = enable_compilation_cache(d)
+    # what jax does itself at start-up when the variable is set
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    jax.config.update("jax_compilation_cache_dir", d)
+    info = enable_compilation_cache()
     try:
         assert info["enabled"]
         assert info["dir"] == d
@@ -192,18 +195,11 @@ def test_compilation_cache_roundtrip(tmp_path, monkeypatch):
         assert rep["cache_entries"] >= 1
         assert rep["cache_hit"] is False
         # a second process starting now would find a warm cache
-        info2 = enable_compilation_cache(d)
+        info2 = enable_compilation_cache()
         assert info2["pre_entries"] == rep["cache_entries"]
         assert cache_report(info2)["cache_hit"] is True
     finally:
         jax.config.update("jax_compilation_cache_dir", None)
-
-
-def test_compilation_cache_disabled_by_env(monkeypatch):
-    monkeypatch.setenv("OTPU_COMPILE_CACHE", "0")
-    info = enable_compilation_cache()
-    assert info["enabled"] is False
-    assert cache_report(info) == {"cache_hit": None, "cache_entries": None}
 
 
 def test_cache_entries_missing_dir():

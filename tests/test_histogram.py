@@ -32,27 +32,6 @@ def test_pallas_interpret_matches_xla_randomized(seed):
                                err_msg=f"shape=({nodes},{n_bins},{s},{n},{d})")
 
 
-@pytest.mark.skipif(
-    jax.default_backend() != "tpu",
-    reason="compiled (Mosaic) Pallas path needs a real TPU: the kernel has "
-           "only ever run in interpret mode on the CPU mesh — a TPU "
-           "session picks this up automatically and exercises the "
-           "compiled lowering against the XLA reference",
-)
-@pytest.mark.parametrize("nodes,n_bins,s", [(1, 32, 3), (4, 16, 5)])
-def test_pallas_compiled_matches_xla_on_tpu(nodes, n_bins, s):
-    rng = np.random.default_rng(7)
-    n, d = 4096, 6
-    B = jnp.asarray(rng.integers(0, n_bins, (n, d)), dtype=jnp.int32)
-    S = jnp.asarray(rng.standard_normal((n, s)), dtype=jnp.float32)
-    pos = jnp.asarray(rng.integers(0, nodes, n), dtype=jnp.int32)
-    ref = _hist_xla(B, S, pos, nodes=nodes, n_bins=n_bins)
-    got = _hist_pallas(B, S, pos, nodes=nodes, n_bins=n_bins,
-                       interpret=False)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5,
-                               atol=1e-4)
-
-
 @pytest.mark.parametrize("nodes,n_bins,s", [(1, 32, 3), (4, 16, 5), (8, 32, 2)])
 def test_pallas_interpret_matches_xla(nodes, n_bins, s):
     rng = np.random.default_rng(0)

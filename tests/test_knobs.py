@@ -75,7 +75,7 @@ def test_typed_getters_defaults_and_overrides(monkeypatch):
     monkeypatch.setenv("OTPU_OBS", "1")
     assert knobs.get_bool("OTPU_OBS") is True
     monkeypatch.delenv("OTPU_BENCH_DIR", raising=False)
-    assert knobs.get_str("OTPU_BENCH_DIR") == "/tmp/otpu_bench"
+    assert knobs.get_str("OTPU_BENCH_DIR") == ""
     # unregistered names are a programming error, loudly
     with pytest.raises(KeyError):
         knobs.get_raw("OTPU_NOT_A_KNOB")

@@ -113,7 +113,6 @@ def test_shard_row_groups_partitions_single_parquet(tmp_path, monkeypatch):
 
 import os  # noqa: E402
 import sys  # noqa: E402
-import time  # noqa: E402
 
 from orange3_spark_tpu.io.multihost import (  # noqa: E402
     RaggedHostBlockError,
@@ -413,24 +412,6 @@ def test_align_checkpoints_common_step_and_donor_copy(tmp_path):
     os.unlink(tmp_path / "rank1.ckpt")
     assert MultihostLauncher.align_checkpoints(str(tmp_path), 2) == 0
     assert not os.path.exists(tmp_path / "rank0.ckpt")
-
-
-def test_cross_process_probe_shape_and_reason():
-    """The ONE capability probe tests and the bench share: (ok, reason);
-    a negative verdict must name the jaxlib version (the canonical skip
-    message)."""
-    from orange3_spark_tpu.parallel.launcher import (
-        cross_process_collectives_supported,
-    )
-    ok, reason = cross_process_collectives_supported()
-    assert isinstance(ok, bool) and isinstance(reason, str)
-    if not ok:
-        import jaxlib
-        assert jaxlib.__version__ in reason
-    # the verdict is cached: a second call must be instant
-    t0 = time.perf_counter()
-    assert cross_process_collectives_supported() == (ok, reason)
-    assert time.perf_counter() - t0 < 1.0
 
 
 def test_multihost_drill_smoke():

@@ -417,6 +417,7 @@ def test_aborted_fit_releases_model_state_entry(session, prof_env):
         # NON-transient: the resilience layer must not absorb it
         raise RuntimeError("poisoned mid-fit")
 
+    gc.collect()    # an earlier test's model may still await collection
     before = prof.LEDGER.owner_bytes().get("model_state", 0)
     with prof.force_enabled():
         with pytest.raises(RuntimeError, match="poisoned"):

@@ -54,9 +54,7 @@ def test_ring_attention_is_differentiable(mesh):
     def loss(q, k, v):
         return jnp.sum(ring_attention(q, k, v, mesh, "sp") ** 2)
 
-    # jax.set_mesh is the newer ambient-mesh context; the Mesh object
-    # itself is the context manager on older jax
-    with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+    with jax.set_mesh(mesh):
         g = jax.jit(jax.grad(loss))(qs, ks, vs)
     assert np.isfinite(np.asarray(g).sum())
 

@@ -1,16 +1,16 @@
 """serve/ subsystem tests: bucket ladder, AOT cache, padding parity,
 recompile-regression guard, micro-batcher, and the mask-based pad strip.
 
-Parity contract (the ISSUE's padding-parity satellite): the bucketed
-serving path's live-row outputs are BITWISE equal to the raw exact-shape
-path for every served model. One documented carve-out, root-caused this
-round: XLA:CPU emits a different (one-ulp on softmax probabilities)
-codegen for programs whose GLOBAL row count is 8 — one row per device on
-the 8-device test mesh, below the vector width — than for every shape
->= 16, measured raw-vs-raw with serve/ nowhere in the loop. So requests
-of n <= 8 rows pin bitwise parity against the raw path run AT THE BUCKET
-SHAPE (proving serve's padding adds nothing), while every n >= 9 (natural
-pad >= 16) pins bitwise against the exact-shape path directly.
+Parity contract (docs/serving.md §1): a served call's live-row outputs
+are BITWISE equal to (a) a second served call of the same bucket and
+(b) the unserved call run AT THE BUCKET SHAPE — serve's pad rows perturb
+nothing — and within ``SERVED_ULP`` ulp (at unit scale) of the unserved
+call at its own shape. XLA does not promise the same arithmetic across
+shapes: measured raw-vs-raw with serve/ nowhere in the loop, the unserved
+program at 40 rows (5 per device on the 8-device test mesh) and at 64
+rows (8 per device) differ by one ulp in softmax/projection outputs under
+jax 0.9.0, as the one-row-per-device shape always did. Discrete outputs
+(labels, cluster ids) stay bitwise against the exact-shape path.
 """
 
 from __future__ import annotations
@@ -47,6 +47,17 @@ def _subtable(table, n, session):
     X = _host(table.X)[:n]
     Y = _host(table.Y)[:n] if table.Y is not None else None
     return TpuTable.from_numpy(table.domain, X, Y, session=session)
+
+
+#: float tolerance of a served call against the unserved call at its own
+#: (different) shape, in float32 ulp at the scale max(|x|, 1)
+SERVED_ULP = 2
+
+
+def _assert_served_parity(served, raw_at_bucket, raw_exact):
+    np.testing.assert_array_equal(served, raw_at_bucket)
+    tol = SERVED_ULP * np.finfo(np.float32).eps
+    np.testing.assert_allclose(served, raw_exact, rtol=tol, atol=tol)
 
 
 def _bucket_padded(table, n, n_pad, session):
@@ -200,11 +211,12 @@ def test_state_hot_reload_keys_fresh_executables(session, iris):
 
 
 # --------------------------------------------------------- padding parity
-# natural pad >= 16: bitwise vs exact. Four sizes span the ladder (the
-# tiny-pad boundary, two interior buckets, the full table) — enough to
-# catch any per-bucket divergence while keeping the suite's XLA-compile
-# bill inside the tier-1 wall budget.
+# Four sizes span the ladder (the tiny-pad boundary, two interior buckets,
+# the full table) — enough to catch any per-bucket divergence while keeping
+# the suite's XLA-compile bill inside the tier-1 wall budget. 33 is the one
+# whose natural pad (40) and bucket (64) lower to different arithmetic.
 SIZES = (9, 33, 64, 150)
+LADDER = dict(min_bucket=16, max_bucket=4096)
 
 
 @pytest.mark.parametrize("n", SIZES)
@@ -222,12 +234,17 @@ def test_parity_logreg_transform_bitwise(session, iris, models, n):
     model = models["logreg"]
     t = _subtable(iris, n, session)
     raw = model.transform(t)
-    with ServingContext(BucketLadder(min_bucket=16, max_bucket=4096)):
+    bucket = BucketLadder(**LADDER).bucket_for(n)
+    raw_b = model.transform(_bucket_padded(iris, n, bucket, session))
+    with ServingContext(BucketLadder(**LADDER)):
         served = model.transform(t)
+        again = model.transform(t)
     assert served.n_rows == n
     assert [v.name for v in served.domain.attributes] \
         == [v.name for v in raw.domain.attributes]
-    np.testing.assert_array_equal(_host(served.X)[:n], _host(raw.X)[:n])
+    np.testing.assert_array_equal(_host(again.X)[:n], _host(served.X)[:n])
+    _assert_served_parity(_host(served.X)[:n], _host(raw_b.X)[:n],
+                          _host(raw.X)[:n])
 
 
 @pytest.mark.parametrize("n", SIZES)
@@ -245,15 +262,20 @@ def test_parity_pca_transform_bitwise(session, iris, models, n):
     model = models["pca"]
     t = _subtable(iris, n, session)
     raw = model.transform(t)
-    with ServingContext(BucketLadder(min_bucket=16, max_bucket=4096)):
+    bucket = BucketLadder(**LADDER).bucket_for(n)
+    raw_b = model.transform(_bucket_padded(iris, n, bucket, session))
+    with ServingContext(BucketLadder(**LADDER)):
         served = model.transform(t)
-    np.testing.assert_array_equal(_host(served.X)[:n], _host(raw.X)[:n])
+        again = model.transform(t)
+    np.testing.assert_array_equal(_host(again.X)[:n], _host(served.X)[:n])
+    _assert_served_parity(_host(served.X)[:n], _host(raw_b.X)[:n],
+                          _host(raw.X)[:n])
 
 
 def test_parity_tiny_batch_vs_bucket_shape(session, iris, models):
-    """n <= 8 (global pad 8: one row per device, the odd-codegen shape —
-    module docstring): parity referees against the raw path AT THE BUCKET
-    SHAPE, pinning that serve's pad rows perturb nothing."""
+    """n <= 8 (global pad 8: one row per device): parity referees against
+    the raw path AT THE BUCKET SHAPE, pinning that serve's pad rows perturb
+    nothing (module docstring)."""
     model = models["logreg"]
     n, bucket = 5, 16
     t = _subtable(iris, n, session)

@@ -1,0 +1,250 @@
+"""AOT compiles for the chip this sandbox does not have.
+
+The TPU compiler is installed here and compiles for a DESCRIBED v5e:2x2
+topology (guide `on-chip-measurement` §2): every jitted program of
+chip_smoke.py's main path that has a TPU-only choice in it, at its real
+size, with that choice passed explicitly — `jax.default_backend()` sees the
+CPU during such a compile. What the chip's compiler would refuse (a Mosaic
+tiling error, a program that does not fit 16 GB, a kernel that cannot be
+partitioned) fails here, at no chip time. A compile that passes is not a
+chip run.
+
+All in this one file, topology described inside a module-scoped fixture,
+nothing built at import, no child processes (only one process may hold the
+TPU library), persistent compile cache off around the compiles.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 16 << 30            # one v5e chip
+ROWS, N_DIMS = 1 << 18, 1 << 22  # chip_smoke's fit: 262,144-row chunks, 2^22
+HIST_REAL = (1 << 20, 28, 3, 16, 32)   # (N, d, s, nodes, bins): HIGGS level
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _abstract(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _fits(compiled, budget=HBM_BYTES) -> int:
+    """The program's own bytes on one device against its HBM (it does not
+    count what else the process keeps there). `pytest -s` shows them."""
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    print(f"\n  args {m.argument_size_in_bytes:,} out "
+          f"{m.output_size_in_bytes:,} temp {m.temp_size_in_bytes:,} alias "
+          f"{m.alias_size_in_bytes:,} -> {total:,} bytes")
+    assert total < budget, (total, m)
+    return total
+
+
+# --------------------------------------------------------------- histogram
+def _hist_args(shape, sharding, trees=None):
+    n, d, s, _nodes, _bins = shape
+    lead = () if trees is None else (trees,)
+    return (jax.ShapeDtypeStruct(lead + (n, d), jnp.int32, sharding=sharding),
+            jax.ShapeDtypeStruct(lead + (n, s), jnp.float32, sharding=sharding),
+            jax.ShapeDtypeStruct(lead + (n,), jnp.int32, sharding=sharding))
+
+
+@pytest.mark.parametrize("shape", [
+    (4096, 6, 3, 1, 32),      # the two cases the old on-TPU-only test never ran
+    (4096, 6, 5, 4, 16),
+    HIST_REAL,                # GBT ([g, h, w] stats) at level 4
+    (1 << 20, 28, 3, 1, 32),  # ... at the root
+    (1 << 20, 28, 2, 16, 32),  # RF (two class counts)
+    (1 << 20, 28, 3, 64, 64),  # deeper and finer: the one-hot's VMEM block
+], ids=["n4096-nodes1", "n4096-nodes4", "higgs-level", "higgs-root",
+        "higgs-rf", "higgs-nodes64-bins64"])
+def test_hist_pallas_compiles(one_chip, shape):
+    from orange3_spark_tpu.ops.histogram import _hist_pallas
+
+    compiled = _hist_pallas.lower(
+        *_hist_args(shape, one_chip), nodes=shape[3], n_bins=shape[4]
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def test_hist_pallas_vmapped_forest_compiles(one_chip):
+    """The forest's shape: grow_tree vmapped over 20 trees. Its transposed
+    [T, d, N] key copy is the temp that grows linearly in rows (3.0 GB here,
+    ~32 GB at HIGGS-11M: the reach work starts from this number)."""
+    from orange3_spark_tpu.ops.histogram import _hist_pallas
+
+    nodes, bins = HIST_REAL[3:]
+    f = jax.jit(jax.vmap(functools.partial(_hist_pallas, nodes=nodes,
+                                           n_bins=bins)))
+    compiled = f.lower(*_hist_args(HIST_REAL, one_chip, trees=20)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes > 2 << 30
+
+
+def test_grow_tree_level_compiles_with_kernel_inside(one_chip, monkeypatch):
+    """One level of the growth loop with the Pallas kernel in it: the choice
+    `node_histograms` makes on a TPU, steered here in the test."""
+    from orange3_spark_tpu.models import _tree
+    from orange3_spark_tpu.ops.histogram import _hist_pallas
+
+    monkeypatch.setattr(_tree, "node_histograms", _hist_pallas)
+    n, d, s, _nodes, bins = HIST_REAL
+    B, S, _ = _hist_args(HIST_REAL, one_chip)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = _tree.grow_tree.lower(
+        B, S, sds((d, bins - 1), jnp.float32), sds((1, d), jnp.float32),
+        sds((), jnp.float32), depth=1, n_bins=bins, gain_mode="newton",
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+# ------------------------------------------------------------ hashed linear
+@pytest.fixture(scope="module")
+def hashed(session):
+    """chip_smoke's estimator, its fresh fit state and one encoded zero
+    chunk — concrete on the CPU; the tests turn them into shapes placed on
+    the described chip. 'sort' is what resolve_sparse_lowering returns on a
+    TPU (it sees the CPU here)."""
+    import chip_smoke
+    from orange3_spark_tpu.models.hashed_linear import (
+        _encode_chunk_np, _init_fit_state,
+    )
+
+    p = chip_smoke.make_estimator(chip_smoke.REAL).params
+    assert (p.chunk_rows, p.n_dims) == (ROWS, N_DIMS)
+    theta, opt, salts_np, _salts, kw = _init_fit_state(p, session)
+    assert kw["codec"] is not None and kw["codec"].mode == "packed"
+    kw["sparse_lowering"] = "sort"
+    chunk = _encode_chunk_np(
+        kw["codec"], np.zeros((ROWS, 1 + p.n_dense + p.n_cat), np.float32),
+        salts_np)
+    return p, theta, opt, salts_np, chunk, kw
+
+
+def _step_args(hashed, state_sh, row_sh, vec_sh, *, stack: int = 0):
+    """Abstract arguments of `_hashed_step` (`stack` > 0: of the replay's
+    chunk stack, one-chip shardings only)."""
+    p, theta, opt, salts_np, chunk, kw = hashed
+    lead = (stack,) if stack else ()
+
+    def data(a):
+        return jax.ShapeDtypeStruct(
+            lead + a.shape, a.dtype,
+            sharding=row_sh if a.ndim == 2 else vec_sh)
+
+    scalar = functools.partial(jax.ShapeDtypeStruct, sharding=state_sh)
+    return (
+        _abstract(theta, state_sh), _abstract(opt, state_sh),
+        jax.tree.map(data, chunk),
+        scalar(lead, jnp.int32),                       # n_valid
+        scalar(lead + (1,), jnp.float32),              # y (label_in_chunk)
+        scalar(lead + (1,), jnp.float32),              # w
+        _abstract(salts_np, state_sh),
+        scalar((), jnp.float32), scalar((), jnp.float32),   # reg, lr
+    ), kw
+
+
+def test_hashed_step_sort_lowering_compiles(one_chip, hashed):
+    from orange3_spark_tpu.models.hashed_linear import _hashed_step
+
+    args, kw = _step_args(hashed, one_chip, one_chip, one_chip)
+    compiled = _hashed_step.donated.lower(*args, **kw).compile()
+    _fits(compiled)
+
+
+def test_hashed_replay_epochs_compiles(one_chip, hashed):
+    """The library-default one-dispatch replay: 7 epochs over 6 cached
+    chunks in ONE program (chip_smoke's fit: 8 chunks less 2 held out)."""
+    from orange3_spark_tpu.models.hashed_linear import _hashed_replay_epochs
+
+    (theta, opt, X, nv, y, w, salts, reg, lr), kw = _step_args(
+        hashed, one_chip, one_chip, one_chip, stack=6)
+    compiled = _hashed_replay_epochs.donated.lower(
+        theta, opt, (X, nv, y, w), salts, reg, lr, n_epochs=7, **kw
+    ).compile()
+    _fits(compiled)
+
+
+def test_hashed_predict_compiles_at_bucket(one_chip, hashed):
+    from orange3_spark_tpu.models.hashed_linear import _hashed_predict
+
+    p, theta, _opt, salts_np, _chunk, _kw = hashed
+    compiled = _hashed_predict.lower(
+        _abstract(theta, one_chip),
+        jax.ShapeDtypeStruct((4096, p.n_dense + p.n_cat), jnp.float32,
+                             sharding=one_chip),
+        _abstract(salts_np, one_chip), n_dims=p.n_dims, n_dense=p.n_dense,
+    ).compile()
+    _fits(compiled)
+
+
+def test_hashed_step_compiles_on_four_chip_mesh(topo, hashed):
+    """chip_smoke --four-chips' data-parallel step: rows on `data`, the
+    table replicated (DataParallelPartitioner's (4,1) mesh).
+    memory_analysis() is per device. The (2,2) model-sharded table of
+    SPMDPartitioner compiles too (PR 22, by hand: ~100 s, so not kept)."""
+    from orange3_spark_tpu.models.hashed_linear import _hashed_step
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
+    args, kw = _step_args(hashed, NamedSharding(mesh, P()),
+                          NamedSharding(mesh, P("data", None)),
+                          NamedSharding(mesh, P("data")))
+    compiled = _hashed_step.donated.lower(*args, **kw).compile()
+    _fits(compiled)
+    # the cross-device gradient sum the compiler had to put in
+    assert "all-reduce" in compiled.as_text()
+
+
+# ------------------------------------------------------------------- kmeans
+def test_kmeans_lloyd_compiles(one_chip):
+    from orange3_spark_tpu.models.kmeans import _lloyd
+
+    n, d, k = 2_097_152, 8, 10
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = _lloyd.donated.lower(
+        sds((n, d), jnp.float32), sds((n,), jnp.float32),
+        sds((k, d), jnp.float32), sds((), jnp.float32),
+        k=k, max_iter=10).compile()
+    _fits(compiled)

@@ -30,15 +30,6 @@ def main():
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args()
 
-    # serialize against any other TPU harness (see utils/devlock.py)
-    from orange3_spark_tpu.utils.devlock import tpu_device_lock
-
-    with tpu_device_lock(name="step_ab"):
-        _main_locked(args)
-
-
-def _main_locked(args):
-
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -92,8 +83,7 @@ def _main_locked(args):
         out[f"{key}_rows_per_sec"] = round(args.rows / ms * 1e3, 1)
     best = min(("fused", "per_column", "sorted"), key=lambda v: out[v])
     out["best"] = best
-    # "value" (truthy) is the capture watcher's banking contract — the
-    # winning variant's step time carries it
+    # the winning variant's step time is the headline value
     out["value"] = out[best]
     # print + flush the A/B line BEFORE the scan cell below: that cell
     # dispatches a multi-chunk multi-epoch scan, the one program shape
