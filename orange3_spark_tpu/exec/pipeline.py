@@ -35,7 +35,7 @@ from typing import Callable, Iterator
 
 from orange3_spark_tpu.obs import context as obs_context
 from orange3_spark_tpu.obs import prof
-from orange3_spark_tpu.obs.trace import span
+from orange3_spark_tpu.obs.trace import stage
 from orange3_spark_tpu.utils.dispatch import beat
 
 _EOF = object()
@@ -120,15 +120,17 @@ class PipelinedExecutor:
         t_start = time.perf_counter()
         try:
             while True:
-                t0 = time.perf_counter()
-                got = q.get()
-                dt_wait = time.perf_counter() - t0
-                stats.wait_s += dt_wait
+                # the consumer's blocked time is one "input_wait" span a
+                # result (the first of a stream is the pipeline fill);
+                # wait_s is the sum of their durations
+                with stage("input_wait", stats, "wait_s",
+                           stats.items) as wait:
+                    got = q.get()
                 # goodput attribution (obs/prof.py): the consumer is the
                 # fit's thread of control, so this wait IS input_wait —
                 # fed live (not at stream end) so per-epoch bottleneck
                 # classification sees intra-epoch waits
-                prof.note_input_wait(dt_wait)
+                prof.note_input_wait(wait.seconds)
                 if (isinstance(got, tuple) and len(got) == 2
                         and got[0] is _EOF):
                     if got[1] is not None:
@@ -154,14 +156,14 @@ class PipelinedExecutor:
                 # parse/rechunk work lives (prep is only pad+device_put),
                 # and both run on this thread — prep_s must carry the
                 # whole host-side cost or overlap_pct overstates waits
-                t0 = time.perf_counter()
-                with span("prefetch", stats.items):
+                # (it is the sum of the "prefetch" spans, the pull that
+                # finds the stream's end among them)
+                with stage("prefetch", stats, "prep_s", stats.items):
                     try:
                         item = next(it)
                     except StopIteration:
                         break
                     out = prep(item)
-                stats.prep_s += time.perf_counter() - t0
                 beat()  # parse/DMA progress feeds the stall watchdog
                 while not stop.is_set():
                     try:

@@ -30,6 +30,7 @@ from orange3_spark_tpu.models._tree import (
     tree_apply,
 )
 from orange3_spark_tpu.models.base import Estimator, Model, Params
+from orange3_spark_tpu.obs.trace import span_iter
 from orange3_spark_tpu.utils.dispatch import bound_dispatch
 
 EPS = 1e-12
@@ -100,7 +101,7 @@ def _boost(B, edges, W, y, depth, n_bins, p: GBTParams, loss: str):
 
     trees = []
     imps = []
-    for r in range(p.max_iter):
+    for r in span_iter("gbt_round", range(p.max_iter)):
         key, sub = jax.random.split(key)
         F, tree, imp = _gbt_round(F, B, edges, W, y, sub, p=p, loss=loss,
                                   depth=depth, n_bins=n_bins)

@@ -75,7 +75,7 @@ from orange3_spark_tpu.optim.sparse import (
 )
 from orange3_spark_tpu.obs import prof
 from orange3_spark_tpu.obs.report import RunReport
-from orange3_spark_tpu.obs.trace import span, span_iter, traced
+from orange3_spark_tpu.obs.trace import span, span_iter, stage, traced
 from orange3_spark_tpu.obs.trace import refreshed_enabled as obs_enabled
 from orange3_spark_tpu.resilience.numerics import check_finite_training
 from orange3_spark_tpu.utils.dispatch import bound_dispatch
@@ -398,29 +398,44 @@ def _step_core(
     f32 chunk; otherwise ``Xall`` is the compressed block dict and the
     decode (bf16 widen / static bit-unpack, fused by XLA) happens HERE, so
     the replay scan reads compressed HBM bytes. A packed plan unpacks here
-    too — bit-exact, so the plan-lowering update is unchanged math."""
-    if codec is None:
-        yv, dense, cats, wv, vals = _split_chunk(
-            Xall, n_valid, y, w, label_in_chunk=label_in_chunk,
-            n_dense=n_dense, value_weighted=value_weighted,
-            impute_missing=impute_missing,
-        )
-        idx = hash_columns(cats, salts, n_dims)
-    else:
-        yv, dense, idx, wv = _decode_chunk(codec, Xall, n_valid, y, w, salts)
-        cats = None
-        vals = None
-        if plan is not None and codec.mode == "packed":
-            plan = unpack_plan(plan, Xall["cats"].shape[0], codec.n_cat,
-                               n_dims)
+    too — bit-exact, so the plan-lowering update is unchanged math.
+
+    The phases carry ``jax.named_scope``s (``step/decode``,
+    ``step/forward``, ``step/loss_grad``, ``step/dense_leaf`` here;
+    ``step/sort|segment|gather|rule|scatter`` in optim/sparse.py):
+    metadata only, so that a device trace is read by phase and not by
+    XLA's fusion numbers (docs/observability.md, "Device scopes")."""
+    with jax.named_scope("step/decode"):
+        if codec is None:
+            yv, dense, cats, wv, vals = _split_chunk(
+                Xall, n_valid, y, w, label_in_chunk=label_in_chunk,
+                n_dense=n_dense, value_weighted=value_weighted,
+                impute_missing=impute_missing,
+            )
+            idx = hash_columns(cats, salts, n_dims)
+        else:
+            yv, dense, idx, wv = _decode_chunk(codec, Xall, n_valid, y, w,
+                                               salts)
+            cats = None
+            vals = None
+            if plan is not None and codec.mode == "packed":
+                plan = unpack_plan(plan, Xall["cats"].shape[0], codec.n_cat,
+                                   n_dims)
+
+    def forward(theta, lowering):
+        with jax.named_scope("step/forward"):
+            return _hashed_logits(theta, dense, idx, compute_dtype,
+                                  lowering, vals)
+
+    def data_loss(logits):
+        with jax.named_scope("step/loss_grad"):
+            row = per_row_loss(loss_kind, logits, yv)
+            sw = jnp.maximum(jnp.sum(wv), EPS_TOTAL_WEIGHT)
+            return jnp.sum(row * wv) / sw
 
     if optim_update == "adam":
         def loss_fn(theta):
-            logits = _hashed_logits(theta, dense, idx, compute_dtype,
-                                    emb_update, vals)
-            row = per_row_loss(loss_kind, logits, yv)
-            sw = jnp.maximum(jnp.sum(wv), EPS_TOTAL_WEIGHT)
-            data = jnp.sum(row * wv) / sw
+            data = data_loss(forward(theta, emb_update))
             return data + 0.5 * reg * (
                 jnp.sum(theta["emb"] ** 2) + jnp.sum(theta["coef"] ** 2)
             )
@@ -439,14 +454,7 @@ def _step_core(
         # gradient is all the touched-row engine needs (the plain 'fused'
         # gather forward; emb_update scatter lowerings are a BACKWARD
         # concern and only apply to the dense paths)
-        logits = _hashed_logits(theta, dense, idx, compute_dtype, "fused",
-                                vals)
-
-        def data_loss(z):
-            row = per_row_loss(loss_kind, z, yv)
-            sw = jnp.maximum(jnp.sum(wv), EPS_TOTAL_WEIGHT)
-            return jnp.sum(row * wv) / sw
-
+        logits = forward(theta, "fused")
         loss, dl = jax.value_and_grad(data_loss)(logits)
         emb, t, eslots = sparse_embedding_update(
             kind, theta["emb"], opt_state["t"], slots["emb"], dl, idx,
@@ -455,35 +463,31 @@ def _step_core(
             raw_cats=(cats if value_weighted else None), vals=vals,
         )
         # dense small parameters: the same rule, full-array (they are tiny)
-        if theta["coef"].shape[0]:
-            g_coef = jnp.dot(dense.astype(compute_dtype).T, dl,
-                             preferred_element_type=jnp.float32)
-        else:
-            g_coef = jnp.zeros_like(theta["coef"])
-        g_int = jnp.sum(dl, axis=0)
+        with jax.named_scope("step/dense_leaf"):
+            if theta["coef"].shape[0]:
+                g_coef = jnp.dot(dense.astype(compute_dtype).T, dl,
+                                 preferred_element_type=jnp.float32)
+            else:
+                g_coef = jnp.zeros_like(theta["coef"])
+            g_int = jnp.sum(dl, axis=0)
     else:
         # dense twin: autodiff through the table (the emb_update scatter
         # lowering applies), then a full-array rule sweep — the parity
         # baseline the sparse path is measured against
-        def loss_fn(theta):
-            logits = _hashed_logits(theta, dense, idx, compute_dtype,
-                                    emb_update, vals)
-            row = per_row_loss(loss_kind, logits, yv)
-            sw = jnp.maximum(jnp.sum(wv), EPS_TOTAL_WEIGHT)
-            return jnp.sum(row * wv) / sw
-
-        loss, g = jax.value_and_grad(loss_fn)(theta)
+        loss, g = jax.value_and_grad(
+            lambda theta: data_loss(forward(theta, emb_update)))(theta)
         t = opt_state["t"]
         emb, eslots = dense_update(
             kind, theta["emb"], slots["emb"], g["emb"], lr, decay, reg, l1,
             use_decay=use_decay)
         g_coef, g_int = g["coef"], g["intercept"]
-    coef, cslots = dense_update(
-        kind, theta["coef"], slots["coef"], g_coef, lr, decay, reg, l1,
-        use_decay=use_decay)
-    intercept, islots = dense_update(
-        kind, theta["intercept"], slots["intercept"], g_int, lr, decay,
-        reg, l1, use_decay=False)    # reg never touched the intercept
+    with jax.named_scope("step/dense_leaf"):
+        coef, cslots = dense_update(
+            kind, theta["coef"], slots["coef"], g_coef, lr, decay, reg, l1,
+            use_decay=use_decay)
+        intercept, islots = dense_update(
+            kind, theta["intercept"], slots["intercept"], g_int, lr, decay,
+            reg, l1, use_decay=False)    # reg never touched the intercept
     theta = {"emb": emb, "coef": coef, "intercept": intercept}
     opt_state = {"step": step + 1, "t": t,
                  "slots": {"emb": eslots, "coef": cslots,
@@ -555,13 +559,16 @@ def _hashed_replay_epochs(
         theta, opt = carry
         Xall, n_valid, y, w = xs[:4]
         plan = xs[4] if len(xs) > 4 else None
-        theta, opt, loss = _step_core(
-            theta, opt, Xall, n_valid, y, w, salts, reg, lr, plan, l1, **kw
-        )
+        with jax.named_scope("replay/chunk"):
+            theta, opt, loss = _step_core(
+                theta, opt, Xall, n_valid, y, w, salts, reg, lr, plan, l1,
+                **kw
+            )
         return (theta, opt), loss
 
     def epoch_body(carry, _):
-        carry, losses = jax.lax.scan(chunk_body, carry, tuple(stacks))
+        with jax.named_scope("replay/epoch"):
+            carry, losses = jax.lax.scan(chunk_body, carry, tuple(stacks))
         return carry, losses
 
     (theta, opt_state), chunk_losses = jax.lax.scan(
@@ -591,6 +598,7 @@ def _hashed_predict(theta, Xall, salts, *, n_dims: int, n_dense: int,
     static_argnames=("loss_kind", "n_dims", "n_dense", "label_in_chunk",
                      "value_weighted", "impute_missing", "codec"),
 )
+@jax.named_scope("eval/chunk")
 def _hashed_eval_chunk(
     theta, Xall, n_valid, y, w, salts,
     *, loss_kind: str, n_dims: int, n_dense: int, label_in_chunk: bool,
@@ -770,24 +778,26 @@ class HashedLinearModel(Model):
         salts = jnp.asarray(self.salts)
         kind = _row_loss_kind(p)
         tot = None
-        for chunk in device_chunks:
-            # sparse-plan fits cache 5-tuples (the touched-row plan rides
-            # along for replay); eval only needs the data quadruple
-            Xd, n_valid, yd, wd = chunk[:4]
-            count_dispatch()
-            out = _hashed_eval_chunk(
-                self.theta, Xd, n_valid, yd, wd, salts,
-                loss_kind=kind, n_dims=p.n_dims, n_dense=p.n_dense,
-                label_in_chunk=p.label_in_chunk,
-                value_weighted=p.value_weighted,
-                impute_missing=_impute_flag(p), codec=codec,
-            )
-            tot = out if tot is None else tuple(
-                a + b for a, b in zip(tot, out)
-            )
-        if tot is None:
-            raise ValueError("no chunks to evaluate")
-        loss_sum, correct, wsum, pos, neg = jax.device_get(tot)
+        with span("evaluate"):
+            for i, chunk in enumerate(device_chunks):
+                # sparse-plan fits cache 5-tuples (the touched-row plan
+                # rides along for replay); eval only needs the quadruple
+                Xd, n_valid, yd, wd = chunk[:4]
+                count_dispatch()
+                with span("eval_chunk", i):
+                    out = _hashed_eval_chunk(
+                        self.theta, Xd, n_valid, yd, wd, salts,
+                        loss_kind=kind, n_dims=p.n_dims, n_dense=p.n_dense,
+                        label_in_chunk=p.label_in_chunk,
+                        value_weighted=p.value_weighted,
+                        impute_missing=_impute_flag(p), codec=codec,
+                    )
+                    tot = out if tot is None else tuple(
+                        a + b for a, b in zip(tot, out)
+                    )
+            if tot is None:
+                raise ValueError("no chunks to evaluate")
+            loss_sum, correct, wsum, pos, neg = jax.device_get(tot)
         out = {
             "logloss": float(loss_sum / max(wsum, 1e-12)),
             "accuracy": float(correct / max(wsum, 1e-12)),
@@ -1372,16 +1382,24 @@ class StreamingHashedLinearEstimator(Estimator):
         # categorical block offset in the padded chunk ([label?] + dense +
         # cats, or [label?] + idx pairs; n_dense == 0 in vw mode)
         cats_off = (1 if p.label_in_chunk else 0) + p.n_dense
-        # stage timings collect for the caller's stage_times= dict AND for
-        # the run report (obs/report.py) — under OTPU_OBS=0 with no caller
-        # dict, collection reverts to the legacy zero-instrumentation path.
-        # honest_walls: only an EXPLICIT stage_times= caller (bench) pays
-        # the per-epoch block_until_ready that makes epoch walls exact;
-        # report-only collection must not add epoch-boundary device syncs
-        # to every default fit
+        # stage seconds are the sums of span durations (obs.trace.stage):
+        # they collect for the caller's stage_times= dict AND for the run
+        # report (obs/report.py); under OTPU_OBS=0 with no caller dict
+        # `times` is None and `staged` below is a no-op.
+        # honest_walls CHANGES THE RUN: a caller that passes stage_times=
+        # (the benchmark does) gets one more block_until_ready(last_loss)
+        # at every epoch boundary, so that an epoch's wall ends with its
+        # device work; a default fit has none. What each costs is the
+        # "epoch_barrier" span's duration.
         times = ({"parse_s": 0.0, "h2d_s": 0.0}
                  if stage_times is not None or obs_enabled() else None)
         honest_walls = stage_times is not None
+
+        def staged(name, key, **args):
+            """A ``name`` span whose seconds also land in ``times[key]``."""
+            if times is None:       # spans are off too: the shared no-op
+                return span(name, **args)
+            return stage(name, times, key, **args)
         # fit-level pipeline counters: every prefetch stream (live ingest,
         # disk replay, grouped disk replay) folds in, so overlap_pct is the
         # measured host-prep/device-compute overlap of the WHOLE fit
@@ -1441,8 +1459,10 @@ class StreamingHashedLinearEstimator(Estimator):
                            in zip(plan_specs, arrays[len(chunk_specs):])}
             return payload, y_np, w_np, plan_np
 
-        def to_device(host_chunk):
-            """parse-thread side: pad + device_put one chunk."""
+        def encode_chunk(host_chunk, enc):
+            """One host chunk -> (payload, y, w, plan, n_valid) as the
+            cache, the spill and the DMA carry it; ``enc`` is the
+            surrounding "encode" span."""
             if p.label_in_chunk:
                 X_np = host_chunk if isinstance(
                     host_chunk, np.ndarray) else host_chunk[0]
@@ -1477,75 +1497,69 @@ class StreamingHashedLinearEstimator(Estimator):
                 # host-presorted touched-row plan (optim/sparse.py) —
                 # the stable argsort runs here on the prefetch thread,
                 # overlapping device steps, and is replayed every epoch
-                t_pl = time.perf_counter() if times is not None else 0.0
-                plan_np = build_plan_np(
-                    Xp[:, cats_off:cats_off + p.n_cat], salts_np,
-                    p.n_dims, n,
-                    vals=(Xp[:, cats_off + p.n_cat:]
-                          if p.value_weighted else None),
-                    impute_missing=static_kw["impute_missing"],
-                    idx=idx_np)
-                if times is not None:
-                    times["plan_s"] = (times.get("plan_s", 0.0)
-                                       + time.perf_counter() - t_pl)
+                with staged("plan", "plan_s") as planned:
+                    plan_np = build_plan_np(
+                        Xp[:, cats_off:cats_off + p.n_cat], salts_np,
+                        p.n_dims, n,
+                        vals=(Xp[:, cats_off + p.n_cat:]
+                              if p.value_weighted else None),
+                        impute_missing=static_kw["impute_missing"],
+                        idx=idx_np)
+                enc.note(plan_s=round(planned.seconds, 6))
             # encode on the prefetch thread (io/codec.py): bf16 / u8 /
             # bit-packed blocks — the cache, the spill AND the DMA all
             # carry the compressed bytes from here on
             payload = Xp
             plan_store = plan_np
             if codec is not None:
-                t_en = time.perf_counter()
                 payload = _encode_chunk_np(codec, Xp, salts_np, idx=idx_np)
                 if plan_np is not None:
                     plan_store = _plan_device_form(codec, plan_np,
                                                    pad_rows, p)
-                dt_en = time.perf_counter() - t_en
-                pipe_stats.encode_s += dt_en
-                if times is not None:
-                    times["encode_s"] = times.get("encode_s", 0.0) + dt_en
+            return payload, yp, wp, plan_store, n
+
+        def to_device(host_chunk):
+            """parse-thread side: encode (pad, hash, plan, codec) and
+            device_put one chunk."""
+            with stage("encode", pipe_stats, "encode_s") as enc:
+                payload, yp, wp, plan_store, n = encode_chunk(host_chunk,
+                                                              enc)
             if spill_active[0]:
                 # sequential write of the already-encoded chunk — still
                 # on the prefetch thread, overlapping device steps. Plan
                 # arrays ride the same record, typed (packed u32 under
                 # the 'packed' codec).
-                t_sp = time.perf_counter() if times is not None else 0.0
-                spill.append(record_arrays(payload, yp, wp, plan_store), n)
-                if times is not None:
-                    times["spill_s"] = (times.get("spill_s", 0.0)
-                                        + time.perf_counter() - t_sp)
-            t0 = time.perf_counter() if times is not None else 0.0
-            Xd = put_payload(payload)
-            if p.label_in_chunk:
-                yd = wd = _ZERO
-            else:
-                yd = put_sharded(yp, vec_sh)
-                wd = put_sharded(wp, vec_sh)
-            out = (Xd, jnp.int32(n), yd, wd)
-            if plan_store is not None:
-                out = out + (jax.device_put(plan_store, session.replicated),)
-            if times is not None:
-                times["h2d_s"] += time.perf_counter() - t0
+                with staged("spill", "spill_s"):
+                    spill.append(
+                        record_arrays(payload, yp, wp, plan_store), n)
+            with staged("h2d", "h2d_s"):
+                Xd = put_payload(payload)
+                if p.label_in_chunk:
+                    yd = wd = _ZERO
+                else:
+                    yd = put_sharded(yp, vec_sh)
+                    wd = put_sharded(wp, vec_sh)
+                out = (Xd, jnp.int32(n), yd, wd)
+                if plan_store is not None:
+                    out = out + (jax.device_put(plan_store,
+                                                session.replicated),)
             return out
 
         _ZERO = jnp.zeros((1,), jnp.float32)
 
         def host_chunks():
-            """Rechunked host stream, with parse time attributed."""
+            """Rechunked host stream, each pull one "parse" span (the pull
+            that finds the stream's end among them)."""
             if p.label_in_chunk:
                 it = _rechunk(((c, None) for c in source()), pad_rows)
             else:
                 it = _rechunk(source(), pad_rows)
-            if times is None:
-                yield from ((x if not p.label_in_chunk else x[0]) for x in it)
-            else:
-                while True:
-                    t0 = time.perf_counter()
-                    try:
-                        item = next(it)
-                    except StopIteration:
-                        return
-                    times["parse_s"] += time.perf_counter() - t0
-                    yield item if not p.label_in_chunk else item[0]
+            while True:
+                with staged("parse", "parse_s"):
+                    item = next(it, None)
+                if item is None:
+                    return
+                yield item if not p.label_in_chunk else item[0]
 
         def device_chunk_iter():
             from orange3_spark_tpu.io.streaming import prefetch_map
@@ -1666,19 +1680,17 @@ class StreamingHashedLinearEstimator(Estimator):
             def rec_to_device(i):
                 arrays, n = spill.read(i)
                 payload, y_np, w_np, plan_np = record_to_host(arrays)
-                t0 = time.perf_counter() if times is not None else 0.0
-                Xd = put_payload(payload)
-                if p.label_in_chunk:
-                    yd = wd = _ZERO
-                else:
-                    yd = put_sharded(y_np, vec_sh)
-                    wd = put_sharded(w_np, vec_sh)
-                out = (Xd, jnp.int32(n), yd, wd)
-                if plan_np is not None:
-                    out = out + (jax.device_put(plan_np,
-                                                session.replicated),)
-                if times is not None:
-                    times["h2d_s"] += time.perf_counter() - t0
+                with staged("h2d", "h2d_s"):
+                    Xd = put_payload(payload)
+                    if p.label_in_chunk:
+                        yd = wd = _ZERO
+                    else:
+                        yd = put_sharded(y_np, vec_sh)
+                        wd = put_sharded(w_np, vec_sh)
+                    out = (Xd, jnp.int32(n), yd, wd)
+                    if plan_np is not None:
+                        out = out + (jax.device_put(plan_np,
+                                                    session.replicated),)
                 return out
 
             idxs = iter(range(start, spill.n_records - holdout_chunks))
@@ -1706,7 +1718,6 @@ class StreamingHashedLinearEstimator(Estimator):
                 g = group
                 recs = [spill.read(start + j) for j in range(g)]
                 hosts = [record_to_host(r[0]) for r in recs]
-                t0 = time.perf_counter() if times is not None else 0.0
 
                 def stack_put(leaves):
                     a = np.stack(leaves)
@@ -1714,25 +1725,24 @@ class StreamingHashedLinearEstimator(Estimator):
                             + (None,) * (a.ndim - 2))
                     return put_sharded(a, session.sharding(*spec))
 
-                if codec is None:
-                    Xs = stack_put([h[0] for h in hosts])
-                else:
-                    Xs = {k2: stack_put([h[0][k2] for h in hosts])
-                          for k2 in hosts[0][0]}
-                nv = jnp.asarray([r[1] for r in recs], jnp.int32)
-                if p.label_in_chunk:
-                    ys = ws = jnp.zeros((g, 1), jnp.float32)
-                else:
-                    ys = stack_put([h[1] for h in hosts])
-                    ws = stack_put([h[2] for h in hosts])
-                stacks = (Xs, nv, ys, ws)
-                if sparse_plan:
-                    plans = [h[3] for h in hosts]
-                    stacks = stacks + (jax.device_put(
-                        jax.tree.map(lambda *a: np.stack(a), *plans),
-                        session.replicated),)
-                if times is not None:
-                    times["h2d_s"] += time.perf_counter() - t0
+                with staged("h2d", "h2d_s"):
+                    if codec is None:
+                        Xs = stack_put([h[0] for h in hosts])
+                    else:
+                        Xs = {k2: stack_put([h[0][k2] for h in hosts])
+                              for k2 in hosts[0][0]}
+                    nv = jnp.asarray([r[1] for r in recs], jnp.int32)
+                    if p.label_in_chunk:
+                        ys = ws = jnp.zeros((g, 1), jnp.float32)
+                    else:
+                        ys = stack_put([h[1] for h in hosts])
+                        ws = stack_put([h[2] for h in hosts])
+                    stacks = (Xs, nv, ys, ws)
+                    if sparse_plan:
+                        plans = [h[3] for h in hosts]
+                        stacks = stacks + (jax.device_put(
+                            jax.tree.map(lambda *a: np.stack(a), *plans),
+                            session.replicated),)
                 return g, stacks
 
             starts = iter(range(0, n_full, group))
@@ -1850,9 +1860,10 @@ class StreamingHashedLinearEstimator(Estimator):
                         run_step(dev_chunk)
             # non-finite guard (resilience/numerics.py) BEFORE the save:
             # a divergent epoch raises typed, never checkpoints NaN state
-            check_finite_training(
-                last_loss, theta, epoch=epoch, chunk=n_steps,
-                estimator="StreamingHashedLinearEstimator")
+            with span("finite_check", final=False):
+                check_finite_training(
+                    last_loss, theta, epoch=epoch, chunk=n_steps,
+                    estimator="StreamingHashedLinearEstimator")
             # epoch-boundary snapshot (checkpoint_every_epochs cadence):
             # the shared save decision covers every epoch path above
             epoch_boundary_snapshot(
@@ -1863,20 +1874,16 @@ class StreamingHashedLinearEstimator(Estimator):
             )
             if times is not None:
                 if honest_walls and last_loss is not None:
-                    t_bar = time.perf_counter()
-                    jax.block_until_ready(last_loss)  # honest epoch wall
+                    with stage("epoch_barrier") as barrier:
+                        jax.block_until_ready(last_loss)  # honest epoch wall
                     # an explicit epoch barrier is synchronization, not
                     # device pace (the periodic sync already charged that)
-                    prof.note_sync(time.perf_counter() - t_bar,
-                                   barrier=True)
+                    prof.note_sync(barrier.seconds, barrier=True)
                 epoch_walls.append(time.perf_counter() - t_epoch)
             if acc is not None:
                 # close the goodput window: per-epoch stage deltas +
                 # hysteresis bottleneck classification (obs/prof.py)
-                acc.epoch_boundary(
-                    epoch,
-                    encode_s=pipe_stats.encode_s
-                    + (times or {}).get("plan_s", 0.0))
+                acc.epoch_boundary(epoch, encode_s=pipe_stats.encode_s)
             if (epoch == 0 and fuse_replay and cache.enabled
                     and cache.batches
                     and 2 * cache.nbytes <= cache_device_bytes
@@ -1900,73 +1907,76 @@ class StreamingHashedLinearEstimator(Estimator):
                     # resume-at-completion edge
                     n_steps += n_rep * spe
                     break
-                t_rep = time.perf_counter()
-                # stack the WHOLE chunk tuple as one pytree — the 5th
-                # (plan) element's dict leaves stack right along under
-                # the sparse 'plan' lowering
-                stacks = jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *cache.batches)
-                # the stack is a SECOND device copy of the cache (chunk
-                # arrays + sparse plans) — a distinct ledger tenant for
-                # exactly as long as it lives. Name keyed per FIT (two
-                # concurrent replays must not share one entry); the
-                # guard releases on an aborted replay (device OOM while
-                # holding the copy is THE likely failure here), the
-                # explicit release below makes its firing a no-op
-                rp_key = f"replay_stack-{state_key}"
-                _rp_guard = prof.ledger_guard("replay_plans", rp_key)
-                prof.ledger_set("replay_plans", rp_key,
-                                prof.tree_device_bytes(stacks))
-                if p.replay_granularity == "epoch":
-                    # one n_epochs=1 scan dispatch per epoch over the same
-                    # stack (see the Params docstring). Epoch boundaries are the
-                    # snapshot/resume grain; the skip/save protocol is the
-                    # shared run_epoch_replay.
-                    from orange3_spark_tpu.io.streaming import (
-                        run_epoch_replay,
-                    )
+                with stage("replay_stack") as stacked:
+                    # stack the WHOLE chunk tuple as one pytree — the 5th
+                    # (plan) element's dict leaves stack right along under
+                    # the sparse 'plan' lowering
+                    stacks = jax.tree.map(
+                        lambda *xs: jnp.stack(xs), *cache.batches)
+                    # the stack is a SECOND device copy of the cache (chunk
+                    # arrays + sparse plans) — a distinct ledger tenant for
+                    # exactly as long as it lives. Name keyed per FIT (two
+                    # concurrent replays must not share one entry); the
+                    # guard releases on an aborted replay (device OOM while
+                    # holding the copy is THE likely failure here), the
+                    # explicit release below makes its firing a no-op
+                    rp_key = f"replay_stack-{state_key}"
+                    _rp_guard = prof.ledger_guard("replay_plans", rp_key)
+                    prof.ledger_set("replay_plans", rp_key,
+                                    prof.tree_device_bytes(stacks))
+                with stage("replay", n_epochs=n_rep,
+                           steps=n_rep * spe) as replayed:
+                    if p.replay_granularity == "epoch":
+                        # one n_epochs=1 scan dispatch per epoch over the
+                        # same stack (see the Params docstring). Epoch
+                        # boundaries are the snapshot/resume grain; the
+                        # skip/save protocol is the shared run_epoch_replay.
+                        from orange3_spark_tpu.io.streaming import (
+                            run_epoch_replay,
+                        )
 
-                    def _disp(n_ep):
-                        nonlocal theta, opt_state
+                        def _disp(n_ep):
+                            nonlocal theta, opt_state
+                            with span("replay_dispatch", n_epochs=n_ep):
+                                theta, opt_state, chunk_losses = \
+                                    _hashed_replay_epochs(
+                                        theta, opt_state, stacks, salts,
+                                        reg, lr, l1, n_epochs=n_ep,
+                                        **static_kw,
+                                    )
+                            return chunk_losses[-1, -1]
+
+                        n_steps, last, _ = run_epoch_replay(
+                            n_rep, spe, n_steps, resume_from, checkpointer,
+                            _disp,
+                            lambda: {"theta": theta, "opt_state": opt_state},
+                            ckpt_meta,
+                            epochs_per_dispatch=p.epochs_per_dispatch,
+                            every_epochs=ckpt_epochs,
+                        )
+                        if last is not None:
+                            last_loss = last
+                    else:
                         theta, opt_state, chunk_losses = \
                             _hashed_replay_epochs(
-                                theta, opt_state, stacks, salts, reg, lr,
-                                l1, n_epochs=n_ep, **static_kw,
+                                theta, opt_state, stacks, salts, reg, lr, l1,
+                                n_epochs=n_rep, **static_kw,
                             )
-                        return chunk_losses[-1, -1]
-
-                    n_steps, last, _ = run_epoch_replay(
-                        n_rep, spe, n_steps, resume_from, checkpointer,
-                        _disp,
-                        lambda: {"theta": theta, "opt_state": opt_state},
-                        ckpt_meta,
-                        epochs_per_dispatch=p.epochs_per_dispatch,
-                        every_epochs=ckpt_epochs,
-                    )
-                    if last is not None:
-                        last_loss = last
-                else:
-                    theta, opt_state, chunk_losses = _hashed_replay_epochs(
-                        theta, opt_state, stacks, salts, reg, lr, l1,
-                        n_epochs=n_rep, **static_kw,
-                    )
-                    count_dispatch()   # one-shot fused scan: no loop ticks
-                    last_loss = chunk_losses[-1, -1]
-                    n_steps += n_rep * spe
-                del stacks
-                prof.ledger_release("replay_plans", rp_key)
-                t_bar = time.perf_counter()
-                jax.block_until_ready(last_loss)
-                # this block drains the WHOLE fused replay — it is the
+                        count_dispatch()  # one-shot fused scan: no loop ticks
+                        last_loss = chunk_losses[-1, -1]
+                        n_steps += n_rep * spe
+                    del stacks
+                    prof.ledger_release("replay_plans", rp_key)
+                    with stage("replay_drain") as drained:
+                        jax.block_until_ready(last_loss)
+                # the drain blocks on the WHOLE fused replay — it is the
                 # one place the driver observes the replay's device
                 # compute, so it charges device_compute, not sync_wait
-                prof.note_sync(time.perf_counter() - t_bar)
-                replay_fused_s = time.perf_counter() - t_rep
+                prof.note_sync(drained.seconds)
+                replay_fused_s = stacked.seconds + replayed.seconds
                 if acc is not None:
-                    acc.epoch_boundary(
-                        p.epochs - 1,
-                        encode_s=pipe_stats.encode_s
-                        + (times or {}).get("plan_s", 0.0))
+                    acc.epoch_boundary(p.epochs - 1,
+                                       encode_s=pipe_stats.encode_s)
                 if times is not None:
                     epoch_walls.append(replay_fused_s)
                 break
@@ -1975,17 +1985,23 @@ class StreamingHashedLinearEstimator(Estimator):
             spill.delete()
         # fused replay breaks out past the per-epoch guard: final check
         # (loss AND theta — a last-step divergence only shows in theta)
-        check_finite_training(
-            last_loss, theta, epoch=p.epochs - 1, chunk=n_steps,
-            final=True, estimator="StreamingHashedLinearEstimator")
+        with span("finite_check", final=True):
+            check_finite_training(
+                last_loss, theta, epoch=p.epochs - 1, chunk=n_steps,
+                final=True, estimator="StreamingHashedLinearEstimator")
         if is_sparse_update(optim_resolved):
             # settle the lazy decay the table still owes (rows untouched
             # since their last step) so the returned model equals the
             # dense schedule's — predictions/serving read theta directly
-            theta = finalize_lazy_decay(
-                theta, opt_state, p.step_size, p.reg_param, optim_resolved)
+            with span("finalize"):
+                theta = finalize_lazy_decay(
+                    theta, opt_state, p.step_size, p.reg_param,
+                    optim_resolved)
         if times is not None:
             st = dict(times)
+            # the "encode" spans feed the pipeline's counter (the goodput
+            # accountant reads it with OTPU_OBS off too): one sum, two readers
+            st["encode_s"] = pipe_stats.encode_s
             # the resolved lowerings, so A/B records are self-describing
             # (the 'auto' decisions are otherwise invisible post-hoc)
             st["emb_update"] = static_kw["emb_update"]
@@ -2052,8 +2068,7 @@ class StreamingHashedLinearEstimator(Estimator):
         # cross-check it against the legacy cache_bytes stage key
         prof.attach_fit_report(
             report, acc,
-            encode_s=pipe_stats.encode_s + (times or {}).get("plan_s", 0.0),
-            cache_key=cache.ledger_key)
+            encode_s=pipe_stats.encode_s, cache_key=cache.ledger_key)
         if report is not None:
             model.run_report_ = report.add(n_steps=n_steps).finish()
         if checkpointer is not None:

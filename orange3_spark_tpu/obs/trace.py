@@ -31,13 +31,18 @@ Design constraints, in order:
   nesting is by time containment per thread, the viewer convention, and
   flow arrows render from the ``s``/``t``/``f`` events.
 * **device alignment** — when recording, each span also enters a
-  ``jax.profiler.TraceAnnotation``, so running a fit under
-  ``utils.profiling.profile_trace`` shows the SAME host span names lined
-  up against the XLA device timeline.
+  ``jax.profiler.TraceAnnotation`` named ``otpu:<span name>``, so running
+  a fit under a profiler shows the SAME host spans lined up against the
+  XLA device timeline, and a reduction of the ``.xplane.pb`` picks the
+  program's spans out by that prefix (ring names stay bare).
+* **one clock for stage seconds** — :func:`stage` is a span whose
+  duration is also added to a caller's accumulator, so a fit's
+  ``stage_times`` are sums of span durations and not a second timer.
 
 Span taxonomy (docs/observability.md): ``fit`` ⊃ ``epoch`` ⊃ ``chunk`` ⊃
-``dispatch`` for the streaming estimators, ``prefetch`` on the pipeline
-worker thread, ``serve``/``mb_flush``/``serve_dispatch`` on the serving
+``dispatch`` for the streaming estimators, ``prefetch`` ⊃ ``parse`` /
+``encode`` / ``h2d`` on the pipeline worker thread and ``input_wait`` on
+its consumer, ``serve``/``mb_flush``/``serve_dispatch`` on the serving
 path, ``timed:*`` for ``@timed`` functions; instants ``retry``/``fault``/
 ``wedge``/``crc_failure``/``shed``/``divergence``/``brownout`` from the
 resilience subsystem; flows ``req`` across the micro-batcher's threads.
@@ -63,6 +68,7 @@ from orange3_spark_tpu.obs import context as _context
 from orange3_spark_tpu.utils import knobs
 
 __all__ = [
+    "ANNOTATION_PREFIX",
     "clear",
     "enabled",
     "events",
@@ -80,6 +86,7 @@ __all__ = [
     "span",
     "span_iter",
     "spans_payload",
+    "stage",
     "validate_chrome_trace",
 ]
 
@@ -92,6 +99,9 @@ _span_ids = itertools.count(1)
 
 # TraceAnnotation is a cheap TraceMe when no profiler is active
 _ANNOTATION = jax.profiler.TraceAnnotation
+#: the twin annotation's name is this + the ring name: what a reduction of
+#: a profiler trace selects the program's spans by
+ANNOTATION_PREFIX = "otpu:"
 
 
 def enabled() -> bool:
@@ -181,12 +191,16 @@ def flush_buffered(evs: list) -> None:
 
 class _NullSpan:
     __slots__ = ()
+    seconds = 0.0
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
         return False
+
+    def note(self, **args) -> None:
+        pass
 
 
 _NULL = _NullSpan()
@@ -228,7 +242,7 @@ def _open_stack() -> list:
 
 class _Span:
     __slots__ = ("name", "args", "t0", "ann", "uniq",
-                 "trace_id", "span_id", "parent_id", "_buf")
+                 "trace_id", "span_id", "parent_id", "_buf", "seconds")
 
     def __init__(self, name: str, args: dict | None, uniq: bool = False):
         self.name = name
@@ -240,6 +254,7 @@ class _Span:
         self.span_id = None
         self.parent_id = None
         self._buf = None
+        self.seconds = 0.0      # the recorded duration, once exited
 
     def __enter__(self):
         if self.uniq:
@@ -255,14 +270,20 @@ class _Span:
         self.parent_id = st[-1].span_id if st else None
         self.span_id = next(_span_ids)
         st.append(self)
-        self.ann = _ANNOTATION(self.name)
+        self.ann = _ANNOTATION(ANNOTATION_PREFIX + self.name)
         self.ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
+    def note(self, **args) -> None:
+        """Add args learned inside the span (before it exits)."""
+        self.args = {**(self.args or {}), **args}
+
     def __exit__(self, *exc):
         t0 = self.t0
-        _record("X", self.name, t0, time.perf_counter_ns() - t0, self.args,
+        dur_ns = time.perf_counter_ns() - t0
+        self.seconds = dur_ns * 1e-9
+        _record("X", self.name, t0, dur_ns, self.args,
                 trace_id=self.trace_id, span_id=self.span_id,
                 parent_id=self.parent_id, buffer=self._buf)
         st = getattr(_TLS, "stack", None)
@@ -296,6 +317,67 @@ def span(name: str, index=None, unique: bool = False, **args):
     if index is not None:
         args["i"] = index
     return _Span(name, args or None, uniq=unique)
+
+
+def _accumulate(into, key: str, seconds: float) -> None:
+    if into is None:
+        return
+    if isinstance(into, dict):
+        into[key] = into.get(key, 0.0) + seconds
+    else:
+        setattr(into, key, getattr(into, key) + seconds)
+
+
+class _Stage(_Span):
+    """A span that also hands its duration on (see :func:`stage`)."""
+
+    __slots__ = ("into", "key")
+
+    def __init__(self, name: str, args: dict | None, into, key):
+        super().__init__(name, args)
+        self.into, self.key = into, key
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        _accumulate(self.into, self.key, self.seconds)
+        return False
+
+
+class _Timer:
+    """:func:`stage` with obs off: the same clock, nothing recorded."""
+
+    __slots__ = ("into", "key", "seconds", "t0")
+
+    def __init__(self, into, key):
+        self.into, self.key, self.seconds = into, key, 0.0
+
+    def __enter__(self):
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = (time.perf_counter_ns() - self.t0) * 1e-9
+        _accumulate(self.into, self.key, self.seconds)
+        return False
+
+    def note(self, **args) -> None:
+        pass
+
+
+def stage(name: str, into=None, key: str | None = None, index=None, **args):
+    """A :func:`span` whose duration is ALSO added to ``into[key]`` (a
+    dict entry, or the attribute ``key`` of an object such as
+    ``PipelineStats``) and left on the returned object as ``.seconds``
+    once it has exited — so the stage seconds a fit reports are the sum
+    of the durations of the spans it recorded, read off one clock.
+    Unlike ``span`` it always times: with obs off nothing is recorded
+    and the accumulator still fills (a caller that wants no timing at
+    all then does not call it)."""
+    if not _enabled:
+        return _Timer(into, key)
+    if index is not None:
+        args["i"] = index
+    return _Stage(name, args or None, into, key)
 
 
 def span_iter(name: str, iterable: Iterable) -> Iterator:

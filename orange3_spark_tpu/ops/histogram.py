@@ -88,20 +88,23 @@ def _hist_pallas(B, S, pos, *, nodes: int, n_bins: int, interpret: bool = False)
         K = jnp.pad(K, ((0, 0), (0, n_pad - N)))
         St = jnp.pad(St, ((0, 0), (0, n_pad - N)))
     kernel = functools.partial(_hist_kernel, d=d, nb=nb)
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((d, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((s, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
-        ],
-        # every grid step maps to the SAME output block: VMEM-resident
-        # accumulator, flushed to HBM after the last step
-        out_specs=pl.BlockSpec((d, s, nb), lambda i: (0, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((d, s, nb), jnp.float32),
-        interpret=interpret,
-    )(K, St)
+    with jax.named_scope("hist/kernel"):
+        out = pl.pallas_call(
+            kernel,
+            grid=(n_blocks,),
+            in_specs=[
+                pl.BlockSpec((d, blk), lambda i: (0, i),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((s, blk), lambda i: (0, i),
+                             memory_space=pltpu.VMEM),
+            ],
+            # every grid step maps to the SAME output block: VMEM-resident
+            # accumulator, flushed to HBM after the last step
+            out_specs=pl.BlockSpec((d, s, nb), lambda i: (0, 0, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((d, s, nb), jnp.float32),
+            interpret=interpret,
+        )(K, St)
     return out.transpose(0, 2, 1)                  # [d, nb, s] like the XLA path
 
 
@@ -126,4 +129,5 @@ def node_histograms(B, S, pos, *, nodes: int, n_bins: int):
         return _hist_pallas(B, S, pos, nodes=nodes, n_bins=n_bins)
     if backend == "pallas-interpret":  # CPU correctness testing of the kernel
         return _hist_pallas(B, S, pos, nodes=nodes, n_bins=n_bins, interpret=True)
-    return _hist_xla(B, S, pos, nodes=nodes, n_bins=n_bins)
+    with jax.named_scope("hist/xla"):
+        return _hist_xla(B, S, pos, nodes=nodes, n_bins=n_bins)

@@ -419,17 +419,20 @@ def _touched_rows_update(kind, emb, t, slots, sums, rid, lr, decay, reg, l1,
     lazy decay and the rule — the core both lowerings share. ``rid`` is
     the [U] touched-row list (-1 on dead slots; gathers clamp, writeback
     masks). Returns the updated [U, k] rows/slot rows and timestamps."""
-    rsafe = jnp.maximum(rid, 0)
-    p_rows = jnp.take(emb, rsafe, axis=0)
-    slot_rows = {n: jnp.take(v, rsafe, axis=0) for n, v in slots.items()}
-    if use_decay:
-        t_rows = jnp.take(t, rsafe)
-        # catch-up for the steps the row sat untouched, PLUS this step's
-        # own decay: (1-lr*reg)^(step+1-t) — the exact product the dense
-        # schedule applies one factor at a time
-        fac = jnp.power(decay, (step + 1 - t_rows).astype(jnp.float32))
-        p_rows = p_rows * fac[:, None]
-    return apply_rule(kind, p_rows, slot_rows, sums, lr, reg, l1)
+    with jax.named_scope("step/gather"):
+        rsafe = jnp.maximum(rid, 0)
+        p_rows = jnp.take(emb, rsafe, axis=0)
+        slot_rows = {n: jnp.take(v, rsafe, axis=0) for n, v in slots.items()}
+        if use_decay:
+            t_rows = jnp.take(t, rsafe)
+    with jax.named_scope("step/rule"):
+        if use_decay:
+            # catch-up for the steps the row sat untouched, PLUS this
+            # step's own decay: (1-lr*reg)^(step+1-t) — the exact product
+            # the dense schedule applies one factor at a time
+            fac = jnp.power(decay, (step + 1 - t_rows).astype(jnp.float32))
+            p_rows = p_rows * fac[:, None]
+        return apply_rule(kind, p_rows, slot_rows, sums, lr, reg, l1)
 
 
 def _segment_sums(g_sorted, seg, n_slots: int):
@@ -454,27 +457,34 @@ def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
     scatter-free except the one sorted segment-sum.
     'sort': everything derived in-jit (argsort + cumsum-of-boundaries);
     writeback is a sorted unique scatter with out-of-range dead slots
-    dropped."""
+    dropped.
+
+    Phases, as ``jax.named_scope``s a device trace is read by:
+    ``step/sort`` ('sort' only), ``step/segment``, ``step/gather``,
+    ``step/rule``, ``step/scatter`` (the write-back of either lowering)."""
     D = emb.shape[0]
     if lowering == "plan":
-        g = jnp.take(dl, plan["row"], axis=0)             # [M, k]
-        if "val" in plan:
-            g = g * plan["val"][:, None]
-        U = plan["uniq"].shape[0]
-        sums = _segment_sums(g, plan["seg"], U)
-        rid = plan["uniq"]
+        with jax.named_scope("step/segment"):
+            g = jnp.take(dl, plan["row"], axis=0)             # [M, k]
+            if "val" in plan:
+                g = g * plan["val"][:, None]
+            U = plan["uniq"].shape[0]
+            sums = _segment_sums(g, plan["seg"], U)
+            rid = plan["uniq"]
         p_rows, slot_rows = _touched_rows_update(
             kind, emb, t, slots, sums, rid, lr, decay, reg, l1, step,
             use_decay=use_decay)
-        inv = plan["inv"]
-        sel = inv >= 0
-        isafe = jnp.maximum(inv, 0)
-        emb = jnp.where(sel[:, None], jnp.take(p_rows, isafe, axis=0), emb)
-        slots = {n: jnp.where(sel[:, None], jnp.take(v, isafe, axis=0),
-                              slots[n])
-                 for n, v in slot_rows.items()}
-        if use_decay:
-            t = jnp.where(sel, step + 1, t)
+        with jax.named_scope("step/scatter"):
+            inv = plan["inv"]
+            sel = inv >= 0
+            isafe = jnp.maximum(inv, 0)
+            emb = jnp.where(sel[:, None], jnp.take(p_rows, isafe, axis=0),
+                            emb)
+            slots = {n: jnp.where(sel[:, None], jnp.take(v, isafe, axis=0),
+                                  slots[n])
+                     for n, v in slot_rows.items()}
+            if use_decay:
+                t = jnp.where(sel, step + 1, t)
         return emb, t, slots
 
     if lowering != "sort":
@@ -482,32 +492,36 @@ def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
     N, C = idx.shape
     M = N * C
     U = plan_slots(N, C, D)
-    dead = occurrence_dead(N, C, n_valid, raw_cats)
-    flat = jnp.where(dead, jnp.int32(D), idx).reshape(-1)
-    order = jnp.argsort(flat)                             # stable sort
-    s_idx = jnp.take(flat, order)
-    g = jnp.take(dl, order // C, axis=0)
-    if vals is not None:
-        g = g * jnp.take(vals.reshape(-1), order)[:, None]
-    start = jnp.concatenate(
-        [jnp.ones((1,), bool), s_idx[1:] != s_idx[:-1]])
-    seg = jnp.cumsum(start.astype(jnp.int32)) - 1
-    sums = _segment_sums(g, seg, U)
-    # unique row id per segment slot: scatter the segment-start values;
-    # non-starts and the dead sentinel route out of range and drop
-    uniq = jnp.full((U,), -1, jnp.int32).at[
-        jnp.where(start & (s_idx < D), seg, U)
-    ].set(s_idx.astype(jnp.int32), mode="drop")
+    with jax.named_scope("step/sort"):
+        dead = occurrence_dead(N, C, n_valid, raw_cats)
+        flat = jnp.where(dead, jnp.int32(D), idx).reshape(-1)
+        order = jnp.argsort(flat)                         # stable sort
+        s_idx = jnp.take(flat, order)
+    with jax.named_scope("step/segment"):
+        g = jnp.take(dl, order // C, axis=0)
+        if vals is not None:
+            g = g * jnp.take(vals.reshape(-1), order)[:, None]
+        start = jnp.concatenate(
+            [jnp.ones((1,), bool), s_idx[1:] != s_idx[:-1]])
+        seg = jnp.cumsum(start.astype(jnp.int32)) - 1
+        sums = _segment_sums(g, seg, U)
+        # unique row id per segment slot: scatter the segment-start
+        # values; non-starts and the dead sentinel route out of range and
+        # drop
+        uniq = jnp.full((U,), -1, jnp.int32).at[
+            jnp.where(start & (s_idx < D), seg, U)
+        ].set(s_idx.astype(jnp.int32), mode="drop")
     p_rows, slot_rows = _touched_rows_update(
         kind, emb, t, slots, sums, uniq, lr, decay, reg, l1, step,
         use_decay=use_decay)
-    wb = jnp.where(uniq >= 0, uniq, D)                    # D drops
-    sc = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
-    emb = emb.at[wb].set(p_rows, **sc)
-    slots = {n: slots[n].at[wb].set(v, **sc)
-             for n, v in slot_rows.items()}
-    if use_decay:
-        t = t.at[wb].set(step + 1, **sc)
+    with jax.named_scope("step/scatter"):
+        wb = jnp.where(uniq >= 0, uniq, D)                # D drops
+        sc = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
+        emb = emb.at[wb].set(p_rows, **sc)
+        slots = {n: slots[n].at[wb].set(v, **sc)
+                 for n, v in slot_rows.items()}
+        if use_decay:
+            t = t.at[wb].set(step + 1, **sc)
     return emb, t, slots
 
 
@@ -530,6 +544,7 @@ def finalize_lazy_decay(theta: dict, state: dict, lr: float, reg: float,
 
 
 @jax.jit
+@jax.named_scope("finalize/decay")
 def _finalize_emb(emb, t, step, decay):
     fac = jnp.power(decay, (step - t).astype(jnp.float32))
     return emb * fac[:, None]

@@ -56,7 +56,7 @@ def bound_dispatch(step: int, token, period: int = DISPATCH_SYNC_PERIOD) -> None
     count_dispatch()
     if step % period == 0:
         from orange3_spark_tpu.obs.prof import note_sync
-        from orange3_spark_tpu.obs.trace import span
+        from orange3_spark_tpu.obs.trace import stage
         from orange3_spark_tpu.resilience.watchdog import maybe_guarded_block
 
         # the one place a step loop blocks on the device: a "dispatch"
@@ -65,8 +65,7 @@ def bound_dispatch(step: int, token, period: int = DISPATCH_SYNC_PERIOD) -> None
         # blocked seconds feed the goodput accountant as device_compute
         # — the driver only ever observes device pace by blocking here
         # (obs/prof.py; a bare contextvar read when no fit is live)
-        with span("dispatch", step):
-            t0 = time.perf_counter()
+        with stage("dispatch", index=step) as blocked:
             maybe_guarded_block(token, step=step)
-            note_sync(time.perf_counter() - t0)
+        note_sync(blocked.seconds)
         beat()
