@@ -68,9 +68,10 @@ from orange3_spark_tpu.ops.hashing import (
     column_salts, hash_columns, hash_columns_np,
 )
 from orange3_spark_tpu.optim.sparse import (
-    build_plan_np, dense_update, finalize_lazy_decay, init_optim_state,
-    is_sparse_update, optim_kind, pack_plan_np, plan_field_shapes,
-    plan_packed_field_shapes, resolve_optim_update, resolve_sparse_lowering,
+    adopt_optim_state, build_plan_np, dense_update, finalize_lazy_decay,
+    init_optim_state, is_sparse_update, note_slot_blocks, optim_kind,
+    pack_plan_np, plan_field_shapes, plan_packed_field_shapes,
+    resolve_optim_update, resolve_sparse_lowering, slot_blocks,
     sparse_embedding_update, unpack_plan,
 )
 from orange3_spark_tpu.obs import prof
@@ -456,7 +457,7 @@ def _step_core(
         # concern and only apply to the dense paths)
         logits = forward(theta, "fused")
         loss, dl = jax.value_and_grad(data_loss)(logits)
-        emb, t, eslots = sparse_embedding_update(
+        emb, t, eslots, n_blocks = sparse_embedding_update(
             kind, theta["emb"], opt_state["t"], slots["emb"], dl, idx,
             lr, decay, reg, l1, step, lowering=sparse_lowering,
             use_decay=use_decay, plan=plan, n_valid=n_valid,
@@ -477,6 +478,7 @@ def _step_core(
         loss, g = jax.value_and_grad(
             lambda theta: data_loss(forward(theta, emb_update)))(theta)
         t = opt_state["t"]
+        n_blocks = 0
         emb, eslots = dense_update(
             kind, theta["emb"], slots["emb"], g["emb"], lr, decay, reg, l1,
             use_decay=use_decay)
@@ -489,7 +491,8 @@ def _step_core(
             kind, theta["intercept"], slots["intercept"], g_int, lr, decay,
             reg, l1, use_decay=False)    # reg never touched the intercept
     theta = {"emb": emb, "coef": coef, "intercept": intercept}
-    opt_state = {"step": step + 1, "t": t,
+    opt_state = {"step": step + 1, "blocks": opt_state["blocks"] + n_blocks,
+                 "t": t,
                  "slots": {"emb": eslots, "coef": cslots,
                            "intercept": islots}}
     return theta, opt_state, loss
@@ -1349,14 +1352,22 @@ class StreamingHashedLinearEstimator(Estimator):
         # epoch-cadence snapshots (checkpoint_every_epochs): the shared
         # arming rule — see StreamingLinearParams for the contract
         ckpt_epochs = resolve_epoch_checkpointing(p, checkpointer)
+        # the 'sort' lowering's block count rides opt_state; where this
+        # fit's own steps start counting (a resumed fit starts above zero)
+        counted_from = (0, 0)
         if checkpointer is not None:
             step0, saved = checkpointer.load(expect_meta=ckpt_meta)
             if saved is not None:
                 theta = jax.tree.map(jnp.asarray, saved["theta"])
+                saved_opt = saved["opt_state"]
+                if isinstance(opt_state, dict):
+                    saved_opt = adopt_optim_state(saved_opt)
+                    counted_from = (int(saved_opt["step"]),
+                                    int(saved_opt["blocks"]))
                 opt_state = jax.tree.map(
                     lambda tmpl, v: jnp.asarray(v)
                     if isinstance(tmpl, (jax.Array, np.ndarray)) else v,
-                    opt_state, saved["opt_state"],
+                    opt_state, saved_opt,
                 )
                 resume_from = step0
 
@@ -2049,6 +2060,15 @@ class StreamingHashedLinearEstimator(Estimator):
         )
         model.n_steps_ = n_steps
         model.final_loss_ = float(last_loss) if last_loss is not None else None
+        if static_kw["sparse_lowering"] == "sort":
+            # the loss above has waited for the last step, so these two
+            # scalars of the same program are ready: no wait of their own
+            steps, blocks = jax.device_get(
+                (opt_state["step"], opt_state["blocks"]))
+            note_slot_blocks(
+                int(blocks) - counted_from[1],
+                (int(steps) - counted_from[0])
+                * slot_blocks(pad_rows, p.n_cat, p.n_dims))
         model.device_chunks_ = cache.batches if cache_device else None
         model.holdout_chunks_ = holdout if holdout_chunks > 0 else None
         model.cache_codec_ = codec   # evaluate_device's decode key

@@ -152,13 +152,18 @@ class IncrementalTrainer:
     def _maybe_resume(self) -> None:
         import jax.numpy as jnp
 
+        from orange3_spark_tpu.optim.sparse import adopt_optim_state
+
         step, state = self.ckpt.load(expect_meta=self._meta())
         if state is None:
             return
         with self._lock:
             self.theta = {k: jnp.asarray(v)
                           for k, v in state["theta"].items()}
-            self.opt_state = _host_to_device(state["opt"])
+            opt = state["opt"]
+            if isinstance(opt, dict):    # adam keeps its optax tuple
+                opt = adopt_optim_state(opt)
+            self.opt_state = _host_to_device(opt)
             self.offset = int(state["offset"])
             self.steps = int(step)
             self.examples = int(state["examples"])

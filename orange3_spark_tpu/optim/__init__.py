@@ -8,16 +8,19 @@ from orange3_spark_tpu.optim.sparse import (  # noqa: F401
     FTRL_BETA,
     OPTIM_UPDATES,
     SPARSE_UPDATES,
+    adopt_optim_state,
     apply_rule,
     build_plan_np,
     dense_update,
     finalize_lazy_decay,
     init_optim_state,
     is_sparse_update,
+    note_slot_blocks,
     occurrence_dead,
     optim_kind,
     plan_field_shapes,
     plan_slots,
+    slot_blocks,
     resolve_optim_update,
     resolve_sparse_lowering,
     sparse_embedding_update,

@@ -47,9 +47,14 @@ This module is the one home of that machinery:
     (``where(touched, new_rows[inv], emb)``) — no unsorted scatter
     anywhere. Default on CPU.
   - ``'sort'`` — the ISSUE-classic in-step form: ``argsort`` + segment
-    ids by ``cumsum`` of boundaries, writeback by a sorted unique
-    scatter. No per-chunk auxiliary memory; the sort is cheap on TPU.
-    Default on TPU.
+    ids by ``cumsum`` of boundaries, then gather -> rule -> sorted unique
+    scatter over the LIVE prefix of the slots only, ``SLOT_BLOCK`` slots
+    a loop trip. No per-chunk auxiliary memory. Default on TPU, where
+    HBM is the scarce resource. What the chip read at 2^29 rows and
+    6.8M occurrences a step (v5e, PERF.md §5): the sort is 0.06 s of a
+    step — not "cheap", but never the first cost; the table-wide gathers
+    were 0.42 s while they ran over the 6.8M-slot static bound, and cost
+    per INDEX (~14 ns), not per distinct row — hence the live prefix.
 
 * **kill-switch** — ``OTPU_SPARSE_UPDATE=0`` resolves every ``sparse_*``
   rule to its ``dense_*`` twin (mirroring ``OTPU_DONATE``'s convention):
@@ -72,15 +77,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from orange3_spark_tpu.obs.registry import REGISTRY
+
 __all__ = [
     "OPTIM_UPDATES", "SPARSE_UPDATES", "DENSE_UPDATES",
     "sparse_updates_enabled", "resolve_optim_update",
     "resolve_sparse_lowering", "optim_kind", "is_sparse_update",
-    "init_optim_state", "plan_slots", "build_plan_np", "plan_field_shapes",
+    "init_optim_state", "adopt_optim_state", "plan_slots", "slot_blocks",
+    "build_plan_np", "plan_field_shapes",
     "plan_pack_widths", "plan_packed_field_shapes", "pack_plan_np",
     "unpack_plan",
     "occurrence_dead", "apply_rule", "dense_update",
-    "sparse_embedding_update", "finalize_lazy_decay",
+    "sparse_embedding_update", "note_slot_blocks", "finalize_lazy_decay",
 ]
 
 SPARSE_UPDATES = ("sparse_sgd", "sparse_adagrad", "sparse_ftrl")
@@ -92,6 +100,22 @@ OPTIM_UPDATES = ("adam",) + DENSE_UPDATES + SPARSE_UPDATES
 ADAGRAD_EPS = 1e-10
 #: FTRL-proximal beta (McMahan et al. 2013); alpha is the fit's step_size.
 FTRL_BETA = 1.0
+#: slots one trip of the 'sort' lowering's gather -> rule -> write-back
+#: loop takes. What the chip read (v5e, 2^29-row tables, PERF.md §6 PR 27):
+#: a gather costs ~14 ns an index and nothing else, but a scatter costs
+#: ~7 ms a CALL — a pass over the 2 GB table, whatever it writes, in every
+#: form XLA was given — plus ~5 ns an index. A trip therefore pays ~21 ms
+#: for its three write-backs, and the last, partly live block wastes ~55 ns
+#: a dead slot: the sum is least near sqrt(0.76e6 x n_live) slots, 2^20
+#: for the ~0.93M live slots of a 2^18-row Criteo chunk (1 trip of 7).
+SLOT_BLOCK = 1 << 20
+
+_M_SLOT_BLOCKS = REGISTRY.counter(
+    "otpu_sparse_slot_blocks_total",
+    "'sort'-lowering slot blocks of finished fits: which=run (trips the "
+    "steps took, from the chunks' live slots) | possible (steps x "
+    "slot_blocks, the static bound). run/possible = share of the slot "
+    "bound the steps really gathered, updated and wrote back")
 
 
 def sparse_updates_enabled() -> bool:
@@ -118,11 +142,14 @@ def resolve_optim_update(value: str) -> str:
 
 
 def resolve_sparse_lowering(value: str) -> str:
-    """'auto' picks the measured-best dedup lowering per backend:
-    ``'plan'`` (host-presorted, gather-based writeback) on CPU where an
-    in-step 6.8M-element sort costs seconds and unsorted scatters ~240
+    """'auto' picks the dedup lowering per backend: ``'plan'``
+    (host-presorted, gather-based writeback) on CPU where an in-step
+    6.8M-element sort costs seconds and unsorted scatters ~240
     ns/element; ``'sort'`` (in-step argsort, zero per-chunk aux memory)
-    on TPU where the sort is ~ms and HBM is the scarce resource."""
+    on TPU where HBM is the scarce resource — 'plan' keeps an O(n_dims)
+    inverse map per cached chunk. On a v5e at 2^29 rows the in-step sort
+    reads 0.06 s of a step (PERF.md §5); 'plan' has not been timed on the
+    chip, so 'sort' is the default there, not a measured best."""
     if value == "auto":
         return "sort" if jax.default_backend() == "tpu" else "plan"
     if value not in ("plan", "sort"):
@@ -155,20 +182,32 @@ def _rule_slots(kind: str, param):
 def init_optim_state(resolved: str, theta: dict) -> dict:
     """Fresh optimizer state for a non-adam rule: a global step counter,
     the per-row last-seen step vector ``t`` (the lazy-decay timestamps;
-    zeros and unused for dense twins and ftrl), and per-parameter slot
-    dicts. ``zeros_like`` inherits each parameter's GSPMD placement, so a
-    model-axis-sharded table gets sharded slots/timestamps for free."""
+    zeros and unused for dense twins and ftrl), per-parameter slot dicts,
+    and ``blocks`` — the slot blocks the 'sort' lowering has run so far
+    (``sparse_embedding_update``; stays 0 under every other path), summed
+    on the device and read once at fit end. ``zeros_like`` inherits each
+    parameter's GSPMD placement, so a model-axis-sharded table gets
+    sharded slots/timestamps for free."""
     kind = optim_kind(resolved)
     if kind == "adam":
         raise ValueError("'adam' keeps its optax state; no optim state here")
     emb = theta["emb"]
     return {
         "step": jnp.int32(0),
+        "blocks": jnp.int32(0),
         # timestamps ride a column slice of zeros_like(emb) so they share
         # the table's sharding (P('model') rows under model parallelism)
         "t": jnp.zeros_like(emb[:, 0], dtype=jnp.int32),
         "slots": {name: _rule_slots(kind, p) for name, p in theta.items()},
     }
+
+
+def adopt_optim_state(saved: dict) -> dict:
+    """A checkpointed non-adam optimizer state as this program's steps
+    read it — the one place every resume path (``fit_stream``, the online
+    trainer) passes a snapshot through. A snapshot written before the
+    state carried ``blocks`` resumes counting from zero."""
+    return {"blocks": np.zeros((), np.int32), **saved}
 
 
 # --------------------------------------------------------------- the rules
@@ -215,6 +254,22 @@ def plan_slots(pad_rows: int, n_cat: int, n_dims: int) -> int:
     that absorbs the dead-occurrence segment (padding rows / vw idx=-1):
     live segments can number at most min(occurrences, table rows)."""
     return min(pad_rows * n_cat, n_dims) + 1
+
+
+def slot_blocks(pad_rows: int, n_cat: int, n_dims: int) -> int:
+    """Blocks of ``SLOT_BLOCK`` slots that cover a chunk's static slot
+    bound: the most trips one 'sort'-lowering step can take (a chunk of
+    all-distinct keys), and the denominator of ``otpu_sparse_slot_blocks``.
+    A bound under one block is one block of its own size."""
+    U = plan_slots(pad_rows, n_cat, n_dims)
+    return -(-U // min(SLOT_BLOCK, U))
+
+
+def note_slot_blocks(run: int, possible: int) -> None:
+    """Add one finished fit's block counts to the registry (the fit reads
+    them off ``opt_state`` where it already waits for its last loss)."""
+    _M_SLOT_BLOCKS.inc(run, which="run")
+    _M_SLOT_BLOCKS.inc(possible, which="possible")
 
 
 def plan_field_shapes(pad_rows: int, n_cat: int, n_dims: int,
@@ -417,8 +472,9 @@ def _touched_rows_update(kind, emb, t, slots, sums, rid, lr, decay, reg, l1,
                          step, *, use_decay):
     """Gather the touched rows (+ slots, + timestamps), apply catch-up
     lazy decay and the rule — the core both lowerings share. ``rid`` is
-    the [U] touched-row list (-1 on dead slots; gathers clamp, writeback
-    masks). Returns the updated [U, k] rows/slot rows and timestamps."""
+    a touched-row list (-1 on dead slots; gathers clamp, writeback
+    masks): the plan's whole [U] under 'plan', one block of the live
+    prefix under 'sort'. Returns the updated rows and slot rows."""
     with jax.named_scope("step/gather"):
         rsafe = jnp.maximum(rid, 0)
         p_rows = jnp.take(emb, rsafe, axis=0)
@@ -444,6 +500,39 @@ def _segment_sums(g_sorted, seg, n_slots: int):
         seg].add(g_sorted, indices_are_sorted=True)
 
 
+def _sorted_slots(dl, idx, n_dims: int, n_slots: int, n_valid, raw_cats,
+                  vals):
+    """The 'sort' lowering's in-jit dedup: sort the chunk's hashed
+    occurrences (dead ones behind the sentinel ``n_dims``), number the
+    segments in sorted order and sum each one's gradients. Returns
+    ``sums`` [n_slots, k], ``uniq`` [n_slots] (table row per segment,
+    -1 on dead/unused slots) and ``n_live``: the dead sentinel sorts last,
+    so the live slots are exactly the prefix ``[0, n_live)`` of both."""
+    N, C = idx.shape
+    with jax.named_scope("step/sort"):
+        dead = occurrence_dead(N, C, n_valid, raw_cats)
+        flat = jnp.where(dead, jnp.int32(n_dims), idx).reshape(-1)
+        order = jnp.argsort(flat)                         # stable sort
+        s_idx = jnp.take(flat, order)
+    with jax.named_scope("step/segment"):
+        g = jnp.take(dl, order // C, axis=0)
+        if vals is not None:
+            g = g * jnp.take(vals.reshape(-1), order)[:, None]
+        start = jnp.concatenate(
+            [jnp.ones((1,), bool), s_idx[1:] != s_idx[:-1]])
+        seg = jnp.cumsum(start.astype(jnp.int32)) - 1
+        sums = _segment_sums(g, seg, n_slots)
+        # unique row id per segment slot: scatter the segment-start
+        # values; non-starts and the dead sentinel route out of range and
+        # drop
+        uniq = jnp.full((n_slots,), -1, jnp.int32).at[
+            jnp.where(start & (s_idx < n_dims), seg, n_slots)
+        ].set(s_idx.astype(jnp.int32), mode="drop")
+        # every segment but the dead one
+        n_live = seg[-1] + 1 - (s_idx[-1] >= n_dims).astype(jnp.int32)
+    return sums, uniq, n_live
+
+
 def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
                             step, *, lowering: str, use_decay: bool,
                             plan=None, n_valid=None, raw_cats=None,
@@ -455,13 +544,23 @@ def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
     unique rows / inverse map; writeback is a pure GATHER
     (``where(touched, new_rows[inv], emb)``) — the whole step is
     scatter-free except the one sorted segment-sum.
-    'sort': everything derived in-jit (argsort + cumsum-of-boundaries);
+    'sort': everything derived in-jit (argsort + cumsum-of-boundaries).
+    The slot arrays keep the static bound ``plan_slots`` (a chunk of
+    all-distinct keys fills it), but only their live prefix is gathered,
+    run through the rule and written back: a ``fori_loop`` over blocks of
+    ``SLOT_BLOCK`` slots whose trip count ``ceil(n_live / SLOT_BLOCK)`` is
+    computed on the device from the chunk's own keys. Each trip's
     writeback is a sorted unique scatter with out-of-range dead slots
-    dropped.
+    dropped; the tables are the loop's carries, updated in place.
+
+    Returns ``(emb, t, slots, n_blocks)``: ``n_blocks`` is the i32 count
+    of trips this update ran (0 under 'plan', which has no loop).
 
     Phases, as ``jax.named_scope``s a device trace is read by:
     ``step/sort`` ('sort' only), ``step/segment``, ``step/gather``,
-    ``step/rule``, ``step/scatter`` (the write-back of either lowering)."""
+    ``step/rule``, ``step/scatter`` (the write-back of either lowering);
+    under 'sort' the last three sit inside the block loop, so a trace
+    shows them once per trip (``.../while/body/step/gather/...``)."""
     D = emb.shape[0]
     if lowering == "plan":
         with jax.named_scope("step/segment"):
@@ -485,44 +584,41 @@ def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
                      for n, v in slot_rows.items()}
             if use_decay:
                 t = jnp.where(sel, step + 1, t)
-        return emb, t, slots
+        return emb, t, slots, jnp.int32(0)
 
     if lowering != "sort":
         raise ValueError(f"unknown sparse lowering {lowering!r}")
     N, C = idx.shape
-    M = N * C
     U = plan_slots(N, C, D)
-    with jax.named_scope("step/sort"):
-        dead = occurrence_dead(N, C, n_valid, raw_cats)
-        flat = jnp.where(dead, jnp.int32(D), idx).reshape(-1)
-        order = jnp.argsort(flat)                         # stable sort
-        s_idx = jnp.take(flat, order)
-    with jax.named_scope("step/segment"):
-        g = jnp.take(dl, order // C, axis=0)
-        if vals is not None:
-            g = g * jnp.take(vals.reshape(-1), order)[:, None]
-        start = jnp.concatenate(
-            [jnp.ones((1,), bool), s_idx[1:] != s_idx[:-1]])
-        seg = jnp.cumsum(start.astype(jnp.int32)) - 1
-        sums = _segment_sums(g, seg, U)
-        # unique row id per segment slot: scatter the segment-start
-        # values; non-starts and the dead sentinel route out of range and
-        # drop
-        uniq = jnp.full((U,), -1, jnp.int32).at[
-            jnp.where(start & (s_idx < D), seg, U)
-        ].set(s_idx.astype(jnp.int32), mode="drop")
-    p_rows, slot_rows = _touched_rows_update(
-        kind, emb, t, slots, sums, uniq, lr, decay, reg, l1, step,
-        use_decay=use_decay)
-    with jax.named_scope("step/scatter"):
-        wb = jnp.where(uniq >= 0, uniq, D)                # D drops
-        sc = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
-        emb = emb.at[wb].set(p_rows, **sc)
-        slots = {n: slots[n].at[wb].set(v, **sc)
-                 for n, v in slot_rows.items()}
-        if use_decay:
-            t = t.at[wb].set(step + 1, **sc)
-    return emb, t, slots
+    B = min(SLOT_BLOCK, U)
+    # the slot arrays are allocated at a whole number of blocks, so the
+    # last block's slice never has to be clamped back over its neighbour
+    # (the pad slots are -1 / zero: dead like any other)
+    sums, uniq, n_live = _sorted_slots(
+        dl, idx, D, slot_blocks(N, C, D) * B, n_valid, raw_cats, vals)
+    n_blocks = (n_live + (B - 1)) // B
+    sc = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
+
+    def block(i, tables):
+        emb, t, slots = tables
+        rid = jax.lax.dynamic_slice_in_dim(uniq, i * B, B)
+        p_rows, slot_rows = _touched_rows_update(
+            kind, emb, t, slots, jax.lax.dynamic_slice_in_dim(sums, i * B, B),
+            rid, lr, decay, reg, l1, step, use_decay=use_decay)
+        with jax.named_scope("step/scatter"):
+            wb = jnp.where(rid >= 0, rid, D)              # D drops
+            emb = emb.at[wb].set(p_rows, **sc)
+            slots = {n: slots[n].at[wb].set(v, **sc)
+                     for n, v in slot_rows.items()}
+            if use_decay:
+                t = t.at[wb].set(step + 1, **sc)
+        return emb, t, slots
+
+    # gather -> rule -> write-back over the live prefix only, B slots a
+    # trip, the trip count read off the chunk itself; the tables are the
+    # loop's carries and are updated in place
+    emb, t, slots = jax.lax.fori_loop(0, n_blocks, block, (emb, t, slots))
+    return emb, t, slots, n_blocks
 
 
 def finalize_lazy_decay(theta: dict, state: dict, lr: float, reg: float,

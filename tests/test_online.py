@@ -476,6 +476,50 @@ def test_trainer_crash_typed_then_checkpoint_resume_bitwise(ctr, session,
     log.close()
 
 
+def test_trainer_resumes_checkpoint_without_block_counter(session,
+                                                         tmp_path):
+    """A trainer snapshot written before the sparse rules' opt_state
+    carried 'blocks' (same online-trainer-v1 meta) must resume, to the
+    same candidate bitwise — the crash-recovery loop across an upgrade."""
+    import pickle
+
+    from orange3_spark_tpu.models.hashed_linear import (
+        StreamingHashedLinearEstimator,
+    )
+
+    rng = np.random.default_rng(11)
+    X = np.concatenate([
+        rng.standard_normal((768, 2)).astype(np.float32),
+        rng.integers(0, 50, (768, 2)).astype(np.float32),
+    ], axis=1)
+    y = (X[:, 0] > 0).astype(np.float32)
+    model = StreamingHashedLinearEstimator(
+        n_dims=1 << 8, n_dense=2, n_cat=2, epochs=1, step_size=0.05,
+        chunk_rows=CHUNK, optim_update="sparse_adagrad",
+    ).fit_stream(array_chunk_source(X, y, chunk_rows=CHUNK),
+                 session=session)
+    log = RequestLog(str(tmp_path / "req.log"))
+    _fill_log(log, X, y)                        # 6 steps worth
+    ref = _trainer(model, log, session, tmp_path / "ref.ck", ckpt_steps=2)
+    ref.consume_available()
+    crash = _trainer(model, log, session, tmp_path / "old.ck", ckpt_steps=2)
+    with inject_faults("trainer_crash:at=3"):
+        with pytest.raises(TrainerCrashInjected):
+            crash.consume_available()
+    with open(tmp_path / "old.ck", "rb") as f:
+        blob = pickle.load(f)
+    assert blob["state"]["opt"].pop("blocks") > 0
+    with open(tmp_path / "old.ck", "wb") as f:
+        pickle.dump(blob, f)
+    resumed = _trainer(model, log, session, tmp_path / "old.ck",
+                       ckpt_steps=2)
+    assert resumed.resumed_from_step == 2
+    resumed.consume_available()
+    assert resumed.status()["steps"] == 6
+    assert _theta_equal(resumed.candidate_model(), ref.candidate_model())
+    log.close()
+
+
 def test_trainer_thread_death_is_typed_not_a_hang(ctr, session, tmp_path):
     model, X, y = ctr
     log = RequestLog(str(tmp_path / "req.log"))

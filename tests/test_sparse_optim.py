@@ -12,6 +12,7 @@ replaces N per-step multiplies by one pow of the same factor, so the
 decay'd comparisons carry a slightly looser tolerance."""
 
 import os
+import zlib
 
 import numpy as np
 import pytest
@@ -23,11 +24,14 @@ from orange3_spark_tpu.io.streaming import array_chunk_source
 from orange3_spark_tpu.models.hashed_linear import (
     StreamingHashedLinearEstimator,
 )
+from orange3_spark_tpu.obs.registry import REGISTRY
 from orange3_spark_tpu.ops.hashing import (
     column_salts, hash_columns, hash_columns_np,
 )
+from orange3_spark_tpu.optim import sparse as sparse_mod
 from orange3_spark_tpu.optim.sparse import (
     build_plan_np, plan_slots, resolve_optim_update, resolve_sparse_lowering,
+    slot_blocks, sparse_embedding_update,
 )
 
 from tests.test_hashed_linear import _criteo_shaped
@@ -247,6 +251,117 @@ def test_value_weighted_idx_minus_one_inert(session):
                                    atol=1e-7)
 
 
+# ------------------------------------------- the 'sort' lowering's block loop
+
+def _unblocked_sort_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
+                           step, *, use_decay, n_valid, raw_cats, vals):
+    """The 'sort' lowering as it was before the block loop: one gather ->
+    rule -> write-back over the whole static slot bound. Kept here, not in
+    the package, as what the blocked form must equal bit for bit."""
+    D = emb.shape[0]
+    sums, uniq, _n_live = sparse_mod._sorted_slots(
+        dl, idx, D, plan_slots(*idx.shape, D), n_valid, raw_cats, vals)
+    p_rows, slot_rows = sparse_mod._touched_rows_update(
+        kind, emb, t, slots, sums, uniq, lr, decay, reg, l1, step,
+        use_decay=use_decay)
+    wb = jnp.where(uniq >= 0, uniq, D)
+    sc = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
+    emb = emb.at[wb].set(p_rows, **sc)
+    slots = {n: slots[n].at[wb].set(v, **sc) for n, v in slot_rows.items()}
+    if use_decay:
+        t = t.at[wb].set(step + 1, **sc)
+    return emb, t, slots
+
+
+_BLOCK = 8                        # the injected SLOT_BLOCK
+#: case -> (n_dims, n_valid, distinct live rows asked for); 12 rows x 4
+#: columns, 2 of the rows padding and 4 pairs dead by raw index < 0, so 36
+#: live occurrences. U = min(48, n_dims) + 1.
+_LIVE_CASES = {
+    "n_live=0": (64, 0, 0),
+    "n_live=1": (64, 10, 1),
+    "n_live=2B-1": (64, 10, 2 * _BLOCK - 1),
+    "n_live=2B": (64, 10, 2 * _BLOCK),
+    "n_live=2B+1": (64, 10, 2 * _BLOCK + 1),
+    # every table row touched: n_dims < occurrences, n_live = U - 1
+    "n_live=U-1": (30, 10, 30),
+}
+
+
+@pytest.mark.parametrize("case", list(_LIVE_CASES))
+@pytest.mark.parametrize("use_decay", [False, True], ids=["nodecay", "decay"])
+@pytest.mark.parametrize("kind", ["sgd", "adagrad", "ftrl"])
+def test_blocked_update_equals_unblocked_bitwise(monkeypatch, kind,
+                                                 use_decay, case):
+    """The block loop over the live prefix changes which slots are
+    visited, never what a live slot computes: emb, every slot table and t
+    equal the whole-bound form bit for bit, and the trip count is
+    ceil(n_live / SLOT_BLOCK) — with padding rows (n_valid < N) and
+    value-weighted dead pairs (raw index < 0) among the occurrences."""
+    monkeypatch.setattr(sparse_mod, "SLOT_BLOCK", _BLOCK)
+    D, n_valid, n_live = _LIVE_CASES[case]
+    N, C, k = 12, 4, 2
+    rng = np.random.default_rng(
+        zlib.crc32(f"{kind}/{use_decay}/{case}".encode()))
+    raw = rng.integers(0, 1000, (N, C)).astype(np.float32)
+    raw[rng.permutation(10)[:4], rng.integers(0, C, 4)] = -1.0   # vw dead
+    live = (np.arange(N)[:, None] < n_valid) & (raw >= 0)
+    idx = rng.integers(0, D, (N, C)).astype(np.int32)   # dead: anything
+    if n_live:
+        pool = rng.permutation(D)[:n_live]
+        # each pool row at least once, the rest drawn from the pool
+        occ = np.concatenate(
+            [pool, rng.choice(pool, int(live.sum()) - n_live)])
+        idx[live] = rng.permutation(occ)
+        assert len(set(idx[live].tolist())) == n_live
+    step = jnp.int32(7)
+    emb = jnp.asarray(rng.normal(size=(D, k)), jnp.float32)
+    t = jnp.asarray(rng.integers(0, 8, D), jnp.int32)
+    slots = {n: jnp.asarray(rng.uniform(0.1, 2.0, (D, k)), jnp.float32)
+             for n in {"sgd": (), "adagrad": ("acc",),
+                       "ftrl": ("z", "n")}[kind]}
+    dl = jnp.asarray(rng.normal(size=(N, k)), jnp.float32)
+    vals = jnp.asarray(rng.uniform(0.5, 1.5, (N, C)), jnp.float32)
+    args = (emb, t, slots, dl, jnp.asarray(idx), jnp.float32(0.05),
+            jnp.float32(1 - 0.05 * 1e-2), jnp.float32(1e-2),
+            jnp.float32(1e-3), step)
+    kw = dict(use_decay=use_decay, n_valid=jnp.int32(n_valid),
+              raw_cats=jnp.asarray(raw), vals=vals)
+    got = jax.jit(lambda *a: sparse_embedding_update(
+        kind, *a, lowering="sort", **kw))(*args)
+    want = jax.jit(lambda *a: _unblocked_sort_update(kind, *a, **kw))(*args)
+    assert int(got[3]) == -(-n_live // _BLOCK)
+    assert int(got[3]) <= slot_blocks(N, C, D) == -(-plan_slots(N, C, D)
+                                                    // _BLOCK)
+    for a, b in zip(jax.tree.leaves(got[:3]), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if n_live:          # something moved, and only on touched rows
+        moved = np.any(np.asarray(got[0]) != np.asarray(emb), axis=1)
+        assert moved.any() and set(np.where(moved)[0]) <= set(
+            idx[live].tolist())
+
+
+def test_slot_blocks_are_counted_per_fit(session, data):
+    """The fit reads the device-side trip count once at its end into the
+    registry: run <= possible = steps x slot_blocks, and a 'plan' fit
+    (no loop) counts nothing."""
+    Xall, y = data
+    c = REGISTRY.get("otpu_sparse_slot_blocks_total")
+    before = (c.value(which="run"), c.value(which="possible"))
+    m = _fit(session, Xall, y, optim_update="sparse_adagrad",
+             sparse_lowering="sort", reg_param=1e-3)
+    run = c.value(which="run") - before[0]
+    possible = c.value(which="possible") - before[1]
+    per_step = slot_blocks(session.pad_rows(BASE["chunk_rows"]),
+                           BASE["n_cat"], BASE["n_dims"])
+    assert possible == m.n_steps_ * per_step
+    # these chunks' slot bound is under one SLOT_BLOCK: one trip a step
+    assert per_step == 1 and run == m.n_steps_
+    _fit(session, Xall, y, optim_update="sparse_adagrad",
+         sparse_lowering="plan", reg_param=1e-3)
+    assert c.value(which="possible") - before[1] == possible
+
+
 # ------------------------------------------------- replay-path parity triple
 
 def test_fused_epoch_spill_replay_parity(session, tmp_path, data):
@@ -299,6 +414,34 @@ def test_checkpoint_resume_sparse_state(session, tmp_path, data,
     assert resumed.n_steps_ == ref.n_steps_
 
 
+def test_checkpoint_without_block_counter_resumes(session, tmp_path, data,
+                                                  make_killing_checkpointer):
+    """A snapshot written before opt_state carried the 'sort' lowering's
+    block counter (no 'blocks' key) must still resume, to the same fit."""
+    import pickle
+
+    from orange3_spark_tpu.utils.fault import StreamCheckpointer
+
+    Xall, y = data
+    kw = dict(optim_update="sparse_adagrad", sparse_lowering="sort",
+              reg_param=1e-3, epochs=3, fused_replay=False)
+    ref = _fit(session, Xall, y, **kw)
+    path = str(tmp_path / "ck")
+    killer = make_killing_checkpointer(path, every_steps=4, die_after=2)
+    with pytest.raises(RuntimeError, match="injected fault"):
+        _fit(session, Xall, y, **kw, checkpointer=killer)
+    with open(path, "rb") as f:
+        blob = pickle.load(f)
+    assert blob["state"]["opt_state"].pop("blocks") > 0
+    with open(path, "wb") as f:
+        pickle.dump(blob, f)
+    resumed = _fit(session, Xall, y, **kw,
+                   checkpointer=StreamCheckpointer(path, every_steps=4))
+    np.testing.assert_array_equal(np.asarray(resumed.theta["emb"]),
+                                  np.asarray(ref.theta["emb"]))
+    assert resumed.n_steps_ == ref.n_steps_
+
+
 # --------------------------------------------------- serving + sharding
 
 def test_sparse_trained_model_serves_identically(session, data):
@@ -313,10 +456,14 @@ def test_sparse_trained_model_serves_identically(session, data):
     np.testing.assert_array_equal(served, raw)
 
 
-def test_model_sharded_table_sparse_parity(session, data):
+@pytest.mark.parametrize("lowering", ["auto", "sort"])
+def test_model_sharded_table_sparse_parity(session, data, monkeypatch,
+                                           lowering):
     """The sharded-table oracle: a (4 data x 2 model) mesh fit under
     sparse updates matches the replicated fit — GSPMD lowers the gathers/
-    segment scatter/writeback against the P('model', None) table."""
+    segment scatter/writeback against the P('model', None) table. 'sort'
+    (what a TPU resolves 'auto' to) takes its block loop several trips a
+    step here, the trip count a replicated scalar."""
     from jax.sharding import Mesh
 
     from orange3_spark_tpu.core.session import TpuSession
@@ -324,11 +471,23 @@ def test_model_sharded_table_sparse_parity(session, data):
     Xall, y = data
     devs = np.array(jax.devices()).reshape(4, 2)
     sharded = TpuSession(Mesh(devs, ("data", "model")))
-    kw = dict(optim_update="sparse_adagrad", reg_param=1e-3)
+    kw = dict(optim_update="sparse_adagrad", reg_param=1e-3,
+              sparse_lowering=lowering)
+    if lowering == "sort":
+        # a table size no other test compiles, so the patched block is
+        # traced: 2049 slots a chunk in blocks of 256
+        monkeypatch.setattr(sparse_mod, "SLOT_BLOCK", 256)
+        kw["n_dims"] = 1 << 11
+    blocks = REGISTRY.get("otpu_sparse_slot_blocks_total")
+    run0 = blocks.value(which="run")
     m_sh = _fit(sharded, Xall, y, **kw)
+    trips = blocks.value(which="run") - run0
     m_ref = _fit(session, Xall, y, **kw)
     assert m_sh.theta["emb"].sharding.spec[0] == "model"
     assert _emb_diff(m_sh, m_ref) < 1e-6
+    if lowering == "sort":
+        assert slot_blocks(1024, BASE["n_cat"], 1 << 11) == 9
+        assert trips >= 2 * m_sh.n_steps_
 
 
 # ------------------------------------------------ kill-switch + compiles

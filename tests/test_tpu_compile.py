@@ -16,7 +16,9 @@ TPU library), persistent compile cache off around the compiles.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +29,7 @@ from jax.sharding import SingleDeviceSharding
 
 HBM_BYTES = 16 << 30            # one v5e chip
 ROWS, N_DIMS = 1 << 18, 1 << 22  # chip_smoke's fit: 262,144-row chunks, 2^22
+BENCH_DIMS = 1 << 29             # the benchmark's Criteo table (PERF.md §4)
 HIST_REAL = (1 << 20, 28, 3, 16, 32)   # (N, d, s, nodes, bins): HIGGS level
 
 
@@ -186,25 +189,71 @@ def _step_args(hashed, state_sh, row_sh, vec_sh, *, stack: int = 0):
     ), kw
 
 
-def test_hashed_step_sort_lowering_compiles(one_chip, hashed):
+def _at_dims(hashed, n_dims: int):
+    """`hashed` with the table (and every per-row optimizer array) at
+    `n_dims` rows — shapes only, nothing of that size is allocated here —
+    and the chunk re-encoded at the index width that table needs."""
+    from orange3_spark_tpu.models.hashed_linear import _encode_chunk_np
+
+    p, theta, opt, salts_np, chunk, kw = hashed
+    if n_dims == p.n_dims:
+        return hashed
+
+    def grown(a):
+        return jax.ShapeDtypeStruct(
+            (n_dims,) + a.shape[1:] if a.shape[:1] == (p.n_dims,)
+            else a.shape, a.dtype)
+
+    codec = dataclasses.replace(kw["codec"], n_dims=n_dims)
+    chunk = _encode_chunk_np(
+        codec, np.zeros((ROWS, 1 + p.n_dense + p.n_cat), np.float32),
+        salts_np)
+    return (p, jax.tree.map(grown, theta), jax.tree.map(grown, opt),
+            salts_np, chunk, {**kw, "n_dims": n_dims, "codec": codec})
+
+
+def _tables_stay_in_place(compiled, n_dims: int):
+    """The sparse step's three tables (weight, Adagrad accumulator,
+    last-seen step) are donated, carried through the block loop of
+    optim/sparse.py and written in place: no copy of a table's shape in
+    the program, and — at the benchmark's size, where one table is 2.1 GB
+    and everything sized by a chunk's 6.8M occurrences a few hundred MB —
+    less temp than ONE table."""
+    text = compiled.as_text()
+    assert " while(" in text                   # the block loop is there
+    assert not re.search(rf"= \w+\[{n_dims}[,\]][^ ]* copy\(", text)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 3 * 4 * n_dims
+    if n_dims == BENCH_DIMS:
+        assert m.temp_size_in_bytes < 4 * n_dims, m.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("n_dims", [N_DIMS, BENCH_DIMS],
+                         ids=["smoke-2^22", "bench-2^29"])
+def test_hashed_step_sort_lowering_compiles(one_chip, hashed, n_dims):
     from orange3_spark_tpu.models.hashed_linear import _hashed_step
 
-    args, kw = _step_args(hashed, one_chip, one_chip, one_chip)
+    args, kw = _step_args(_at_dims(hashed, n_dims), one_chip, one_chip,
+                          one_chip)
     compiled = _hashed_step.donated.lower(*args, **kw).compile()
     _fits(compiled)
+    _tables_stay_in_place(compiled, n_dims)
 
 
-def test_hashed_replay_epochs_compiles(one_chip, hashed):
+@pytest.mark.parametrize("n_dims", [N_DIMS, BENCH_DIMS],
+                         ids=["smoke-2^22", "bench-2^29"])
+def test_hashed_replay_epochs_compiles(one_chip, hashed, n_dims):
     """The library-default one-dispatch replay: 7 epochs over 6 cached
     chunks in ONE program (chip_smoke's fit: 8 chunks less 2 held out)."""
     from orange3_spark_tpu.models.hashed_linear import _hashed_replay_epochs
 
     (theta, opt, X, nv, y, w, salts, reg, lr), kw = _step_args(
-        hashed, one_chip, one_chip, one_chip, stack=6)
+        _at_dims(hashed, n_dims), one_chip, one_chip, one_chip, stack=6)
     compiled = _hashed_replay_epochs.donated.lower(
         theta, opt, (X, nv, y, w), salts, reg, lr, n_epochs=7, **kw
     ).compile()
     _fits(compiled)
+    _tables_stay_in_place(compiled, n_dims)
 
 
 def test_hashed_predict_compiles_at_bucket(one_chip, hashed):
