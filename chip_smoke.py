@@ -442,11 +442,21 @@ def phase_four_chips(sz: Sizes, seed: int, devices):
              mesh=dict(part.mesh.shape), rows=sz.fit_rows, n_dims=sz.n_dims,
              epochs=sz.epochs, final_loss=model.final_loss_,
              stage_seconds=stages, emb_sharding=str(model.theta["emb"].sharding.spec),
+             table_specs=model.table_specs_,
              theta_devices=theta_shards[0], chunk_devices=chunk_shards[0],
              theta_max_abs_diff_vs_one_device=diff, tolerance=SHARDED_ATOL)
         assert np.isfinite(model.final_loss_)
         assert all(s == want for s in theta_shards), theta_shards
         assert all(s == want for s in chunk_shards), chunk_shards
+        # where the three table-sized arrays stood when the last step
+        # handed them back: rows over 'model' on the (2,2) mesh — the
+        # accumulator and the last-seen steps beside the weight, or each
+        # chip would hold them whole
+        model_axis = part.mesh.shape["model"]
+        for table in ("emb", "acc", "t"):
+            spec = model.table_specs_[table]
+            assert spec.startswith("PartitionSpec('model'") == (
+                model_axis > 1), (name, table, spec)
         in_use = memory(part.mesh.devices.flat)["bytes_in_use"]
         assert all(b is None or b > 0 for b in in_use), in_use
         if ref is None:

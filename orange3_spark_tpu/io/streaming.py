@@ -809,12 +809,15 @@ class _DeviceCache:
         self.budget = budget
         self.may_exclude_tail = may_exclude_tail
         self.batches: list = []
-        self.nbytes = 0
+        self.nbytes = 0            # global: what the budget gates read
+        self.chip_nbytes = 0       # on one chip: what the ledger reads
         self.degraded = False
         self.offered = 0           # total offer() calls
         self.first_miss: int | None = None   # ordinal of the first miss
         # device-memory ledger entry (obs/prof.py owner "cache_chunks"):
-        # codec-aware bytes, updated on every nbytes change, released by
+        # codec-aware bytes PER CHIP (rows sharded over 'data' divide;
+        # the global ``nbytes`` beside them), updated on every nbytes
+        # change, released by
         # finalize when the cache dies (an aborted fit leaks no entry;
         # the GC-safe deferred form — finalizers must not take the
         # ledger lock)
@@ -825,7 +828,8 @@ class _DeviceCache:
                          self.ledger_key)
 
     def _ledger_sync(self) -> None:
-        prof.ledger_set("cache_chunks", self.ledger_key, self.nbytes)
+        prof.ledger_set("cache_chunks", self.ledger_key,
+                        self.chip_nbytes, self.nbytes)
 
     def offer(self, batch: tuple) -> None:
         if not self.enabled:
@@ -843,7 +847,7 @@ class _DeviceCache:
             self.enabled = False
             self.degraded = True
             self.batches = []
-            self.nbytes = 0
+            self.nbytes = self.chip_nbytes = 0
             self.first_miss = None
             self._ledger_sync()
             return
@@ -853,6 +857,7 @@ class _DeviceCache:
                 and self.nbytes + sz <= budget):
             self.batches.append(batch)
             self.nbytes += sz
+            self.chip_nbytes += prof.tree_chip_bytes(batch)
             self._ledger_sync()
         else:
             if self.first_miss is None:
@@ -863,7 +868,8 @@ class _DeviceCache:
                 # no forgiveness is possible — drop NOW, legacy-style
                 self.enabled = False
                 self.batches = []
-                self.nbytes = 0  # honest accounting for downstream gates
+                # honest accounting for downstream gates
+                self.nbytes = self.chip_nbytes = 0
                 self.first_miss = None
                 self._ledger_sync()
 
@@ -897,6 +903,7 @@ class _DeviceCache:
         for b in self.batches:
             if id(b[0]) in drop_ids:
                 self.nbytes -= self._size(b)
+                self.chip_nbytes -= prof.tree_chip_bytes(b)
             else:
                 kept.append(b)
         self.batches = kept
@@ -911,7 +918,7 @@ class _DeviceCache:
             self.enabled = False
             self.degraded = True
             self.batches = []
-            self.nbytes = 0
+            self.nbytes = self.chip_nbytes = 0
             self.first_miss = None
             self._ledger_sync()
 

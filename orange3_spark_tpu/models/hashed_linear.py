@@ -1071,6 +1071,33 @@ def _chunk_cols(p: HashedLinearParams) -> int:
             + (1 if p.label_in_chunk else 0))
 
 
+def _pin_tables(opt_state: dict, session: TpuSession) -> dict:
+    """The table-sized optimizer arrays (the rule's slots of ``emb``, the
+    last-seen steps ``t``) placed where the table is: rows over 'model',
+    replicated over 'data'. ``zeros_like`` hands them that placement
+    already (then this moves nothing); it is stated here so that a
+    replicated accumulator — 8.6 GB a chip more at 2^30 rows — cannot
+    come back with a change of ``init_optim_state`` or of jax."""
+    ax = session.model_axis
+    slots = dict(opt_state["slots"])
+    slots["emb"] = {n: jax.device_put(v, session.sharding(ax, None))
+                    for n, v in slots["emb"].items()}
+    return {**opt_state, "slots": slots,
+            "t": jax.device_put(opt_state["t"], session.sharding(ax))}
+
+
+def _table_specs(theta: dict, opt_state) -> dict:
+    """name -> ``PartitionSpec`` (as text) of every table-sized array of a
+    fit's state, read off the arrays themselves: ``emb``, the rule's slots
+    of it (``acc`` | ``z``, ``n``) and ``t``; optax's adam state has none
+    by these names."""
+    tables = {"emb": theta["emb"]}
+    if isinstance(opt_state, dict):
+        tables.update(opt_state["slots"]["emb"], t=opt_state["t"])
+    return {n: str(getattr(v.sharding, "spec", None))
+            for n, v in tables.items()}
+
+
 def _init_fit_state(p: HashedLinearParams, session: TpuSession):
     """Fresh (theta, opt_state, salts_np, salts_dev, static_kw) exactly as a
     fit starts — shared by fit_stream and warm_replay so the warm program's
@@ -1078,21 +1105,23 @@ def _init_fit_state(p: HashedLinearParams, session: TpuSession):
     class: a mismatch just misses the jit cache and moves the scan compile
     back into the timed fit)."""
     k = _effective_k(p)
+    model_parallel = (session.model_axis is not None and
+                      session.mesh.shape.get(session.model_axis, 1) > 1)
+    # model-parallel embedding: the table (the one large parameter) shards
+    # its rows over 'model' — P('model', None) — so HBM holds 1/mp of it
+    # per device, and is MADE in that placement: a table one chip cannot
+    # hold (2^30 rows: 4.3 GB, 12.9 GB with its accumulator and
+    # timestamps) never stands whole on any device. GSPMD turns the in-jit
+    # gathers/scatters into per-shard masked lookups plus an all-reduce
+    # over 'model' (tests/test_tpu_compile.py holds it to that at 2^30).
+    emb = jnp.zeros((p.n_dims, k), jnp.float32,
+                    device=(session.sharding(session.model_axis, None)
+                            if model_parallel else None))
     theta = {
-        "emb": jnp.zeros((p.n_dims, k), jnp.float32),
+        "emb": emb,
         "coef": jnp.zeros((p.n_dense, k), jnp.float32),
         "intercept": jnp.zeros((k,), jnp.float32),
     }
-    if session.model_axis is not None and \
-            session.mesh.shape.get(session.model_axis, 1) > 1:
-        # model-parallel embedding: the table (the one large parameter)
-        # shards its rows over 'model' — P('model', None) — so HBM holds
-        # 1/mp of it per device; GSPMD turns the in-jit gather/scatter
-        # into collective-assisted lookups over ICI. Adam state inherits
-        # the placement via zeros_like.
-        theta["emb"] = jax.device_put(
-            theta["emb"], session.sharding(session.model_axis, None)
-        )
     optim = resolve_optim_update(p.optim_update)
     lowering = (resolve_sparse_lowering(p.sparse_lowering)
                 if is_sparse_update(optim) else "none")
@@ -1100,6 +1129,8 @@ def _init_fit_state(p: HashedLinearParams, session: TpuSession):
         opt_state = _ADAM_UNIT.init(theta)
     else:
         opt_state = init_optim_state(optim, theta)
+        if model_parallel:
+            opt_state = _pin_tables(opt_state, session)
     if p.value_weighted:
         # position-INDEPENDENT hashing: libsvm-style sources pack
         # (idx, val) pairs positionally, so every slot must share ONE salt
@@ -1340,8 +1371,10 @@ class StreamingHashedLinearEstimator(Estimator):
         # growth. Re-set to theta-only at fit end (slots die with the
         # fit); released when the fitted model itself dies.
         state_key = f"hashed-{next(_FIT_LEDGER_SEQ)}"
-        prof.ledger_set("model_state", state_key,
-                        prof.tree_device_bytes((theta, opt_state)))
+        prof.ledger_set_tree("model_state", state_key, (theta, opt_state))
+        # what this fit runs on: the mesh's shape and where each table
+        # stands (gauge otpu_mesh_devices + one "mesh" event in the trace)
+        prof.note_mesh(session.mesh, **_table_specs(theta, opt_state))
         # frame-scoped guard: a fit that ABORTS (divergence, wedge,
         # retry exhaustion) must not strand its model_state entry — the
         # guard's death releases it; the success tail detaches it and
@@ -1933,8 +1966,7 @@ class StreamingHashedLinearEstimator(Estimator):
                     # explicit release below makes its firing a no-op
                     rp_key = f"replay_stack-{state_key}"
                     _rp_guard = prof.ledger_guard("replay_plans", rp_key)
-                    prof.ledger_set("replay_plans", rp_key,
-                                    prof.tree_device_bytes(stacks))
+                    prof.ledger_set_tree("replay_plans", rp_key, stacks)
                 with stage("replay", n_epochs=n_rep,
                            steps=n_rep * spe) as replayed:
                     if p.replay_granularity == "epoch":
@@ -2072,12 +2104,13 @@ class StreamingHashedLinearEstimator(Estimator):
         model.device_chunks_ = cache.batches if cache_device else None
         model.holdout_chunks_ = holdout if holdout_chunks > 0 else None
         model.cache_codec_ = codec   # evaluate_device's decode key
+        # where the tables stood when the last step handed them back
+        model.table_specs_ = _table_specs(theta, opt_state)
         # ledger: the optimizer slots die with the fit — the entry
         # shrinks to the table itself and lives as long as the model
         # (the abort guard hands ownership to the model's finalizer)
         _state_guard.finalizer.detach()
-        prof.ledger_set("model_state", state_key,
-                        prof.tree_device_bytes(theta))
+        prof.ledger_set_tree("model_state", state_key, theta)
         import weakref
 
         weakref.finalize(model, prof.ledger_release_on_gc, "model_state",

@@ -92,12 +92,15 @@ __all__ = [
     "last_goodput",
     "ledger_release",
     "ledger_set",
+    "ledger_set_tree",
     "note_input_wait",
+    "note_mesh",
     "note_sync",
     "prof_enabled",
     "refreshed_enabled",
     "reset_rate_limit",
     "trace_capture",
+    "tree_chip_bytes",
 ]
 
 log = logging.getLogger("orange3_spark_tpu")
@@ -129,6 +132,11 @@ _M_DEVICE_BYTES = REGISTRY.gauge(
     "otpu_device_bytes",
     "live device-resident bytes per ledger owner (cache_chunks / "
     "model_state / serve_executables / replay_plans)")
+_M_MESH_DEVICES = REGISTRY.gauge(
+    "otpu_mesh_devices",
+    "devices along each axis of the mesh the last started fit ran on "
+    "(axis=data|model): (1,1) is one chip, (2,2) a table sharded over "
+    "model and rows over data")
 _M_CAPTURES = REGISTRY.counter(
     "otpu_prof_captures_total",
     "deep-profile capture attempts, by outcome "
@@ -415,6 +423,20 @@ def note_input_wait(seconds: float) -> None:
         acc.add("input_wait", seconds)
 
 
+def note_mesh(mesh, **table_specs) -> None:
+    """A fit says, once at its start, what it runs on: the mesh's shape on
+    ``otpu_mesh_devices{axis=}`` and one ``mesh`` event on the fit's own
+    trace carrying that shape and the ``PartitionSpec`` of each table it
+    was handed by name — read off the arrays, not off what was asked for.
+    Rides the OTPU_PROF kill-switch like the ledger."""
+    if not prof_enabled():
+        return
+    shape = {str(axis): int(n) for axis, n in mesh.shape.items()}
+    for axis, n in shape.items():
+        _M_MESH_DEVICES.set(n, axis=axis)
+    _trace.instant("mesh", **shape, **table_specs)
+
+
 def last_goodput() -> dict | None:
     """The most recent finished fit's decomposition (what a serving
     process's deep capture reports when no fit is live)."""
@@ -427,7 +449,13 @@ class DeviceMemoryLedger:
     ``release(owner, name)``, live bytes per owner on
     ``otpu_device_bytes{owner=}``, a running peak, per-fit peaks via
     :meth:`watermark`, and best-effort reconciliation against the JAX
-    runtime. Thread-safe; every mutation is a no-op under
+    runtime. The bytes are PER CHIP: a sharded array counts by what its
+    shards take on the fullest device (:func:`tree_chip_bytes`), since a
+    chip runs out of its own 16 GB and not of the mesh's sum; the array's
+    global size is kept beside it (``global_nbytes=``, ``peak_global()``,
+    the snapshot's ``*global_bytes``) and equals the per-chip figure for
+    everything that lives on one device. Thread-safe; every mutation is a
+    no-op under
     ``OTPU_PROF=0`` (release always applies, so a mid-process kill-
     switch flip cannot strand entries)."""
 
@@ -436,6 +464,11 @@ class DeviceMemoryLedger:
         self._entries: dict[tuple[str, str], int] = {}
         self._total = 0
         self._peak = 0
+        # the same entries by their arrays' global size (see the class
+        # docstring); an entry set without one counts its per-chip bytes
+        self._global: dict[tuple[str, str], int] = {}
+        self._total_global = 0
+        self._peak_global = 0
         self._watermarks: dict[int, "DeviceMemoryLedger._Watermark"] = {}
         self._wm_seq = 0
         # GC-finalizer inbox: weakref.finalize callbacks run
@@ -468,6 +501,7 @@ class DeviceMemoryLedger:
                 prev = self._entries.pop((a, b), None)
                 if prev is not None:
                     self._total -= prev
+                    self._total_global -= self._global.pop((a, b), prev)
                     touched.add(a)
             else:
                 self._watermarks.pop(a, None)
@@ -507,16 +541,23 @@ class DeviceMemoryLedger:
     # out of order and pin phantom bytes on the gauge the fleet digest
     # (and the ROADMAP-3 autoscaler) reads until the owner next moves.
     # Lock order is ledger -> metric; nothing takes them the other way.
-    def set(self, owner: str, name: str, nbytes: int) -> None:
+    def set(self, owner: str, name: str, nbytes: int,
+            global_nbytes: int | None = None) -> None:
         if not prof_enabled():
             return
         nbytes = max(int(nbytes), 0)
+        global_nbytes = (nbytes if global_nbytes is None
+                         else max(int(global_nbytes), 0))
         with self._lock:
             self._drain_pending_locked()
             key = (owner, name)
-            self._total += nbytes - self._entries.get(key, 0)
+            prev = self._entries.get(key, 0)
+            self._total += nbytes - prev
+            self._total_global += global_nbytes - self._global.get(key, prev)
             self._entries[key] = nbytes
+            self._global[key] = global_nbytes
             self._peak = max(self._peak, self._total)
+            self._peak_global = max(self._peak_global, self._total_global)
             for wm in self._watermarks.values():
                 wm.high = max(wm.high, self._total)
             owner_total = sum(v for (o, _n), v in self._entries.items()
@@ -530,15 +571,18 @@ class DeviceMemoryLedger:
             if prev is None:
                 return
             self._total -= prev
+            self._total_global -= self._global.pop((owner, name), prev)
             owner_total = sum(v for (o, _n), v in self._entries.items()
                               if o == owner)
             _M_DEVICE_BYTES.set(owner_total, owner=owner)
 
     # ------------------------------------------------------------- views
-    def get(self, owner: str, name: str) -> int | None:
+    def get(self, owner: str, name: str, *,
+            global_size: bool = False) -> int | None:
         with self._lock:
             self._drain_pending_locked()
-            return self._entries.get((owner, name))
+            return (self._global if global_size
+                    else self._entries).get((owner, name))
 
     def owner_bytes(self) -> dict[str, int]:
         out: dict[str, int] = {}
@@ -557,6 +601,12 @@ class DeviceMemoryLedger:
         with self._lock:
             return self._peak
 
+    def peak_global(self) -> int:
+        """High-water mark of the entries' GLOBAL sizes: what ``peak()``
+        read before a sharded array counted per chip."""
+        with self._lock:
+            return self._peak_global
+
     def snapshot(self, max_entries: int = 64) -> dict:
         """The ledger table (flight bundles, reports, captures): per-
         owner totals plus the largest entries by name — an OOM-adjacent
@@ -568,19 +618,24 @@ class DeviceMemoryLedger:
             # sums == total == entry sums), or a post-mortem reader
             # chases phantom leaks
             entries = sorted(
-                ({"owner": o, "name": n, "bytes": v}
+                ({"owner": o, "name": n, "bytes": v,
+                  "global_bytes": self._global.get((o, n), v)}
                  for (o, n), v in self._entries.items()),
                 key=lambda e: -e["bytes"])
             owners: dict[str, int] = {}
             for (owner, _name), v in self._entries.items():
                 owners[owner] = owners.get(owner, 0) + v
             total, peak = self._total, self._peak
+            total_global, peak_global = (self._total_global,
+                                         self._peak_global)
         dropped = max(len(entries) - max_entries, 0)
         out = {
             "prof_schema": PROF_SCHEMA_VERSION,
             "owners": dict(sorted(owners.items())),
             "total_bytes": total,
             "peak_bytes": peak,
+            "total_global_bytes": total_global,
+            "peak_global_bytes": peak_global,
             "entries": entries[:max_entries],
         }
         if dropped:
@@ -620,8 +675,9 @@ class DeviceMemoryLedger:
             self._drain_pending_locked()
             owners = {o for (o, _n) in self._entries}
             self._entries.clear()
-            self._total = 0
-            self._peak = 0
+            self._global.clear()
+            self._total = self._total_global = 0
+            self._peak = self._peak_global = 0
             for o in owners:
                 _M_DEVICE_BYTES.set(0, owner=o)
 
@@ -661,15 +717,43 @@ def ledger_release_on_gc(owner: str, name: str) -> None:
 
 
 def tree_device_bytes(tree) -> int:
-    """Total ``nbytes`` across a pytree's array leaves (the ledger's
-    standard sizing rule — codec-encoded dict leaves count as stored)."""
+    """Total ``nbytes`` across a pytree's array leaves: their GLOBAL size
+    (codec-encoded dict leaves count as stored)."""
     import jax
 
     return int(sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(tree)))
 
 
-def ledger_set(owner: str, name: str, nbytes: int) -> None:
-    LEDGER.set(owner, name, nbytes)
+def tree_chip_bytes(tree) -> int:
+    """What a pytree's array leaves take on ONE chip — the ledger's sizing
+    rule. A jax array holds one shard of ``sharding.shard_shape`` on every
+    device it lives on (a replicated axis repeats the shard, a sharded one
+    divides it), so its per-chip bytes are that shard's; anything else, and
+    an array on one device, counts its ``nbytes`` as before."""
+    import math
+
+    import jax
+
+    total = 0
+    for x in jax.tree.leaves(tree):
+        sharding = getattr(x, "sharding", None)
+        if sharding is None:
+            total += getattr(x, "nbytes", 0)
+        else:
+            total += (math.prod(sharding.shard_shape(x.shape))
+                      * x.dtype.itemsize)
+    return int(total)
+
+
+def ledger_set_tree(owner: str, name: str, tree) -> None:
+    """One entry for a pytree of device arrays: per chip, with its global
+    size beside it."""
+    LEDGER.set(owner, name, tree_chip_bytes(tree), tree_device_bytes(tree))
+
+
+def ledger_set(owner: str, name: str, nbytes: int,
+               global_nbytes: int | None = None) -> None:
+    LEDGER.set(owner, name, nbytes, global_nbytes)
 
 
 def ledger_release(owner: str, name: str) -> None:
@@ -684,7 +768,9 @@ def attach_fit_report(report, acc: GoodputAccountant | None, *,
     under the kill-switch, so a PR-11 consumer sees the PR-11 dict).
     ``cache_key`` names the fit's own ``cache_chunks`` ledger entry so
     the bench can cross-check it against the legacy ``cache_bytes``
-    stage key without ambiguity from other live caches."""
+    stage key without ambiguity from other live caches: both are the
+    cache's GLOBAL size; ``cache_entry_chip_bytes`` is what one chip
+    holds of it (the same number off a mesh)."""
     if acc is None:
         return
     result = acc.finish(encode_s=encode_s)
@@ -692,7 +778,9 @@ def attach_fit_report(report, acc: GoodputAccountant | None, *,
     dm["peak_bytes_fit"] = result["peak_device_bytes"]
     dm["reconciliation"] = LEDGER.reconcile()
     if cache_key is not None:
-        dm["cache_entry_bytes"] = LEDGER.get("cache_chunks", cache_key)
+        dm["cache_entry_bytes"] = LEDGER.get("cache_chunks", cache_key,
+                                             global_size=True)
+        dm["cache_entry_chip_bytes"] = LEDGER.get("cache_chunks", cache_key)
     if report is not None:
         report.goodput = result
         report.device_memory = dm

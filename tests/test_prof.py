@@ -118,12 +118,18 @@ def test_fit_ledger_cache_entry_matches_stage_times(session, prof_env):
                      stage_times=stage_times)
     dm = model.run_report_.to_dict()["device_memory"]
     assert dm["cache_entry_bytes"] == stage_times["cache_bytes"]
-    assert dm["owners"]["cache_chunks"] >= stage_times["cache_bytes"]
+    # the ledger counts PER CHIP: the session's rows are sharded over its
+    # 'data' axis, so one chip holds that share of the cache
+    on_chip = dm["cache_entry_chip_bytes"]
+    assert on_chip * session.data_parallelism >= stage_times["cache_bytes"]
+    assert on_chip <= stage_times["cache_bytes"]
+    assert dm["owners"]["cache_chunks"] >= on_chip
     assert "model_state" in dm["owners"]
-    assert dm["peak_bytes_fit"] >= dm["cache_entry_bytes"]
+    assert dm["peak_bytes_fit"] >= on_chip
+    assert dm["peak_global_bytes"] >= dm["cache_entry_bytes"]
     # reconciliation is REPORTED, never asserted — but it must be there
     rec = dm["reconciliation"]
-    assert rec["ledger_bytes"] >= dm["cache_entry_bytes"]
+    assert rec["ledger_bytes"] >= on_chip
     assert "delta_vs_live_bytes" in rec
 
 

@@ -30,6 +30,7 @@ from jax.sharding import SingleDeviceSharding
 HBM_BYTES = 16 << 30            # one v5e chip
 ROWS, N_DIMS = 1 << 18, 1 << 22  # chip_smoke's fit: 262,144-row chunks, 2^22
 BENCH_DIMS = 1 << 29             # the benchmark's Criteo table (PERF.md §4)
+MESH_DIMS = 1 << 30              # ... and the one a (2,2) mesh holds
 HIST_REAL = (1 << 20, 28, 3, 16, 32)   # (N, d, s, nodes, bins): HIGGS level
 
 
@@ -273,7 +274,7 @@ def test_hashed_step_compiles_on_four_chip_mesh(topo, hashed):
     """chip_smoke --four-chips' data-parallel step: rows on `data`, the
     table replicated (DataParallelPartitioner's (4,1) mesh).
     memory_analysis() is per device. The (2,2) model-sharded table of
-    SPMDPartitioner compiles too (PR 22, by hand: ~100 s, so not kept)."""
+    SPMDPartitioner is the next test, at the benchmark's own size."""
     from orange3_spark_tpu.models.hashed_linear import _hashed_step
 
     mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
@@ -284,6 +285,82 @@ def test_hashed_step_compiles_on_four_chip_mesh(topo, hashed):
     _fits(compiled)
     # the cross-device gradient sum the compiler had to put in
     assert "all-reduce" in compiled.as_text()
+
+
+def _spmd_args(hashed, mesh, *, stack: int = 0):
+    """`_step_args` as SPMDPartitioner(model_parallel=2) places them: the
+    three tables' rows over `model`, chunk rows over `data`, the rest
+    replicated (`stack` > 0: the replay's chunk stack)."""
+    rep = NamedSharding(mesh, P())
+    lead = (None,) if stack else ()
+    (theta, opt, X, *rest), kw = _step_args(
+        hashed, rep, NamedSharding(mesh, P(*lead, "data", None)),
+        NamedSharding(mesh, P(*lead, "data")), stack=stack)
+    n_dims = kw["n_dims"]
+
+    def table(a):
+        if a.shape[:1] != (n_dims,):
+            return a
+        spec = P("model", None) if len(a.shape) == 2 else P("model")
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    return (jax.tree.map(table, theta), jax.tree.map(table, opt), X,
+            *rest), kw
+
+
+def _tables_stay_sharded(compiled, n_dims: int):
+    """On the (2,2) mesh a device holds HALF of each table and never a whole
+    one: the program's arguments are 3 x 4 B x n_dims / 2 and a little, its
+    temp is far under one half table, no operation has a result of the
+    table's whole shape (an all-gather of one would be 4.3 GB at 2^30),
+    and the only all-gather is of a chunk's 6.8M occurrence indices over
+    `data`. The gathers and write-backs are per-shard masked lookups whose
+    partial rows an all-reduce over `model` adds up."""
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    half = 3 * 4 * n_dims // 2
+    assert half <= m.argument_size_in_bytes < half + (256 << 20), m
+    assert m.alias_size_in_bytes >= half
+    assert m.temp_size_in_bytes < 4 * n_dims // 2, m.temp_size_in_bytes
+    assert not re.search(rf"= \(?\w+\[{n_dims}[,\]]", text)
+    gathered = re.findall(r"= (\w+\[[\d,]*\])\S* all-gather(?:-start)?\(",
+                          text)
+    assert gathered and all(g == f"s32[{ROWS * 26}]" for g in gathered), \
+        gathered
+    assert " all-reduce(" in text and " while(" in text
+
+
+def test_hashed_step_compiles_model_sharded_at_2_30(topo, hashed):
+    """The benchmark's four-chip cell (criteo_svc_h30_fit_replay8_2x2): the
+    'sort' step at 2^30 rows on SPMDPartitioner's (2,2) mesh, compiled by
+    GSPMD from the arguments' shardings alone. ~80 s here."""
+    from orange3_spark_tpu.models.hashed_linear import _hashed_step
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    args, kw = _spmd_args(_at_dims(hashed, MESH_DIMS), mesh)
+    compiled = _hashed_step.donated.lower(*args, **kw).compile()
+    _fits(compiled)
+    _tables_stay_sharded(compiled, MESH_DIMS)
+
+
+@pytest.mark.slow
+def test_hashed_replay_epochs_compiles_model_sharded_at_2_30(topo, hashed):
+    """The same cell's one-dispatch replay (7 epochs x 6 chunks). Slow
+    (~85 s more in this file's one worker); by hand (PR 28, this
+    sandbox): args 6,569,333,248 / temp 178,806,272 / alias
+    6,442,454,016 bytes a device, the step's six collectives and no
+    other, no operation of the table's whole shape."""
+    from orange3_spark_tpu.models.hashed_linear import _hashed_replay_epochs
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    (theta, opt, X, nv, y, w, salts, reg, lr), kw = _spmd_args(
+        _at_dims(hashed, MESH_DIMS), mesh, stack=6)
+    compiled = _hashed_replay_epochs.donated.lower(
+        theta, opt, (X, nv, y, w), salts, reg, lr, n_epochs=7, **kw
+    ).compile()
+    _fits(compiled)
+    _tables_stay_sharded(compiled, MESH_DIMS)
 
 
 # ------------------------------------------------------------------- kmeans
