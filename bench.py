@@ -348,7 +348,8 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
 
         est_w = make_est(epochs)
         warm_state = est_w.warm_replay(n_chunks - holdout_chunks,
-                                       session=session)
+                                       session=session,
+                                       cache_device_bytes=cache_budget)
         if warm_state is None:
             # zero train chunks after holdout, or fused_replay disabled on
             # the params: neither the replay scan nor the eval program can

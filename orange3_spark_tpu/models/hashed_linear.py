@@ -69,10 +69,10 @@ from orange3_spark_tpu.ops.hashing import (
 )
 from orange3_spark_tpu.optim.sparse import (
     adopt_optim_state, build_plan_np, dense_update, finalize_lazy_decay,
-    init_optim_state, is_sparse_update, note_slot_blocks, optim_kind,
-    pack_plan_np, plan_field_shapes, plan_packed_field_shapes,
-    resolve_optim_update, resolve_sparse_lowering, slot_blocks,
-    sparse_embedding_update, unpack_plan,
+    init_optim_state, is_sparse_update, note_slot_blocks, note_sorts,
+    optim_kind, pack_plan_np, plan_field_shapes, plan_packed_field_shapes,
+    resolve_optim_update, resolve_sparse_lowering, slot_blocks, sort_keys,
+    sort_keys_bytes, sort_slots, sparse_embedding_update, unpack_plan,
 )
 from orange3_spark_tpu.obs import prof
 from orange3_spark_tpu.obs.report import RunReport
@@ -378,8 +378,27 @@ def _split_chunk(Xall, n_valid, y, w, *, label_in_chunk: bool, n_dense: int,
     return yv, dense, cats, wv, None
 
 
+def _chunk_fields(Xall, n_valid, y, w, salts, *, n_dims: int, n_dense: int,
+                  label_in_chunk: bool, value_weighted: bool,
+                  impute_missing: bool, codec):
+    """One cached chunk as the step reads it: ``(yv, dense, cats, idx, wv,
+    vals)``, ``idx`` the hashed table rows [N, C]. ``codec`` None is the
+    legacy f32 chunk (hashed here); otherwise ``Xall`` is the compressed
+    block dict and ``cats`` / ``vals`` are None (no value-weighted codec)."""
+    if codec is None:
+        yv, dense, cats, wv, vals = _split_chunk(
+            Xall, n_valid, y, w, label_in_chunk=label_in_chunk,
+            n_dense=n_dense, value_weighted=value_weighted,
+            impute_missing=impute_missing,
+        )
+        return yv, dense, cats, hash_columns(cats, salts, n_dims), wv, vals
+    yv, dense, idx, wv = _decode_chunk(codec, Xall, n_valid, y, w, salts)
+    return yv, dense, None, idx, wv, None
+
+
 def _step_core(
     theta, opt_state, Xall, n_valid, y, w, salts, reg, lr, plan=None, l1=0.0,
+    keys=None,
     *, loss_kind: str, n_dims: int, n_dense: int, compute_dtype=jnp.float32,
     label_in_chunk: bool = False, emb_update: str = "fused",
     value_weighted: bool = False, impute_missing: bool = False,
@@ -393,7 +412,10 @@ def _step_core(
     adam sweep over the whole table. Every other rule (optim/ subsystem)
     reports the pure data loss, treats reg as decoupled weight decay, and
     — for the sparse_* rules — updates only the touched rows, with ``plan``
-    carrying the host-presorted dedup under the 'plan' lowering.
+    carrying the host-presorted dedup under the 'plan' lowering and
+    ``keys`` this chunk's ``optim.sparse.sort_keys`` where the fused
+    replay has built them ahead of its scan ('sort' lowering; None: the
+    step sorts for itself).
 
     codec (io/codec.py, resolved once at fit entry): None is the legacy
     f32 chunk; otherwise ``Xall`` is the compressed block dict and the
@@ -407,21 +429,13 @@ def _step_core(
     metadata only, so that a device trace is read by phase and not by
     XLA's fusion numbers (docs/observability.md, "Device scopes")."""
     with jax.named_scope("step/decode"):
-        if codec is None:
-            yv, dense, cats, wv, vals = _split_chunk(
-                Xall, n_valid, y, w, label_in_chunk=label_in_chunk,
-                n_dense=n_dense, value_weighted=value_weighted,
-                impute_missing=impute_missing,
-            )
-            idx = hash_columns(cats, salts, n_dims)
-        else:
-            yv, dense, idx, wv = _decode_chunk(codec, Xall, n_valid, y, w,
-                                               salts)
-            cats = None
-            vals = None
-            if plan is not None and codec.mode == "packed":
-                plan = unpack_plan(plan, Xall["cats"].shape[0], codec.n_cat,
-                                   n_dims)
+        yv, dense, cats, idx, wv, vals = _chunk_fields(
+            Xall, n_valid, y, w, salts, n_dims=n_dims, n_dense=n_dense,
+            label_in_chunk=label_in_chunk, value_weighted=value_weighted,
+            impute_missing=impute_missing, codec=codec)
+        if plan is not None and codec is not None and codec.mode == "packed":
+            plan = unpack_plan(plan, Xall["cats"].shape[0], codec.n_cat,
+                               n_dims)
 
     def forward(theta, lowering):
         with jax.named_scope("step/forward"):
@@ -462,6 +476,7 @@ def _step_core(
             lr, decay, reg, l1, step, lowering=sparse_lowering,
             use_decay=use_decay, plan=plan, n_valid=n_valid,
             raw_cats=(cats if value_weighted else None), vals=vals,
+            keys=keys,
         )
         # dense small parameters: the same rule, full-array (they are tiny)
         with jax.named_scope("step/dense_leaf"):
@@ -525,7 +540,19 @@ def _hashed_step(
     )
 
 
-@donating_jit(static_argnames=_STEP_STATICS + ("n_epochs",),
+def _to_lanes(v):
+    """A vector as rows of 128 lanes (zero-padded to whole rows)."""
+    if v.ndim != 1:
+        return v
+    return jnp.pad(v, (0, -v.shape[0] % 128)).reshape(-1, 128)
+
+
+def _from_lanes(v, like):
+    """``_to_lanes`` undone: the array of ``like``'s shape again."""
+    return v.reshape(-1)[:like.size].reshape(like.shape)
+
+
+@donating_jit(static_argnames=_STEP_STATICS + ("n_epochs", "hoist_keys"),
               donate_argnums=(0, 1))
 def _hashed_replay_epochs(
     theta, opt_state, stacks, salts, reg, lr, l1=0.0,
@@ -534,7 +561,7 @@ def _hashed_replay_epochs(
     value_weighted: bool = False, impute_missing: bool = False,
     optim_update: str = "adam", sparse_lowering: str = "none",
     use_decay: bool = False, codec=None,
-    n_epochs: int,
+    n_epochs: int, hoist_keys: bool = False,
 ):
     """Epochs 2+ of a cached fit as ONE XLA program: an epoch-level scan
     around a chunk-level scan over the HBM-resident chunk stack.
@@ -543,6 +570,14 @@ def _hashed_replay_epochs(
     ystack, wstack)`` plus, when the sparse 'plan' lowering is active, a
     fifth element holding the stacked per-chunk touched-row plans (each
     leaf [n_chunks, ...]); the scan slices all of them in lockstep.
+
+    ``hoist_keys`` ('sort' lowering only; resolved by ``_hoist_sort_keys``
+    from the caller's cache budget): a cached chunk's keys do not change
+    between epochs, so its sort, segment ids and ``uniq``
+    (``optim.sparse.sort_keys``) are built ONCE here, ahead of the epoch
+    scan, and ride the chunk scan beside the chunk — ``n_chunks`` sorts a
+    dispatch instead of ``n_epochs x n_chunks``, for ``n_chunks x
+    sort_keys_bytes`` of temp. The steps compute what they computed.
 
     Rationale: the per-chunk jit replay pays one dispatch + sync per step;
     fusing the whole replay phase into one dispatch removes that overhead
@@ -558,20 +593,45 @@ def _hashed_replay_epochs(
               sparse_lowering=sparse_lowering, use_decay=use_decay,
               codec=codec)
 
+    stacks = tuple(stacks)
+    if hoist_keys:
+        def chunk_keys(chunk):
+            _, _, cats, idx, _, _ = _chunk_fields(
+                *chunk, salts, n_dims=n_dims, n_dense=n_dense,
+                label_in_chunk=label_in_chunk, value_weighted=value_weighted,
+                impute_missing=impute_missing, codec=codec)
+            return sort_keys(idx, n_dims, sort_slots(*idx.shape, n_dims),
+                             chunk[1], cats if value_weighted else None)
+
+        # a TPU tiles an array's last two axes (8, 128): stacked as
+        # [n_chunks, M] the 6 chunks of the benchmark would be padded to 8
+        # and a chunk's row read 512 bytes at a time, so each vector rides
+        # the scan as [n_chunks, M / 128, 128] — for the step a bitcast
+        key_shapes = jax.eval_shape(
+            chunk_keys, jax.tree.map(lambda a: a[0], stacks[:4]))
+        with jax.named_scope("replay/keys"):
+            stacks = stacks[:4] + (jax.lax.map(
+                lambda chunk: jax.tree.map(_to_lanes, chunk_keys(chunk)),
+                stacks[:4]),)
+
     def chunk_body(carry, xs):
         theta, opt = carry
         Xall, n_valid, y, w = xs[:4]
-        plan = xs[4] if len(xs) > 4 else None
+        # the fifth element: a 'plan' chunk's plan, or the hoisted keys
+        aux = xs[4] if len(xs) > 4 else None
+        plan, keys = aux, None
+        if hoist_keys:
+            plan, keys = None, jax.tree.map(_from_lanes, aux, key_shapes)
         with jax.named_scope("replay/chunk"):
             theta, opt, loss = _step_core(
                 theta, opt, Xall, n_valid, y, w, salts, reg, lr, plan, l1,
-                **kw
+                keys, **kw
             )
         return (theta, opt), loss
 
     def epoch_body(carry, _):
         with jax.named_scope("replay/epoch"):
-            carry, losses = jax.lax.scan(chunk_body, carry, tuple(stacks))
+            carry, losses = jax.lax.scan(chunk_body, carry, stacks)
         return carry, losses
 
     (theta, opt_state), chunk_losses = jax.lax.scan(
@@ -1039,6 +1099,24 @@ def estimate_cached_chunk_bytes(p: HashedLinearParams,
     return sum(int(np.prod(s)) * dt.itemsize for _, s, dt in specs)
 
 
+def _hoist_sort_keys(static_kw: dict, pad_rows: int, n_cat: int,
+                     n_chunks: int, cache_nbytes: int,
+                     cache_device_bytes: int) -> bool:
+    """Whether a fused replay over ``n_chunks`` cached chunks builds their
+    sort keys once per dispatch (``_hashed_replay_epochs(hoist_keys=)``)
+    — THE one place that decides it, from what the caller already gave:
+    the stacked keys are a temp of the replay program (three i32 arrays of
+    the occurrences' length per chunk, several times the packed cache they
+    describe), so they are taken only where the budget that admitted the
+    cache and its stack holds them too. Otherwise every step sorts for
+    itself, as ``_hashed_step`` does."""
+    return (static_kw["sparse_lowering"] == "sort"
+            and 2 * cache_nbytes
+            + n_chunks * sort_keys_bytes(pad_rows, n_cat,
+                                         static_kw["n_dims"])
+            <= cache_device_bytes)
+
+
 def warm_eval_chunk(p: HashedLinearParams, session: TpuSession) -> tuple:
     """A zero device chunk in the fit's CACHE layout (encoded under the
     resolved codec) — bench.py warms the eval program against it so the
@@ -1199,14 +1277,18 @@ class StreamingHashedLinearEstimator(Estimator):
         )
 
     def warm_replay(self, n_chunks: int, *,
-                    session: TpuSession | None = None):
+                    session: TpuSession | None = None,
+                    cache_device_bytes: int = 8 << 30):
         """Pre-compile the fused replay program for a fit whose cache will
         hold ``n_chunks`` train chunks, so a subsequent (timed) fit_stream
         hits the jit cache instead of paying the scan compile mid-fit.
         ``n_epochs`` and the chunk-stack shape are static to that program,
         so the warm shapes must match the real fit's (bench.py computes
-        n_chunks = total chunks - holdout chunks). Device-side zeros only —
-        one chunk-sized host transfer, no data pass.
+        n_chunks = total chunks - holdout chunks), and so must
+        ``cache_device_bytes`` where the fit is given one: it decides
+        whether the replay hoists its sort keys (``_hoist_sort_keys``).
+        Device-side zeros only — one chunk-sized host transfer, no data
+        pass.
 
         Returns ``(theta, salts_np)`` from the executed warm scan (or None
         when no replay program applies): scan-OUTPUT provenance, which is
@@ -1290,6 +1372,10 @@ class StreamingHashedLinearEstimator(Estimator):
             # epochs_per_dispatch group size, clamped to the replay span)
             n_epochs=(min(max(1, p.epochs_per_dispatch), n_rep)
                       if p.replay_granularity == "epoch" else n_rep),
+            hoist_keys=_hoist_sort_keys(
+                kw, pad_rows, p.n_cat, n_chunks,
+                n_chunks * estimate_cached_chunk_bytes(p, session),
+                cache_device_bytes),
             **kw)
         jax.block_until_ready(losses)
         return theta, np.asarray(salts)
@@ -1696,6 +1782,9 @@ class StreamingHashedLinearEstimator(Estimator):
 
         epoch_walls: list = []
         replay_fused_s = None
+        # steps whose sort keys a hoisting replay dispatch had built ahead
+        # of its scan ('sort' lowering; feeds otpu_sparse_sorts_total)
+        sorts_saved = 0
         # fused replay: epochs 2+ lower to ONE dispatch (see
         # _hashed_replay_epochs). Requires the full cache (same chunk set
         # every epoch) and no per-step checkpoint/resume bookkeeping.
@@ -1967,6 +2056,8 @@ class StreamingHashedLinearEstimator(Estimator):
                     rp_key = f"replay_stack-{state_key}"
                     _rp_guard = prof.ledger_guard("replay_plans", rp_key)
                     prof.ledger_set_tree("replay_plans", rp_key, stacks)
+                hoist = _hoist_sort_keys(static_kw, pad_rows, p.n_cat, spe,
+                                         cache.nbytes, cache_device_bytes)
                 with stage("replay", n_epochs=n_rep,
                            steps=n_rep * spe) as replayed:
                     if p.replay_granularity == "epoch":
@@ -1979,14 +2070,15 @@ class StreamingHashedLinearEstimator(Estimator):
                         )
 
                         def _disp(n_ep):
-                            nonlocal theta, opt_state
+                            nonlocal theta, opt_state, sorts_saved
                             with span("replay_dispatch", n_epochs=n_ep):
                                 theta, opt_state, chunk_losses = \
                                     _hashed_replay_epochs(
                                         theta, opt_state, stacks, salts,
                                         reg, lr, l1, n_epochs=n_ep,
-                                        **static_kw,
+                                        hoist_keys=hoist, **static_kw,
                                     )
+                            sorts_saved += hoist * (n_ep - 1) * spe
                             return chunk_losses[-1, -1]
 
                         n_steps, last, _ = run_epoch_replay(
@@ -2003,8 +2095,10 @@ class StreamingHashedLinearEstimator(Estimator):
                         theta, opt_state, chunk_losses = \
                             _hashed_replay_epochs(
                                 theta, opt_state, stacks, salts, reg, lr, l1,
-                                n_epochs=n_rep, **static_kw,
+                                n_epochs=n_rep, hoist_keys=hoist,
+                                **static_kw,
                             )
+                        sorts_saved += hoist * (n_rep - 1) * spe
                         count_dispatch()  # one-shot fused scan: no loop ticks
                         last_loss = chunk_losses[-1, -1]
                         n_steps += n_rep * spe
@@ -2097,10 +2191,11 @@ class StreamingHashedLinearEstimator(Estimator):
             # scalars of the same program are ready: no wait of their own
             steps, blocks = jax.device_get(
                 (opt_state["step"], opt_state["blocks"]))
+            steps = int(steps) - counted_from[0]
             note_slot_blocks(
                 int(blocks) - counted_from[1],
-                (int(steps) - counted_from[0])
-                * slot_blocks(pad_rows, p.n_cat, p.n_dims))
+                steps * slot_blocks(pad_rows, p.n_cat, p.n_dims))
+            note_sorts(steps - sorts_saved, steps)
         model.device_chunks_ = cache.batches if cache_device else None
         model.holdout_chunks_ = holdout if holdout_chunks > 0 else None
         model.cache_codec_ = codec   # evaluate_device's decode key

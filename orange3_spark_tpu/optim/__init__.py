@@ -16,11 +16,15 @@ from orange3_spark_tpu.optim.sparse import (  # noqa: F401
     init_optim_state,
     is_sparse_update,
     note_slot_blocks,
+    note_sorts,
     occurrence_dead,
     optim_kind,
     plan_field_shapes,
     plan_slots,
     slot_blocks,
+    sort_keys,
+    sort_keys_bytes,
+    sort_slots,
     resolve_optim_update,
     resolve_sparse_lowering,
     sparse_embedding_update,

@@ -49,12 +49,20 @@ This module is the one home of that machinery:
   - ``'sort'`` — the ISSUE-classic in-step form: ``argsort`` + segment
     ids by ``cumsum`` of boundaries, then gather -> rule -> sorted unique
     scatter over the LIVE prefix of the slots only, ``SLOT_BLOCK`` slots
-    a loop trip. No per-chunk auxiliary memory. Default on TPU, where
+    a loop trip. Nothing rides the chunk cache. Default on TPU, where
     HBM is the scarce resource. What the chip read at 2^29 rows and
-    6.8M occurrences a step (v5e, PERF.md §5): the sort is 0.06 s of a
-    step — not "cheap", but never the first cost; the table-wide gathers
+    6.8M occurrences a step (v5e, PERF.md §5): the table-wide gathers
     were 0.42 s while they ran over the 6.8M-slot static bound, and cost
-    per INDEX (~14 ns), not per distinct row — hence the live prefix.
+    per INDEX (~14 ns), not per distinct row — hence the live prefix;
+    the part of the dedup that reads the keys alone (``sort_keys``: the
+    sort, the take of the sorted keys, the ``uniq`` scatter) was 0.10 s
+    of the 0.39 s step that left. A cached chunk's keys do not change
+    between epochs, so the fused replay builds that half once per chunk
+    and dispatch and hands it to its steps (``keys=``): ``sort_keys_bytes``
+    a chunk of temp in that one program, for as long as it runs, taken
+    only where the caller's cache budget holds it
+    (``models/hashed_linear._hoist_sort_keys``). ``_hashed_step`` and a
+    replay without the room sort in the step, as before.
 
 * **kill-switch** — ``OTPU_SPARSE_UPDATE=0`` resolves every ``sparse_*``
   rule to its ``dense_*`` twin (mirroring ``OTPU_DONATE``'s convention):
@@ -88,7 +96,9 @@ __all__ = [
     "plan_pack_widths", "plan_packed_field_shapes", "pack_plan_np",
     "unpack_plan",
     "occurrence_dead", "apply_rule", "dense_update",
-    "sparse_embedding_update", "note_slot_blocks", "finalize_lazy_decay",
+    "sort_keys", "sort_slots", "sort_keys_bytes",
+    "sparse_embedding_update", "note_slot_blocks", "note_sorts",
+    "finalize_lazy_decay",
 ]
 
 SPARSE_UPDATES = ("sparse_sgd", "sparse_adagrad", "sparse_ftrl")
@@ -116,6 +126,12 @@ _M_SLOT_BLOCKS = REGISTRY.counter(
     "steps took, from the chunks' live slots) | possible (steps x "
     "slot_blocks, the static bound). run/possible = share of the slot "
     "bound the steps really gathered, updated and wrote back")
+_M_SORTS = REGISTRY.counter(
+    "otpu_sparse_sorts_total",
+    "'sort'-lowering key halves (sort, segment ids, uniq) of finished "
+    "fits: which=run (built: one a step, but one a cached chunk and "
+    "dispatch where the fused replay hoists them) | steps (optimizer "
+    "steps). run/steps = share of the steps that paid for their own sort")
 
 
 def sparse_updates_enabled() -> bool:
@@ -145,7 +161,7 @@ def resolve_sparse_lowering(value: str) -> str:
     """'auto' picks the dedup lowering per backend: ``'plan'``
     (host-presorted, gather-based writeback) on CPU where an in-step
     6.8M-element sort costs seconds and unsorted scatters ~240
-    ns/element; ``'sort'`` (in-step argsort, zero per-chunk aux memory)
+    ns/element; ``'sort'`` (in-jit argsort, nothing kept per cached chunk)
     on TPU where HBM is the scarce resource — 'plan' keeps an O(n_dims)
     inverse map per cached chunk. On a v5e at 2^29 rows the in-step sort
     reads 0.06 s of a step (PERF.md §5); 'plan' has not been timed on the
@@ -270,6 +286,13 @@ def note_slot_blocks(run: int, possible: int) -> None:
     them off ``opt_state`` where it already waits for its last loss)."""
     _M_SLOT_BLOCKS.inc(run, which="run")
     _M_SLOT_BLOCKS.inc(possible, which="possible")
+
+
+def note_sorts(run: int, steps: int) -> None:
+    """Add one finished fit's key-half count to the registry: static
+    counts the fit keeps on the host as it dispatches."""
+    _M_SORTS.inc(run, which="run")
+    _M_SORTS.inc(steps, which="steps")
 
 
 def plan_field_shapes(pad_rows: int, n_cat: int, n_dims: int,
@@ -500,14 +523,18 @@ def _segment_sums(g_sorted, seg, n_slots: int):
         seg].add(g_sorted, indices_are_sorted=True)
 
 
-def _sorted_slots(dl, idx, n_dims: int, n_slots: int, n_valid, raw_cats,
-                  vals):
-    """The 'sort' lowering's in-jit dedup: sort the chunk's hashed
-    occurrences (dead ones behind the sentinel ``n_dims``), number the
-    segments in sorted order and sum each one's gradients. Returns
-    ``sums`` [n_slots, k], ``uniq`` [n_slots] (table row per segment,
-    -1 on dead/unused slots) and ``n_live``: the dead sentinel sorts last,
-    so the live slots are exactly the prefix ``[0, n_live)`` of both."""
+def sort_keys(idx, n_dims: int, n_slots: int, n_valid, raw_cats=None):
+    """The key half of the 'sort' lowering's in-jit dedup — everything
+    that reads the chunk's hashed keys and ``n_valid`` and nothing else:
+    sort the occurrences (dead ones behind the sentinel ``n_dims``) and
+    number the segments in sorted order. Returns ``{'order': i32[M] the
+    stable sort's permutation, 'seg': i32[M] each sorted occurrence's
+    segment, 'uniq': i32[n_slots] table row per segment (-1 on
+    dead/unused slots), 'n_live': i32[]}``: the dead sentinel sorts last,
+    so the live slots are exactly the prefix ``[0, n_live)`` of ``uniq``.
+    A cached chunk's keys do not change between epochs, so the fused
+    replay builds this once per chunk and dispatch (``sort_keys_bytes``
+    is what it then holds) and every step reuses it."""
     N, C = idx.shape
     with jax.named_scope("step/sort"):
         dead = occurrence_dead(N, C, n_valid, raw_cats)
@@ -515,13 +542,9 @@ def _sorted_slots(dl, idx, n_dims: int, n_slots: int, n_valid, raw_cats,
         order = jnp.argsort(flat)                         # stable sort
         s_idx = jnp.take(flat, order)
     with jax.named_scope("step/segment"):
-        g = jnp.take(dl, order // C, axis=0)
-        if vals is not None:
-            g = g * jnp.take(vals.reshape(-1), order)[:, None]
         start = jnp.concatenate(
             [jnp.ones((1,), bool), s_idx[1:] != s_idx[:-1]])
         seg = jnp.cumsum(start.astype(jnp.int32)) - 1
-        sums = _segment_sums(g, seg, n_slots)
         # unique row id per segment slot: scatter the segment-start
         # values; non-starts and the dead sentinel route out of range and
         # drop
@@ -530,13 +553,39 @@ def _sorted_slots(dl, idx, n_dims: int, n_slots: int, n_valid, raw_cats,
         ].set(s_idx.astype(jnp.int32), mode="drop")
         # every segment but the dead one
         n_live = seg[-1] + 1 - (s_idx[-1] >= n_dims).astype(jnp.int32)
-    return sums, uniq, n_live
+    return {"order": order, "seg": seg, "uniq": uniq, "n_live": n_live}
+
+
+def _sorted_sums(dl, vals, keys: dict, n_cat: int):
+    """The gradient half: the per-occurrence gradients ``dl[row] (* val)``
+    taken in ``sort_keys``' order and summed per segment."""
+    with jax.named_scope("step/segment"):
+        g = jnp.take(dl, keys["order"] // n_cat, axis=0)
+        if vals is not None:
+            g = g * jnp.take(vals.reshape(-1), keys["order"])[:, None]
+        return _segment_sums(g, keys["seg"], keys["uniq"].shape[0])
+
+
+def sort_slots(pad_rows: int, n_cat: int, n_dims: int) -> int:
+    """Length of the 'sort' lowering's slot arrays: the static bound
+    ``plan_slots`` rounded up to a whole number of blocks, so the last
+    block's slice never has to be clamped back over its neighbour (the
+    pad slots are -1 / zero: dead like any other)."""
+    return slot_blocks(pad_rows, n_cat, n_dims) * min(
+        SLOT_BLOCK, plan_slots(pad_rows, n_cat, n_dims))
+
+
+def sort_keys_bytes(pad_rows: int, n_cat: int, n_dims: int) -> int:
+    """Device bytes of one chunk's ``sort_keys`` (its three i32 arrays) —
+    what the fused replay holds per cached chunk while it runs,
+    and what its caller's budget is asked for."""
+    return 4 * (2 * pad_rows * n_cat + sort_slots(pad_rows, n_cat, n_dims))
 
 
 def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
                             step, *, lowering: str, use_decay: bool,
                             plan=None, n_valid=None, raw_cats=None,
-                            vals=None):
+                            vals=None, keys=None):
     """One touched-row-only table update. ``dl`` is the [N, k] logits
     gradient; per-occurrence gradients are ``dl[row] (* val)``.
 
@@ -544,10 +593,14 @@ def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
     unique rows / inverse map; writeback is a pure GATHER
     (``where(touched, new_rows[inv], emb)``) — the whole step is
     scatter-free except the one sorted segment-sum.
-    'sort': everything derived in-jit (argsort + cumsum-of-boundaries).
-    The slot arrays keep the static bound ``plan_slots`` (a chunk of
-    all-distinct keys fills it), but only their live prefix is gathered,
-    run through the rule and written back: a ``fori_loop`` over blocks of
+    'sort': everything derived in-jit (argsort + cumsum-of-boundaries) —
+    or, where the caller has already run ``sort_keys`` over this chunk
+    (the fused replay, once per chunk and dispatch), handed in as ``keys``
+    and only the gradient half computed here: the same operations on the
+    same values either way. The slot arrays keep the static bound
+    ``plan_slots`` (a chunk of all-distinct keys fills it), but only their
+    live prefix is gathered, run through the rule and written back: a
+    ``fori_loop`` over blocks of
     ``SLOT_BLOCK`` slots whose trip count ``ceil(n_live / SLOT_BLOCK)`` is
     computed on the device from the chunk's own keys. Each trip's
     writeback is a sorted unique scatter with out-of-range dead slots
@@ -589,14 +642,12 @@ def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
     if lowering != "sort":
         raise ValueError(f"unknown sparse lowering {lowering!r}")
     N, C = idx.shape
-    U = plan_slots(N, C, D)
-    B = min(SLOT_BLOCK, U)
-    # the slot arrays are allocated at a whole number of blocks, so the
-    # last block's slice never has to be clamped back over its neighbour
-    # (the pad slots are -1 / zero: dead like any other)
-    sums, uniq, n_live = _sorted_slots(
-        dl, idx, D, slot_blocks(N, C, D) * B, n_valid, raw_cats, vals)
-    n_blocks = (n_live + (B - 1)) // B
+    B = min(SLOT_BLOCK, plan_slots(N, C, D))
+    if keys is None:
+        keys = sort_keys(idx, D, sort_slots(N, C, D), n_valid, raw_cats)
+    sums = _sorted_sums(dl, vals, keys, C)
+    uniq = keys["uniq"]
+    n_blocks = (keys["n_live"] + (B - 1)) // B
     sc = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
 
     def block(i, tables):

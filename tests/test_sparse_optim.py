@@ -253,13 +253,37 @@ def test_value_weighted_idx_minus_one_inert(session):
 
 # ------------------------------------------- the 'sort' lowering's block loop
 
+def _sorted_slots_whole(dl, idx, n_dims, n_slots, n_valid, raw_cats, vals):
+    """The 'sort' lowering's dedup as ONE function of keys and gradients,
+    as it was before its key half (``sort_keys``) and its gradient half
+    (``_sorted_sums``) were told apart so that a replay can build the
+    first once per chunk. Kept here, not in the package, as what the two
+    halves composed must equal bit for bit."""
+    N, C = idx.shape
+    dead = sparse_mod.occurrence_dead(N, C, n_valid, raw_cats)
+    flat = jnp.where(dead, jnp.int32(n_dims), idx).reshape(-1)
+    order = jnp.argsort(flat)
+    s_idx = jnp.take(flat, order)
+    g = jnp.take(dl, order // C, axis=0)
+    if vals is not None:
+        g = g * jnp.take(vals.reshape(-1), order)[:, None]
+    start = jnp.concatenate([jnp.ones((1,), bool), s_idx[1:] != s_idx[:-1]])
+    seg = jnp.cumsum(start.astype(jnp.int32)) - 1
+    sums = sparse_mod._segment_sums(g, seg, n_slots)
+    uniq = jnp.full((n_slots,), -1, jnp.int32).at[
+        jnp.where(start & (s_idx < n_dims), seg, n_slots)
+    ].set(s_idx.astype(jnp.int32), mode="drop")
+    n_live = seg[-1] + 1 - (s_idx[-1] >= n_dims).astype(jnp.int32)
+    return sums, uniq, n_live
+
+
 def _unblocked_sort_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
                            step, *, use_decay, n_valid, raw_cats, vals):
     """The 'sort' lowering as it was before the block loop: one gather ->
     rule -> write-back over the whole static slot bound. Kept here, not in
     the package, as what the blocked form must equal bit for bit."""
     D = emb.shape[0]
-    sums, uniq, _n_live = sparse_mod._sorted_slots(
+    sums, uniq, _n_live = _sorted_slots_whole(
         dl, idx, D, plan_slots(*idx.shape, D), n_valid, raw_cats, vals)
     p_rows, slot_rows = sparse_mod._touched_rows_update(
         kind, emb, t, slots, sums, uniq, lr, decay, reg, l1, step,
@@ -341,6 +365,69 @@ def test_blocked_update_equals_unblocked_bitwise(monkeypatch, kind,
             idx[live].tolist())
 
 
+#: case -> (n_dims, n_valid of 12 rows, how the 12 x 4 keys are drawn,
+#: value-weighted pairs with dead raw indices)
+_KEY_CASES = {
+    "padding-rows": (64, 7, "random", False),
+    "all-padding": (64, 0, "random", False),
+    "vw-dead-pairs": (64, 10, "random", True),
+    "vw-dead-and-padding": (64, 5, "random", True),
+    "all-distinct": (256, 12, "distinct", False),
+    "all-distinct-vw": (256, 12, "distinct", True),
+    "all-equal": (64, 12, "equal", False),
+    "all-equal-padding-vw": (64, 9, "equal", True),
+    "table-smaller-than-chunk": (16, 12, "random", False),
+}
+
+
+@pytest.mark.parametrize("case", list(_KEY_CASES))
+def test_key_half_and_gradient_half_equal_the_whole_bitwise(case):
+    """``sort_keys`` (keys and n_valid only) then ``_sorted_sums``
+    (gradients, by the keys' order) is the dedup that used to be one
+    function: sums, uniq and n_live equal it bit for bit, computed in one
+    program or the key half in a program of its own (as the fused replay
+    computes it, ahead of its scan)."""
+    D, n_valid, draw, vw = _KEY_CASES[case]
+    N, C, k = 12, 4, 2
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    idx = {"random": lambda: rng.integers(0, D, (N, C)),
+           "distinct": lambda: rng.permutation(D)[:N * C].reshape(N, C),
+           "equal": lambda: np.full((N, C), 5)}[draw]().astype(np.int32)
+    raw = vals = None
+    if vw:
+        raw = rng.integers(0, 1000, (N, C)).astype(np.float32)
+        raw[rng.permutation(N)[:5], rng.integers(0, C, 5)] = -1.0
+        raw = jnp.asarray(raw)
+        vals = jnp.asarray(rng.uniform(0.5, 1.5, (N, C)), jnp.float32)
+    dl = jnp.asarray(rng.normal(size=(N, k)), jnp.float32)
+    idx, nv = jnp.asarray(idx), jnp.int32(n_valid)
+    n_slots = sparse_mod.sort_slots(N, C, D)
+    assert n_slots == plan_slots(N, C, D)    # under one block: no pad slots
+    want = jax.jit(lambda dl, idx, nv: _sorted_slots_whole(
+        dl, idx, D, n_slots, nv, raw, vals))(dl, idx, nv)
+
+    def halves(dl, idx, nv, keys=None):
+        if keys is None:
+            keys = sparse_mod.sort_keys(idx, D, n_slots, nv, raw)
+        return (sparse_mod._sorted_sums(dl, vals, keys, C), keys["uniq"],
+                keys["n_live"])
+
+    keys = jax.jit(lambda idx, nv: sparse_mod.sort_keys(
+        idx, D, n_slots, nv, raw))(idx, nv)
+    assert {n: (v.shape, v.dtype.name) for n, v in keys.items()} == {
+        "order": ((N * C,), "int32"), "seg": ((N * C,), "int32"),
+        "uniq": ((n_slots,), "int32"), "n_live": ((), "int32")}
+    assert sparse_mod.sort_keys_bytes(N, C, D) == sum(
+        v.nbytes for n, v in keys.items() if n != "n_live")
+    for got in (jax.jit(halves)(dl, idx, nv),
+                jax.jit(halves)(dl, idx, nv, keys)):
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    live = np.asarray(idx)[:n_valid][
+        np.asarray(raw)[:n_valid] >= 0 if vw else slice(None)]
+    assert int(want[2]) == len(set(live.ravel().tolist()))
+
+
 def test_slot_blocks_are_counted_per_fit(session, data):
     """The fit reads the device-side trip count once at its end into the
     registry: run <= possible = steps x slot_blocks, and a 'plan' fit
@@ -360,6 +447,186 @@ def test_slot_blocks_are_counted_per_fit(session, data):
     _fit(session, Xall, y, optim_update="sparse_adagrad",
          sparse_lowering="plan", reg_param=1e-3)
     assert c.value(which="possible") - before[1] == possible
+
+
+# ------------------------------------------- the replay's hoisted sort keys
+
+def _layout_session(layout):
+    from orange3_spark_tpu.core.session import TpuSession
+    from orange3_spark_tpu.parallel.partitioner import SPMDPartitioner
+
+    if layout == "2x2":      # the four-chip cell's mesh (tests/test_spmd_h30)
+        part = SPMDPartitioner(jax.devices()[:4], model_parallel=2)
+        assert dict(part.mesh.shape) == {"data": 2, "model": 2}
+        return part.session
+    return TpuSession(TpuSession.default_mesh(jax.devices()[:1]))
+
+
+def _replay_state(p, session, n_chunks, n_valid_last, seed):
+    """A fresh fit state and a stack of ``n_chunks`` random chunks as
+    fit_stream caches and stacks them (encoded by the fit's own codec),
+    the last one part padding."""
+    from orange3_spark_tpu.io.multihost import put_sharded
+    from orange3_spark_tpu.models.hashed_linear import (
+        _encode_chunk_np, _init_fit_state, _put_encoded,
+    )
+
+    theta, opt, salts_np, salts, kw = _init_fit_state(p, session)
+    rng = np.random.default_rng(seed)
+    rows = session.pad_rows(p.chunk_rows)
+    chunks = []
+    for _ in range(n_chunks):
+        X = np.concatenate([
+            (rng.random((rows, 1)) < 0.3).astype(np.float32),
+            rng.standard_normal((rows, p.n_dense)).astype(np.float32),
+            rng.integers(0, 5000, (rows, p.n_cat)).astype(np.float32),
+        ], axis=1)
+        X[rng.random(X.shape) < 0.02] = np.nan    # missing cells, imputed
+        X[:, 0] = np.nan_to_num(X[:, 0])
+        chunks.append(
+            put_sharded(X, session.row_sharding) if kw["codec"] is None
+            else _put_encoded(_encode_chunk_np(kw["codec"], X, salts_np),
+                              session))
+    one = jnp.zeros((1,), jnp.float32)
+    stacks = (jax.tree.map(lambda *xs: jnp.stack(xs), *chunks),
+              jnp.asarray([rows] * (n_chunks - 1) + [n_valid_last],
+                          jnp.int32),
+              jnp.stack([one] * n_chunks), jnp.stack([one] * n_chunks))
+    return theta, opt, stacks, salts, kw
+
+
+@pytest.mark.parametrize("layout", ["one-device", "2x2"])
+@pytest.mark.parametrize("trips", [1, 4], ids=["1trip", "4trips"])
+@pytest.mark.parametrize("cache_dtype", ["packed", "f32"])
+@pytest.mark.parametrize("kind", ["adagrad", "sgd", "ftrl"])
+def test_replay_with_hoisted_keys_equals_replay_without(monkeypatch, kind,
+                                                        cache_dtype, trips,
+                                                        layout):
+    """``_hashed_replay_epochs(hoist_keys=True)`` — every chunk's sort
+    keys built once ahead of the epoch scan — leaves the tables, every
+    slot table, the last-seen steps, the dense leaf, the block count and
+    every loss as the same replay leaves them with the sort inside each
+    step: bit for bit on one device; on the (2,2) mesh GSPMD partitions
+    two different programs, and they agree within float32 rounding of the
+    cross-shard sums."""
+    from orange3_spark_tpu.models.hashed_linear import _hashed_replay_epochs
+
+    n_dims = 1 << 12
+    if trips > 1:
+        # a table size no other test compiles, so the patched block is
+        # traced: ~1000 live slots of a 1025-slot bound, in blocks of 256
+        monkeypatch.setattr(sparse_mod, "SLOT_BLOCK", 256)
+        n_dims = 1 << 10
+    session = _layout_session(layout)
+    p = StreamingHashedLinearEstimator(
+        n_dims=n_dims, n_dense=4, n_cat=6, epochs=4, step_size=0.05,
+        chunk_rows=1024, reg_param=1e-3, l1_param=1e-4,
+        loss="squared_hinge", label_in_chunk=True,
+        optim_update=f"sparse_{kind}", sparse_lowering="sort",
+        cache_dtype=cache_dtype).params
+    out = {}
+    for hoist in (False, True):
+        theta, opt, stacks, salts, kw = _replay_state(
+            p, session, n_chunks=3, n_valid_last=700, seed=11)
+        assert (kw["codec"] is None) == (cache_dtype == "f32")
+        out[hoist] = jax.device_get(_hashed_replay_epochs(
+            theta, opt, stacks, salts, jnp.float32(p.reg_param),
+            jnp.float32(p.step_size), jnp.float32(p.l1_param), n_epochs=3,
+            hoist_keys=hoist, **kw))
+    (theta, opt, losses), (theta_h, opt_h, losses_h) = out[False], out[True]
+    assert int(opt["step"]) == int(opt_h["step"]) == 9
+    assert int(opt["blocks"]) == int(opt_h["blocks"]) >= 9 * trips - 2
+    assert np.isfinite(losses).all() and losses.shape == (3, 3)
+    assert np.abs(theta["emb"]).max() > 1e-3
+    same = (np.testing.assert_array_equal if layout == "one-device" else
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=2e-6,
+                                                    atol=1e-8))
+    for a, b in zip(jax.tree.leaves((theta, opt, losses)),
+                    jax.tree.leaves((theta_h, opt_h, losses_h))):
+        same(a, b)
+
+
+def test_hoisted_keys_are_gated_by_the_cache_budget(session, data):
+    """The stacked sort keys are a temp of the replay program, so the
+    replay takes them only where ``cache_device_bytes`` holds the cache,
+    its stack AND them; otherwise it runs as before, a sort a step — to
+    the same answer. ``otpu_sparse_sorts_total`` says which it was."""
+    from orange3_spark_tpu.optim.sparse import sort_keys_bytes
+
+    Xall, y = data
+    sorts = REGISTRY.get("otpu_sparse_sorts_total")
+
+    def fit(**kw):
+        before = (sorts.value(which="run"), sorts.value(which="steps"))
+        st: dict = {}
+        m = _fit(session, Xall, y, optim_update="sparse_adagrad",
+                 sparse_lowering="sort", reg_param=1e-3, stage_times=st,
+                 **kw)
+        assert m.n_steps_ == 16                  # 4 chunks x 4 epochs
+        assert sorts.value(which="steps") - before[1] == m.n_steps_
+        return m, st, sorts.value(which="run") - before[0]
+
+    roomy, st, run = fit()
+    assert st["replay_source"] == "fused" and run == 4 * (1 + 1)
+    key_bytes = 4 * sort_keys_bytes(session.pad_rows(BASE["chunk_rows"]),
+                                    BASE["n_cat"], BASE["n_dims"])
+    # admits the cache and its stack, not the keys beside them
+    tight, st, run = fit(cache_device_bytes=2 * st["cache_bytes"]
+                         + key_bytes - 1)
+    assert st["replay_source"] == "fused" and run == tight.n_steps_
+    assert _emb_diff(tight, roomy) == 0.0
+    # ... and one byte more does
+    _, _, run = fit(cache_device_bytes=2 * st["cache_bytes"] + key_bytes)
+    assert run == 4 * (1 + 1)
+    # one dispatch an epoch builds the keys and uses them once; K = 2
+    # epochs a dispatch: 3 replay epochs in 2 dispatches
+    epoch, st, run = fit(replay_granularity="epoch")
+    assert st["replay_source"] == "fused_epoch" and run == epoch.n_steps_
+    assert _emb_diff(epoch, roomy) == 0.0
+    grouped, _, run = fit(replay_granularity="epoch", epochs_per_dispatch=2)
+    assert run == 4 * (1 + 2) and _emb_diff(grouped, roomy) == 0.0
+    # no replay, no hoist; 'plan' builds no sort keys at all
+    _, _, run = fit(fused_replay=False)
+    assert run == 16
+    before = sorts.value(which="steps")
+    _fit(session, Xall, y, optim_update="sparse_adagrad",
+         sparse_lowering="plan", reg_param=1e-3)
+    assert sorts.value(which="steps") == before
+
+
+@pytest.mark.parametrize("room", ["roomy", "tight"])
+def test_warm_replay_compiles_the_replay_the_fit_dispatches(session, data,
+                                                            room):
+    """``warm_replay`` resolves the hoist from the same budget by the same
+    rule as the fit, so the fit's replay program is the warmed one: the
+    timed fit traces no second ``_hashed_replay_epochs``."""
+    from orange3_spark_tpu.models.hashed_linear import (
+        _hashed_replay_epochs, estimate_cached_chunk_bytes,
+    )
+    from orange3_spark_tpu.optim.sparse import sort_keys_bytes
+
+    Xall, y = data
+    kw = dict(BASE, optim_update="sparse_adagrad", sparse_lowering="sort",
+              reg_param=1e-3, n_dims=1 << 13)
+    est = StreamingHashedLinearEstimator(**kw)
+    budget = 8 << 30
+    if room == "tight":     # the cache and its stack, half the keys
+        budget = (2 * 4 * estimate_cached_chunk_bytes(est.params, session)
+                  + 2 * sort_keys_bytes(session.pad_rows(kw["chunk_rows"]),
+                                        kw["n_cat"], kw["n_dims"]))
+    sorts = REGISTRY.get("otpu_sparse_sorts_total")
+    run0 = sorts.value(which="run")
+    assert est.warm_replay(4, session=session,
+                           cache_device_bytes=budget) is not None
+    traced = _hashed_replay_epochs.donated._cache_size()
+    st: dict = {}
+    m = est.fit_stream(array_chunk_source(Xall, y, chunk_rows=1000),
+                       session=session, cache_device=True,
+                       cache_device_bytes=budget, stage_times=st)
+    assert st["replay_source"] == "fused"
+    assert _hashed_replay_epochs.donated._cache_size() == traced
+    assert sorts.value(which="run") - run0 == (
+        4 * 2 if room == "roomy" else m.n_steps_)
 
 
 # ------------------------------------------------- replay-path parity triple

@@ -241,20 +241,66 @@ def test_hashed_step_sort_lowering_compiles(one_chip, hashed, n_dims):
     _tables_stay_in_place(compiled, n_dims)
 
 
-@pytest.mark.parametrize("n_dims", [N_DIMS, BENCH_DIMS],
-                         ids=["smoke-2^22", "bench-2^29"])
-def test_hashed_replay_epochs_compiles(one_chip, hashed, n_dims):
+def _sorts_in_loops_over_tables(text: str, table_rows: int) -> list:
+    """How many ``sort`` instructions each ``while`` body whose carried
+    tuple holds a table of ``table_rows`` rows runs, itself or through what
+    it calls — the replay's epoch scan and everything under it (chunk scan,
+    step, block loop). The loop that builds the chunks' sort keys ahead of
+    the scan carries the chunk stack and no table, so it is not among
+    them."""
+    comps = {}
+    for block in re.split(r"\n(?=(?:ENTRY )?%[\w.-]+ \()", text):
+        m = re.match(r"(?:ENTRY )?%([\w.-]+) \((.*)", block)
+        if m:
+            comps[m.group(1)] = (m.group(2), block)
+
+    @functools.lru_cache(maxsize=None)
+    def sorts(name):
+        body = comps[name][1]
+        called = set(re.findall(
+            r"(?:body|condition|calls|to_apply)=%([\w.-]+)", body))
+        return len(re.findall(r" sort\(", body)) + sum(
+            sorts(c) for c in called if c in comps and c != name)
+
+    bodies = set(re.findall(r" while\(.*?body=%([\w.-]+)", text))
+    return [sorts(b) for b in sorted(bodies)
+            if re.search(rf"\[{table_rows}[,\]]",
+                         comps[b][0].split("\n")[0])]
+
+
+@pytest.mark.parametrize("n_dims, hoist", [
+    (N_DIMS, True), (BENCH_DIMS, True), (N_DIMS, False)],
+    ids=["smoke-2^22", "bench-2^29", "smoke-2^22-sort-in-step"])
+def test_hashed_replay_epochs_compiles(one_chip, hashed, n_dims, hoist):
     """The library-default one-dispatch replay: 7 epochs over 6 cached
-    chunks in ONE program (chip_smoke's fit: 8 chunks less 2 held out)."""
+    chunks in ONE program (chip_smoke's fit: 8 chunks less 2 held out),
+    with every chunk's sort keys built once ahead of the epoch scan
+    (``hoist_keys``, what a fit whose cache budget holds them runs) — and
+    as a fit with a tight budget runs it, the sort inside each step.
+
+    By hand at 2^29 (PR 29, this sandbox; PR 27 read temp 332,963,840 for
+    the sort-in-step form, which still reads that): args 6,645,879,296 /
+    temp 1,055,901,696 / alias 6,442,454,016 bytes — 0.503 GB of it the
+    stacked keys (three i32 vectors a chunk, lane-tiled [6, M/128, 128]:
+    stacked [6, M] the TPU's (8, 128) tiling pads 6 chunks to 8 and the
+    temp reads 1,169,083,392); two sorts in the program (the argsort and
+    the ``uniq`` scatter's own), both in the loop that builds the keys."""
     from orange3_spark_tpu.models.hashed_linear import _hashed_replay_epochs
 
     (theta, opt, X, nv, y, w, salts, reg, lr), kw = _step_args(
         _at_dims(hashed, n_dims), one_chip, one_chip, one_chip, stack=6)
     compiled = _hashed_replay_epochs.donated.lower(
-        theta, opt, (X, nv, y, w), salts, reg, lr, n_epochs=7, **kw
+        theta, opt, (X, nv, y, w), salts, reg, lr, n_epochs=7,
+        hoist_keys=hoist, **kw
     ).compile()
     _fits(compiled)
     _tables_stay_in_place(compiled, n_dims)
+    text = compiled.as_text()
+    in_scan = _sorts_in_loops_over_tables(text, n_dims)
+    assert in_scan and text.count(" sort(") >= 2
+    # the epoch scan (and every loop under it) runs a sort only where the
+    # keys were not hoisted
+    assert all(n == 0 for n in in_scan) if hoist else max(in_scan) >= 2
 
 
 def test_hashed_predict_compiles_at_bucket(one_chip, hashed):
@@ -346,21 +392,26 @@ def test_hashed_step_compiles_model_sharded_at_2_30(topo, hashed):
 
 @pytest.mark.slow
 def test_hashed_replay_epochs_compiles_model_sharded_at_2_30(topo, hashed):
-    """The same cell's one-dispatch replay (7 epochs x 6 chunks). Slow
-    (~85 s more in this file's one worker); by hand (PR 28, this
-    sandbox): args 6,569,333,248 / temp 178,806,272 / alias
-    6,442,454,016 bytes a device, the step's six collectives and no
-    other, no operation of the table's whole shape."""
+    """The same cell's one-dispatch replay (7 epochs x 6 chunks), the
+    chunks' sort keys built ahead of the epoch scan. Slow (~85 s more in
+    this file's one worker); by hand (PR 28, this sandbox, sort in the
+    step): args 6,569,333,248 / temp 178,806,272 / alias 6,442,454,016
+    bytes a device, the step's six collectives and no other, no operation
+    of the table's whole shape. PR 29, keys hoisted (replicated: 0.503 GB a
+    device): temp 774,028,288, everything else as it was."""
     from orange3_spark_tpu.models.hashed_linear import _hashed_replay_epochs
 
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
     (theta, opt, X, nv, y, w, salts, reg, lr), kw = _spmd_args(
         _at_dims(hashed, MESH_DIMS), mesh, stack=6)
     compiled = _hashed_replay_epochs.donated.lower(
-        theta, opt, (X, nv, y, w), salts, reg, lr, n_epochs=7, **kw
+        theta, opt, (X, nv, y, w), salts, reg, lr, n_epochs=7,
+        hoist_keys=True, **kw
     ).compile()
     _fits(compiled)
     _tables_stay_sharded(compiled, MESH_DIMS)
+    in_scan = _sorts_in_loops_over_tables(compiled.as_text(), MESH_DIMS // 2)
+    assert in_scan and all(n == 0 for n in in_scan)
 
 
 # ------------------------------------------------------------------- kmeans
