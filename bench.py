@@ -224,16 +224,14 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
     # replay wall on XLA:CPU (the full-table moment sweeps + in-loss L2;
     # not re-decided on a chip yet), so the bench default is the touched-row
     # sparse path. OTPU_OPTIM_UPDATE pins a rule ('adam' reproduces the
-    # pre-optim records); OTPU_SPARSE_UPDATE=0 is the subsystem kill-switch
-    # (resolves sparse_* to the dense twin; the resolution is surfaced in
-    # the JSON's optim_update field either way). The dense A/B arm below
+    # pre-optim records; a dense_* name the full-sweep twin; the rule is
+    # surfaced in the JSON's optim_update field). The dense A/B arm below
     # measures the legacy path in the SAME run.
     optim_update = os.environ.get("OTPU_OPTIM_UPDATE", "sparse_adagrad")
     # Cache precision (io/codec.py): the bench default is the full
     # compressed codec — bf16 dense block, u8 label, bit-packed hashed
-    # indices and (under the CPU 'plan' lowering) bit-packed plan arrays —
-    # so the HBM cache, the disk spill and the h2d DMA move ~2x fewer
-    # bytes and the fused-replay gate admits ~2x the rows.
+    # indices — so the HBM cache, the disk spill and the h2d DMA move
+    # fewer bytes and the fused-replay gate admits more rows.
     # OTPU_CACHE_DTYPE pins a mode ('f32' restores the legacy cache
     # exactly — the kill-switch); the f32 A/B arm below measures the
     # legacy cache's step over the SAME data in the same run.
@@ -247,10 +245,6 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
             fused_replay=fused_env, replay_granularity=granularity,
             epochs_per_dispatch=epochs_per_dispatch,
             defer_epoch1=defer if defer_epoch1 is None else defer_epoch1,
-            # 'auto' -> 'fused' everywhere (tools/step_ab.py 2026-07-31 on
-            # the v5e chip: fused 0.27 ms/step < sorted 0.41 < per_column
-            # 0.75; XLA:CPU sorts slowly so fused wins there too)
-            emb_update="auto",
             optim_update=optim_update if optim is None else optim,
             cache_dtype=cache_dtype,
         )
@@ -268,9 +262,7 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
     n_chunks = -(-n_rows // session.pad_rows(CHUNK_ROWS))
     holdout_chunks = max(min(HOLDOUT_CHUNKS, n_chunks - 1), 0)
     cache_budget = cache_bytes
-    # per-chunk cache bytes under the RESOLVED codec + optimizer lowering
-    # (a sparse-'plan' fit caches per-chunk touched-row plans alongside
-    # the chunks; a compressed codec shrinks both) — one shared estimator
+    # per-chunk cache bytes under the RESOLVED codec — one shared estimator
     # so this pre-gate cannot disagree with fit_stream's fusion gate,
     # which reads the REAL cache.nbytes
     from orange3_spark_tpu.models.hashed_linear import (
@@ -470,11 +462,9 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
                    else init_optim_state(kw["optim_update"], theta))
 
             def args(c):
-                plan = (c[4] if len(c) > 4
-                        and kw["sparse_lowering"] == "plan" else None)
                 return (c[0], c[1], c[2], c[3], salts,
                         jnp.float32(reg), jnp.float32(step_size),
-                        plan, jnp.float32(0.0))
+                        jnp.float32(0.0))
 
             return theta, opt, kw, args
 
@@ -519,7 +509,7 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
             best_on = best_off = None
             for i in range(2 * n_pairs):
                 on = i % 2 == 0
-                # pair the arms on the SAME chunk: sparse-plan step
+                # pair the arms on the SAME chunk: the sparse step's
                 # time is data-dependent, and with an even chunk
                 # count i % len(chs) would hand each arm a disjoint
                 # chunk set — workload bias masquerading as overhead
@@ -736,12 +726,9 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
                   / stage_times["replay_fused_s"] / n_chips, 1)
             if stage_times.get("replay_fused_s") else None),
         # ---- optimizer A/B (optim/ subsystem) ----
-        # the RESOLVED rule + lowerings the timed fit ran (the 'auto'
-        # decisions, the OTPU_SPARSE_UPDATE kill-switch, and the per-
-        # backend plan/sort choice are all visible post-hoc)
+        # the RESOLVED rule + dedup lowering the timed fit ran
         "optim_update": stage_times.get("optim_update"),
         "sparse_lowering": stage_times.get("sparse_lowering"),
-        "emb_update": stage_times.get("emb_update"),
         # dense arm of the same run: the legacy dense-adam step over the
         # SAME cached chunks (probe-derived per-chunk rate; the sparse
         # pair is pure_step_ms / the timed replay rate above)
@@ -807,10 +794,6 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
         "cache_entries": cache_rep["cache_entries"],
         "parse_s": round(stage_times.get("parse_s", 0.0), 2),
         "h2d_s": round(stage_times.get("h2d_s", 0.0), 2),
-        # prefetch-thread seconds building touched-row plans (sparse
-        # 'plan' lowering only; overlaps device work like parse_s)
-        "plan_s": (round(stage_times["plan_s"], 2)
-                   if "plan_s" in stage_times else None),
         "epoch1_s": round(epoch_s[0], 2) if epoch_s else None,
         "device_epoch_s": (round(device_epoch, 3)
                            if device_epoch is not None else None),

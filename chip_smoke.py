@@ -146,8 +146,8 @@ def make_estimator(sz: Sizes):
     )
 
     # bench.py's Criteo configuration; everything not named is the library
-    # default — replay_granularity, fused_replay, and the backend-resolved
-    # sparse_lowering / emb_update / cache codec ('auto')
+    # default — replay_granularity, fused_replay, sparse_lowering, and the
+    # session-resolved cache codec ('auto')
     return StreamingHashedLinearEstimator(
         n_dims=sz.n_dims, n_dense=N_DENSE, n_cat=N_CAT, epochs=sz.epochs,
         chunk_rows=sz.chunk_rows, label_in_chunk=True,
@@ -175,7 +175,6 @@ def fit_criteo(sz: Sizes, path: str, session=None):
 # ------------------------------------------------------------------- fit
 def phase_fit(sz: Sizes, seed: int, devices):
     import bench
-    from orange3_spark_tpu.models.hashed_linear import resolve_emb_update
     from orange3_spark_tpu.optim.sparse import resolve_sparse_lowering
 
     clock = Clock()
@@ -191,7 +190,6 @@ def phase_fit(sz: Sizes, seed: int, devices):
          holdout_chunks=len(model.holdout_chunks_),
          replay_granularity=p.replay_granularity,
          sparse_lowering=resolve_sparse_lowering(p.sparse_lowering),
-         emb_update=resolve_emb_update(p),
          cache_dtype=codec.mode if codec is not None else "f32",
          n_steps=model.n_steps_, final_loss=model.final_loss_,
          holdout_auc=ev["auc"], holdout_logloss=ev["logloss"],

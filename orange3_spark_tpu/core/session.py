@@ -41,8 +41,8 @@ class TpuSession:
     #: compression (bf16 floats + lossless bit-packed ints — ~2x cache/
     #: spill/DMA capacity); assign 'f32' to opt a whole session back onto
     #: the legacy layout. The per-fit ``OTPU_CACHE_DTYPE`` env kill-switch
-    #: overrides BOTH this and the param, and like ``OTPU_SPARSE_UPDATE``
-    #: it resolves ONCE at fit entry into a static jit argument.
+    #: overrides BOTH this and the param, and resolves ONCE at fit
+    #: entry into a static jit argument.
     default_cache_dtype: str = "packed"
 
     _lock = threading.Lock()

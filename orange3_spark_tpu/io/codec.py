@@ -10,8 +10,8 @@ them inside the jitted step (a cheap decode XLA fuses into the consumer),
 so HBM, disk and the h2d DMA all move ~2x fewer bytes while the math stays
 f32.
 
-Three cache dtypes, resolved ONCE at fit entry (the ``OTPU_SPARSE_UPDATE``
-convention — the resolution is a static jit argument, never the env var):
+Three cache dtypes, resolved ONCE at fit entry (the resolution is a
+static jit argument, never the env var):
 
 * ``'f32'``    — the legacy layout, bit-for-bit. The kill-switch target.
 * ``'bf16'``   — dense float features stored bfloat16 (lossy, bounded:
@@ -19,21 +19,16 @@ convention — the resolution is a static jit argument, never the env var):
   (labels where exact, categorical codes) stay exact.
 * ``'packed'`` — bf16 floats PLUS lossless integer bit-packing: values with
   a statically known range (hashed categorical indices bounded by
-  ``n_dims``, the sparse-optimizer plan arrays bounded by chunk/table
-  shape) are stored at their true bit width in a u32 carrier and unpacked
-  with static shifts/masks in-jit.
+  ``n_dims``) are stored at their true bit width in a u32 carrier and
+  unpacked with static shifts/masks in-jit.
 
 Layering: this module knows nothing about chunk layouts or models — it
 provides the primitives (bit packing, bf16 host encode) and the policy
 resolver; ``models/hashed_linear`` and ``io/streaming`` own their layouts.
 
-Bit-packing layouts (both decode with STATIC shift/mask ops — no gathers):
-
-* per-row: ``[N, C]`` values at ``b`` bits -> ``[N, ceil(C*b/32)]`` u32.
-  Row-aligned, so the packed array row-shards exactly like the raw one.
-* 32-group (flat): ``[n]`` values at ``b`` bits -> ``[ceil(n/32), b]`` u32
-  — 32 b-bit values fill exactly b words, zero padding waste. Used for the
-  (replicated) plan arrays. ``b = 1`` packs a bit array 32x.
+Bit-packing layout (decodes with STATIC shift/mask ops — no gathers):
+per-row, ``[N, C]`` values at ``b`` bits -> ``[N, ceil(C*b/32)]`` u32.
+Row-aligned, so the packed array row-shards exactly like the raw one.
 """
 
 from __future__ import annotations
@@ -48,7 +43,6 @@ import numpy as np
 __all__ = [
     "CACHE_DTYPES", "BF16", "SpillCorruptionError", "resolve_cache_dtype",
     "force_cache_dtype", "bit_width", "pack_rows_np", "unpack_rows",
-    "pack_flat_np", "unpack_flat",
 ]
 
 
@@ -161,86 +155,3 @@ def unpack_rows(packed, bits: int, n_cols: int):
     if not cols:
         return jnp.zeros((packed.shape[0], 0), jnp.int32)
     return jnp.stack(cols, axis=1)
-
-
-def _planes(bits: int) -> tuple:
-    """Decomposition of a bit width into word-divisor plane widths
-    (16/8/4/2/1) — e.g. 18 -> (16, 2), 23 -> (16, 8). Within a plane
-    every field sits wholly inside one u32 word, so the decode is a
-    single broadcast shift+mask+reshape per plane: no cross-word
-    combines, no gathers, no 32-way stacks (the naive sequential-bit
-    layout decoded at ~60 ns/value on XLA:CPU — a stack of 32 strided
-    extracts; planes decode in a handful of dense vectorized passes).
-
-    Each plane costs a full pass over the data at decode, so FEWER planes
-    beat exact bit counts: widths may round UP by at most 2 bits when
-    that removes a plane (23 stores as 16+8=24 — one pass saved for a
-    4% size cost — while 9 stays 8+1: rounding to 16 would waste 7)."""
-    best = None
-    for m in range(32):                       # subsets of {16, 8, 4, 2, 1}
-        sizes = tuple(s for i, s in enumerate((16, 8, 4, 2, 1))
-                      if m & (1 << i))
-        total = sum(sizes)
-        if bits <= total <= bits + 2:
-            key = (len(sizes), total)
-            if best is None or key < best[0]:
-                best = (key, sizes)
-    return best[1]
-
-
-def pack_flat_np(vals: np.ndarray, bits: int) -> np.ndarray:
-    """Host-side flat pack: ``[n]`` unsigned values at ``bits`` bits each
-    -> ``[ceil(n/32) * bits]`` u32 — exact bit count, zero waste. The
-    value's bits split across the ``_planes`` sub-arrays, concatenated:
-    plane of width s holds 32/s consecutive values' s-bit fields per
-    word. ``bits=1`` is the bit-array case (32x)."""
-    mask = _check_bits(bits)
-    vals = np.asarray(vals).astype(np.uint32) & mask
-    n = vals.shape[0]
-    B = -(-n // 32) if n else 0
-    n_pad = B * 32
-    if n_pad != n:
-        vals = np.concatenate([vals, np.zeros(n_pad - n, np.uint32)])
-    parts = []
-    bit_ofs = 0
-    for s in _planes(bits):
-        k = 32 // s
-        f = ((vals >> np.uint32(bit_ofs))
-             & np.uint32((1 << s) - 1)).reshape(-1, k)
-        w = np.zeros(f.shape[0], np.uint32)
-        for pos in range(k):
-            w |= f[:, pos] << np.uint32(pos * s)
-        parts.append(w)
-        bit_ofs += s
-    if not parts:
-        return np.zeros((0,), np.uint32)
-    return np.concatenate(parts)
-
-
-def flat_words(n: int, bits: int) -> int:
-    """u32 words ``pack_flat_np`` emits for ``n`` values at ``bits`` bits
-    (the plane decomposition may round the stored width up slightly)."""
-    return -(-n // 32) * sum(_planes(bits))
-
-
-def unpack_flat(packed, bits: int, n: int):
-    """In-jit inverse of ``pack_flat_np``: ``[flat_words(n, bits)]`` u32
-    -> ``[n]`` i32. One broadcast shift + mask + reshape per plane, OR-ed
-    into the accumulator — fully dense vectorized ops (see ``_planes``)."""
-    _check_bits(bits)
-    planes = _planes(bits)
-    n_pad = (packed.shape[0] // sum(planes)) * 32
-    acc = None
-    word_ofs = 0
-    bit_ofs = 0
-    for s in planes:
-        k = 32 // s
-        nw = n_pad // k
-        w = packed[word_ofs:word_ofs + nw]
-        shifts = (jnp.arange(k, dtype=jnp.uint32) * np.uint32(s))[None, :]
-        f = (w[:, None] >> shifts) & np.uint32((1 << s) - 1)
-        part = f.reshape(n_pad) << np.uint32(bit_ofs)
-        acc = part if acc is None else acc | part
-        word_ofs += nw
-        bit_ofs += s
-    return acc[:n].astype(jnp.int32)

@@ -884,10 +884,9 @@ class _DeviceCache:
 
     @staticmethod
     def _size(batch: tuple) -> int:
-        # tree-flatten, not a flat scan: hashed sparse-plan batches carry
-        # a DICT of plan arrays as their 5th element (and compressed
-        # chunks a dict of encoded blocks as their 1st), and skipping
-        # them would under-count the budget the replay-fusion gate reads
+        # tree-flatten, not a flat scan: compressed chunks carry a DICT
+        # of encoded blocks as their 1st element, and skipping it would
+        # under-count the budget the replay-fusion gate reads
         import jax
 
         return sum(b.nbytes for b in jax.tree.leaves(batch)

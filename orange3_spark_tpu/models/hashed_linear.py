@@ -68,11 +68,10 @@ from orange3_spark_tpu.ops.hashing import (
     column_salts, hash_columns, hash_columns_np,
 )
 from orange3_spark_tpu.optim.sparse import (
-    adopt_optim_state, build_plan_np, dense_update, finalize_lazy_decay,
+    adopt_optim_state, dense_update, finalize_lazy_decay,
     init_optim_state, is_sparse_update, note_slot_blocks, note_sorts,
-    optim_kind, pack_plan_np, plan_field_shapes, plan_packed_field_shapes,
-    resolve_optim_update, resolve_sparse_lowering, slot_blocks, sort_keys,
-    sort_keys_bytes, sort_slots, sparse_embedding_update, unpack_plan,
+    optim_kind, resolve_optim_update, resolve_sparse_lowering, slot_blocks,
+    sort_keys, sort_keys_bytes, sort_slots, sparse_embedding_update,
 )
 from orange3_spark_tpu.obs import prof
 from orange3_spark_tpu.obs.report import RunReport
@@ -105,27 +104,20 @@ class HashedLinearParams(Params):
     compute_dtype: str = "float32"
     label_in_chunk: bool = False  # chunks carry the label as column 0
     prefetch_depth: int = 2       # host->device pipeline depth (0 disables)
-    # 'auto' resolves at fit time via resolve_emb_update (currently
-    # 'fused' on every backend — the 2026-07-31 on-chip A/B winner).
-    # Explicit values force a specific scatter lowering.
-    emb_update: str = "auto"     # 'auto' | 'fused' | 'per_column' | 'sorted'
     # Optimizer rule + lowering (optim/ subsystem, docs/optim.md):
     # 'adam' is the legacy dense optax path (in-loss L2, full-table moment
     # sweeps every step). The sparse_* rules update ONLY the rows a step
     # touches — per-row f32 slots, lazy decoupled weight decay via
     # last-seen timestamps — and each has a dense_* twin (same math, full
-    # sweeps) for parity/A-B. OTPU_SPARSE_UPDATE=0 resolves sparse_* to
-    # dense_* at fit entry (the kill-switch, donation-sweep conventions).
+    # sweeps), chosen by name: the reference of the parity tests.
     # Note: the non-adam rules treat reg_param as DECOUPLED weight decay
     # (FTRL: its closed-form L2), not an in-loss term, and report the
     # pure data loss.
     optim_update: str = "adam"   # 'adam' | '{dense,sparse}_{sgd,adagrad,ftrl}'
-    # Dedup lowering for sparse_* rules: 'plan' pre-sorts each chunk's
-    # touched rows on the HOST at ingest (replayed every epoch, gather-
-    # based writeback — CPU default); 'sort' dedups in-step (argsort in
-    # the jit, no per-chunk aux memory — TPU default). 'auto' resolves
-    # per backend via optim.resolve_sparse_lowering.
-    sparse_lowering: str = "auto"   # 'auto' | 'plan' | 'sort'
+    # Dedup lowering for sparse_* rules. There is one, 'sort' (argsort in
+    # the jit, no per-chunk aux memory), on every backend; the field is
+    # kept for the callers that name it (ROADMAP D2a).
+    sparse_lowering: str = "auto"   # 'auto' | 'sort'
     l1_param: float = 0.0        # FTRL-proximal l1 (sparse/dense ftrl only)
     fused_replay: bool = True    # cache replay epochs as scan program(s)
     # Granularity of the fused replay dispatches: 'all' lowers epochs 2+
@@ -193,8 +185,7 @@ class HashedLinearParams(Params):
     #   'packed' bf16 PLUS lossless integer packing: categorical columns
     #            pre-hash on the prefetch thread (the host hash twin is
     #            pinned bit-identical to the device's) and store at
-    #            log2(n_dims) bits; the sparse 'plan' arrays bit-pack at
-    #            their static widths (optim/sparse.pack_plan_np). Decode
+    #            log2(n_dims) bits. Decode
     #            is static shifts/masks INSIDE the step — HBM, disk spill
     #            and h2d DMA all move ~2x fewer bytes, and the cache/
     #            fusion-gate capacity roughly doubles.
@@ -215,21 +206,6 @@ def _effective_k(p: HashedLinearParams) -> int:
     return 1 if p.n_classes == 2 else p.n_classes
 
 
-def resolve_emb_update(p: HashedLinearParams) -> str:
-    """The concrete scatter lowering for this fit — 'auto' picks the
-    measured-best per backend. THE one resolver: anything handing
-    ``emb_update`` to a jitted step must go through it.
-
-    Currently 'fused' everywhere: the 2026-07-31 on-chip A/B
-    (BENCH_HW_r4.jsonl, before PR 1: fused 0.27 ms/step < sorted 0.41 <
-    per_column 0.75 at 2^18 rows x 2^22 dims), and XLA:CPU always sorted
-    slowly. 'sorted' (conflict-free custom-vjp
-    scatter) remains available by explicit request."""
-    if p.emb_update == "auto":
-        return "fused"
-    return p.emb_update
-
-
 def _impute_flag(p: HashedLinearParams) -> bool:
     """Static impute flag for the jitted functions; value-weighted rows
     carry explicit (index, value) pairs with their own -1/0 padding
@@ -246,100 +222,17 @@ def _row_loss_kind(p: HashedLinearParams) -> str:
     return p.loss
 
 
-@jax.custom_vjp
-def _emb_sum_sorted_grad(emb, idx):
-    """Same forward as take+sum; the BACKWARD sorts the flattened
-    (index, grad) pairs and scatter-adds with indices_are_sorted=True — the
-    classic TPU trade of one O(M log M) sort for a conflict-free scatter.
-    An A/B lever against the plain scatter (emb_update='sorted')."""
-    return jnp.sum(jnp.take(emb, idx, axis=0), axis=1, dtype=jnp.float32)
-
-
-def _emb_sum_sorted_fwd(emb, idx):
-    # dtype travels as a zero-size array (a bare dtype is not a JAX type)
-    proto = jnp.zeros((0,), emb.dtype)
-    return _emb_sum_sorted_grad(emb, idx), (idx, emb.shape, proto)
-
-
-def _sorted_scatter(flat_idx, flat_g, D: int, k: int, dtype):
-    """Sort (index, grad) pairs, then a conflict-free ordered scatter-add —
-    the shared backward of both sorted lowerings."""
-    order = jnp.argsort(flat_idx)
-    return jnp.zeros((D, k), dtype).at[flat_idx[order]].add(
-        flat_g[order].astype(dtype),
-        indices_are_sorted=True, unique_indices=False,
-    )
-
-
-def _emb_sum_sorted_bwd(res, g):
-    idx, (D, k), proto = res
-    N, C = idx.shape
-    flat_g = jnp.broadcast_to(g[:, None, :], (N, C, k)).reshape(N * C, k)
-    return _sorted_scatter(idx.reshape(-1), flat_g, D, k, proto.dtype), None
-
-
-_emb_sum_sorted_grad.defvjp(_emb_sum_sorted_fwd, _emb_sum_sorted_bwd)
-
-
-@jax.custom_vjp
-def _emb_wsum_sorted_grad(emb, idx, vals):
-    """Value-weighted twin of ``_emb_sum_sorted_grad``: forward
-    sum(emb[idx] * vals), backward sorts (index, g*val) pairs into a
-    conflict-free scatter. vals gets no gradient (data, not parameters)."""
-    return jnp.sum(
-        jnp.take(emb, idx, axis=0) * vals[:, :, None], axis=1,
-        dtype=jnp.float32,
-    )
-
-
-def _emb_wsum_sorted_fwd(emb, idx, vals):
-    proto = jnp.zeros((0,), emb.dtype)
-    return _emb_wsum_sorted_grad(emb, idx, vals), (idx, vals, emb.shape, proto)
-
-
-def _emb_wsum_sorted_bwd(res, g):
-    idx, vals, (D, k), proto = res
-    N, C = idx.shape
-    flat_g = (g[:, None, :] * vals[:, :, None]).reshape(N * C, k)
-    return (_sorted_scatter(idx.reshape(-1), flat_g, D, k, proto.dtype),
-            None, None)
-
-
-_emb_wsum_sorted_grad.defvjp(_emb_wsum_sorted_fwd, _emb_wsum_sorted_bwd)
-
-
-def _hashed_logits(theta, dense, idx, compute_dtype, emb_update: str = "fused",
-                   vals=None):
-    """emb_update selects the gather/scatter formulation — all numerically
-    identical, different XLA lowerings (the step is scatter-bound; see
-    tools/step_ab.py for the on-hardware A/B):
-      'fused'      one [N, C] gather; autodiff emits one fused scatter
-      'per_column' C independent [N] gathers/scatters
-      'sorted'     custom-vjp backward: sort pairs, conflict-free scatter
-    ``vals`` (value-weighted sparse mode): per-pair multipliers — the
-    forward becomes sum(emb[idx] * val), MLlib SparseVector semantics.
-    """
+def _hashed_logits(theta, dense, idx, compute_dtype, vals=None):
+    """One [N, C] gather of the table, summed over the columns, plus the
+    dense block's matmul; autodiff (the adam and ``dense_*`` paths) emits
+    one fused scatter. ``vals`` (value-weighted sparse mode): per-pair
+    multipliers — the forward becomes sum(emb[idx] * val), MLlib
+    SparseVector semantics."""
     emb = theta["emb"].astype(compute_dtype)
-    if emb_update == "per_column":
-        logits = jnp.zeros((idx.shape[0], emb.shape[1]), jnp.float32)
-        for c in range(idx.shape[1]):
-            col = jnp.take(emb, idx[:, c], axis=0)
-            if vals is not None:
-                col = col * vals[:, c, None]
-            logits = logits + col
-    elif emb_update == "sorted":
-        logits = (_emb_sum_sorted_grad(emb, idx) if vals is None
-                  else _emb_wsum_sorted_grad(emb, idx, vals))
-    elif emb_update != "fused":
-        raise ValueError(
-            f"emb_update must be 'fused' | 'per_column' | 'sorted', "
-            f"got {emb_update!r}"
-        )
-    else:
-        emb_rows = jnp.take(emb, idx, axis=0)
-        if vals is not None:
-            emb_rows = emb_rows * vals[:, :, None]
-        logits = jnp.sum(emb_rows, axis=1, dtype=jnp.float32)    # [N, k]
+    emb_rows = jnp.take(emb, idx, axis=0)
+    if vals is not None:
+        emb_rows = emb_rows * vals[:, :, None]
+    logits = jnp.sum(emb_rows, axis=1, dtype=jnp.float32)        # [N, k]
     if theta["coef"].shape[0]:
         logits = logits + jnp.dot(
             dense.astype(compute_dtype),
@@ -397,13 +290,11 @@ def _chunk_fields(Xall, n_valid, y, w, salts, *, n_dims: int, n_dense: int,
 
 
 def _step_core(
-    theta, opt_state, Xall, n_valid, y, w, salts, reg, lr, plan=None, l1=0.0,
-    keys=None,
+    theta, opt_state, Xall, n_valid, y, w, salts, reg, lr, l1=0.0, keys=None,
     *, loss_kind: str, n_dims: int, n_dense: int, compute_dtype=jnp.float32,
-    label_in_chunk: bool = False, emb_update: str = "fused",
-    value_weighted: bool = False, impute_missing: bool = False,
-    optim_update: str = "adam", sparse_lowering: str = "none",
-    use_decay: bool = False, codec=None,
+    label_in_chunk: bool = False, value_weighted: bool = False,
+    impute_missing: bool = False, optim_update: str = "adam",
+    sparse_lowering: str = "none", use_decay: bool = False, codec=None,
 ):
     """One optimizer step on one chunk — traced by both the per-chunk jit
     (`_hashed_step`) and the fused replay scan (`_hashed_replay_epochs`).
@@ -411,17 +302,16 @@ def _step_core(
     optim_update == 'adam' is the legacy path: in-loss L2 + a dense optax
     adam sweep over the whole table. Every other rule (optim/ subsystem)
     reports the pure data loss, treats reg as decoupled weight decay, and
-    — for the sparse_* rules — updates only the touched rows, with ``plan``
-    carrying the host-presorted dedup under the 'plan' lowering and
+    — for the sparse_* rules — updates only the touched rows, with
     ``keys`` this chunk's ``optim.sparse.sort_keys`` where the fused
-    replay has built them ahead of its scan ('sort' lowering; None: the
-    step sorts for itself).
+    replay has built them ahead of its scan (None: the step sorts for
+    itself). ``sparse_lowering`` ('sort' | 'none') names the dedup in the
+    jit's static key and chooses nothing.
 
     codec (io/codec.py, resolved once at fit entry): None is the legacy
     f32 chunk; otherwise ``Xall`` is the compressed block dict and the
     decode (bf16 widen / static bit-unpack, fused by XLA) happens HERE, so
-    the replay scan reads compressed HBM bytes. A packed plan unpacks here
-    too — bit-exact, so the plan-lowering update is unchanged math.
+    the replay scan reads compressed HBM bytes.
 
     The phases carry ``jax.named_scope``s (``step/decode``,
     ``step/forward``, ``step/loss_grad``, ``step/dense_leaf`` here;
@@ -433,14 +323,10 @@ def _step_core(
             Xall, n_valid, y, w, salts, n_dims=n_dims, n_dense=n_dense,
             label_in_chunk=label_in_chunk, value_weighted=value_weighted,
             impute_missing=impute_missing, codec=codec)
-        if plan is not None and codec is not None and codec.mode == "packed":
-            plan = unpack_plan(plan, Xall["cats"].shape[0], codec.n_cat,
-                               n_dims)
 
-    def forward(theta, lowering):
+    def forward(theta):
         with jax.named_scope("step/forward"):
-            return _hashed_logits(theta, dense, idx, compute_dtype,
-                                  lowering, vals)
+            return _hashed_logits(theta, dense, idx, compute_dtype, vals)
 
     def data_loss(logits):
         with jax.named_scope("step/loss_grad"):
@@ -450,7 +336,7 @@ def _step_core(
 
     if optim_update == "adam":
         def loss_fn(theta):
-            data = data_loss(forward(theta, emb_update))
+            data = data_loss(forward(theta))
             return data + 0.5 * reg * (
                 jnp.sum(theta["emb"] ** 2) + jnp.sum(theta["coef"] ** 2)
             )
@@ -466,15 +352,12 @@ def _step_core(
     slots = opt_state["slots"]
     if is_sparse_update(optim_update):
         # forward only — no autodiff through the table: the [N, k] logits
-        # gradient is all the touched-row engine needs (the plain 'fused'
-        # gather forward; emb_update scatter lowerings are a BACKWARD
-        # concern and only apply to the dense paths)
-        logits = forward(theta, "fused")
+        # gradient is all the touched-row engine needs
+        logits = forward(theta)
         loss, dl = jax.value_and_grad(data_loss)(logits)
         emb, t, eslots, n_blocks = sparse_embedding_update(
             kind, theta["emb"], opt_state["t"], slots["emb"], dl, idx,
-            lr, decay, reg, l1, step, lowering=sparse_lowering,
-            use_decay=use_decay, plan=plan, n_valid=n_valid,
+            lr, decay, reg, l1, step, use_decay=use_decay, n_valid=n_valid,
             raw_cats=(cats if value_weighted else None), vals=vals,
             keys=keys,
         )
@@ -487,11 +370,10 @@ def _step_core(
                 g_coef = jnp.zeros_like(theta["coef"])
             g_int = jnp.sum(dl, axis=0)
     else:
-        # dense twin: autodiff through the table (the emb_update scatter
-        # lowering applies), then a full-array rule sweep — the parity
-        # baseline the sparse path is measured against
+        # dense twin: autodiff through the table, then a full-array rule
+        # sweep — the parity baseline the sparse path is measured against
         loss, g = jax.value_and_grad(
-            lambda theta: data_loss(forward(theta, emb_update)))(theta)
+            lambda theta: data_loss(forward(theta)))(theta)
         t = opt_state["t"]
         n_blocks = 0
         emb, eslots = dense_update(
@@ -515,28 +397,26 @@ def _step_core(
 
 _STEP_STATICS = (
     "loss_kind", "n_dims", "n_dense", "compute_dtype", "label_in_chunk",
-    "emb_update", "value_weighted", "impute_missing", "optim_update",
-    "sparse_lowering", "use_decay", "codec",
+    "value_weighted", "impute_missing", "optim_update", "sparse_lowering",
+    "use_decay", "codec",
 )
 
 
 @donating_jit(static_argnames=_STEP_STATICS, donate_argnums=(0, 1))
 def _hashed_step(
-    theta, opt_state, Xall, n_valid, y, w, salts, reg, lr, plan=None,
-    l1=0.0,
+    theta, opt_state, Xall, n_valid, y, w, salts, reg, lr, l1=0.0,
     *, loss_kind: str, n_dims: int, n_dense: int, compute_dtype=jnp.float32,
-    label_in_chunk: bool = False, emb_update: str = "fused",
-    value_weighted: bool = False, impute_missing: bool = False,
-    optim_update: str = "adam", sparse_lowering: str = "none",
-    use_decay: bool = False, codec=None,
+    label_in_chunk: bool = False, value_weighted: bool = False,
+    impute_missing: bool = False, optim_update: str = "adam",
+    sparse_lowering: str = "none", use_decay: bool = False, codec=None,
 ):
     return _step_core(
-        theta, opt_state, Xall, n_valid, y, w, salts, reg, lr, plan, l1,
+        theta, opt_state, Xall, n_valid, y, w, salts, reg, lr, l1,
         loss_kind=loss_kind, n_dims=n_dims, n_dense=n_dense,
         compute_dtype=compute_dtype, label_in_chunk=label_in_chunk,
-        emb_update=emb_update, value_weighted=value_weighted,
-        impute_missing=impute_missing, optim_update=optim_update,
-        sparse_lowering=sparse_lowering, use_decay=use_decay, codec=codec,
+        value_weighted=value_weighted, impute_missing=impute_missing,
+        optim_update=optim_update, sparse_lowering=sparse_lowering,
+        use_decay=use_decay, codec=codec,
     )
 
 
@@ -557,21 +437,19 @@ def _from_lanes(v, like):
 def _hashed_replay_epochs(
     theta, opt_state, stacks, salts, reg, lr, l1=0.0,
     *, loss_kind: str, n_dims: int, n_dense: int, compute_dtype=jnp.float32,
-    label_in_chunk: bool = False, emb_update: str = "fused",
-    value_weighted: bool = False, impute_missing: bool = False,
-    optim_update: str = "adam", sparse_lowering: str = "none",
-    use_decay: bool = False, codec=None,
+    label_in_chunk: bool = False, value_weighted: bool = False,
+    impute_missing: bool = False, optim_update: str = "adam",
+    sparse_lowering: str = "none", use_decay: bool = False, codec=None,
     n_epochs: int, hoist_keys: bool = False,
 ):
     """Epochs 2+ of a cached fit as ONE XLA program: an epoch-level scan
     around a chunk-level scan over the HBM-resident chunk stack.
 
     ``stacks`` is the chunk stack as one pytree — ``(Xstack, n_valid_vec,
-    ystack, wstack)`` plus, when the sparse 'plan' lowering is active, a
-    fifth element holding the stacked per-chunk touched-row plans (each
-    leaf [n_chunks, ...]); the scan slices all of them in lockstep.
+    ystack, wstack)``, each leaf [n_chunks, ...]; the scan slices all of
+    them in lockstep.
 
-    ``hoist_keys`` ('sort' lowering only; resolved by ``_hoist_sort_keys``
+    ``hoist_keys`` (sparse_* rules only; resolved by ``_hoist_sort_keys``
     from the caller's cache budget): a cached chunk's keys do not change
     between epochs, so its sort, segment ids and ``uniq``
     (``optim.sparse.sort_keys``) are built ONCE here, ahead of the epoch
@@ -588,10 +466,9 @@ def _hashed_replay_epochs(
     """
     kw = dict(loss_kind=loss_kind, n_dims=n_dims, n_dense=n_dense,
               compute_dtype=compute_dtype, label_in_chunk=label_in_chunk,
-              emb_update=emb_update, value_weighted=value_weighted,
-              impute_missing=impute_missing, optim_update=optim_update,
-              sparse_lowering=sparse_lowering, use_decay=use_decay,
-              codec=codec)
+              value_weighted=value_weighted, impute_missing=impute_missing,
+              optim_update=optim_update, sparse_lowering=sparse_lowering,
+              use_decay=use_decay, codec=codec)
 
     stacks = tuple(stacks)
     if hoist_keys:
@@ -617,15 +494,13 @@ def _hashed_replay_epochs(
     def chunk_body(carry, xs):
         theta, opt = carry
         Xall, n_valid, y, w = xs[:4]
-        # the fifth element: a 'plan' chunk's plan, or the hoisted keys
-        aux = xs[4] if len(xs) > 4 else None
-        plan, keys = aux, None
-        if hoist_keys:
-            plan, keys = None, jax.tree.map(_from_lanes, aux, key_shapes)
+        # the fifth element, where there is one: the hoisted keys
+        keys = (jax.tree.map(_from_lanes, xs[4], key_shapes)
+                if hoist_keys else None)
         with jax.named_scope("replay/chunk"):
             theta, opt, loss = _step_core(
-                theta, opt, Xall, n_valid, y, w, salts, reg, lr, plan, l1,
-                keys, **kw
+                theta, opt, Xall, n_valid, y, w, salts, reg, lr, l1, keys,
+                **kw
             )
         return (theta, opt), loss
 
@@ -843,8 +718,6 @@ class HashedLinearModel(Model):
         tot = None
         with span("evaluate"):
             for i, chunk in enumerate(device_chunks):
-                # sparse-plan fits cache 5-tuples (the touched-row plan
-                # rides along for replay); eval only needs the quadruple
                 Xd, n_valid, yd, wd = chunk[:4]
                 count_dispatch()
                 with span("eval_chunk", i):
@@ -873,13 +746,6 @@ class HashedLinearModel(Model):
             if auc is not None:
                 out["auc"] = auc
         return out
-
-
-#: spill serialization order of the touched-row plan's arrays ('val' only
-#: in value-weighted mode) — shared with the DiskChunkCache record layout
-_PLAN_ORDER = ("row", "seg", "uniq", "inv", "val")
-#: spill order of the PACKED plan's u32 carriers (cache_dtype='packed')
-_PLAN_PACKED_ORDER = ("rowp", "segb", "uniqp", "invp")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -936,13 +802,10 @@ def resolve_chunk_codec(p: HashedLinearParams,
 
 
 def _encode_chunk_np(codec: _ChunkCodec, Xp: np.ndarray,
-                     salts_np: np.ndarray,
-                     idx: np.ndarray | None = None) -> dict:
+                     salts_np: np.ndarray) -> dict:
     """Host-side encode of one PADDED chunk on the prefetch thread: the
     dict this returns is what the HBM cache, the disk spill and the h2d
-    DMA all carry — compressed bytes, decoded only inside the step.
-    ``idx``: the pre-hashed [N, C] indices when the caller already built
-    them (the sparse-plan path shares ONE host hash per chunk)."""
+    DMA all carry — compressed bytes, decoded only inside the step."""
     off = 1 if codec.label_in_chunk else 0
     enc = {}
     if codec.label_in_chunk:
@@ -964,11 +827,10 @@ def _encode_chunk_np(codec: _ChunkCodec, Xp: np.ndarray,
             Xp[:, off:off + codec.n_dense]).astype(BF16)
     cats = Xp[:, off + codec.n_dense:]
     if codec.mode == "packed":
-        if idx is None:
-            if codec.impute:
-                cats = np.where(np.isnan(cats), np.float32(0.0), cats)
-            idx = hash_columns_np(cats, salts_np, codec.n_dims)
-        enc["cats"] = pack_rows_np(idx, codec.idx_bits)
+        if codec.impute:
+            cats = np.where(np.isnan(cats), np.float32(0.0), cats)
+        enc["cats"] = pack_rows_np(
+            hash_columns_np(cats, salts_np, codec.n_dims), codec.idx_bits)
     else:
         enc["cats"] = np.ascontiguousarray(cats, np.float32)
     return enc
@@ -1014,10 +876,8 @@ def _put_encoded(enc: dict, session: TpuSession) -> dict:
 
 
 def _chunk_field_specs(p: HashedLinearParams, codec, pad_rows: int) -> tuple:
-    """Ordered (name, shape, dtype) of one spill record's CHUNK payload —
-    the one authority the spill writer/reader and the warm-path builders
-    share (plan fields, when the sparse 'plan' lowering is active, append
-    after these via ``_plan_store_specs``)."""
+    """Ordered (name, shape, dtype) of one spill record — the one
+    authority the spill writer/reader and the warm-path builders share."""
     if codec is None:
         n_cols = _chunk_cols(p)
         fields = [("x", (pad_rows, n_cols), np.dtype(np.float32))]
@@ -1043,59 +903,24 @@ def _chunk_field_specs(p: HashedLinearParams, codec, pad_rows: int) -> tuple:
     return tuple(fields)
 
 
-def _plan_store_specs(p: HashedLinearParams, codec, pad_rows: int) -> tuple:
-    """Ordered (name, shape, dtype) of the plan's spill fields — packed
-    u32 carriers under the 'packed' codec, raw i32 (+ f32 'val') else."""
-    if codec is not None and codec.mode == "packed":
-        d = plan_packed_field_shapes(pad_rows, p.n_cat, p.n_dims)
-        return tuple((k, d[k][0], np.dtype(d[k][1]))
-                     for k in _PLAN_PACKED_ORDER)
-    shapes = plan_field_shapes(pad_rows, p.n_cat, p.n_dims, p.value_weighted)
-    return tuple(
-        (k, shapes[k],
-         np.dtype(np.float32 if k == "val" else np.int32))
-        for k in _PLAN_ORDER if k in shapes
-    )
-
-
-def _plan_device_form(codec, plan_np: dict, pad_rows: int,
-                      p: HashedLinearParams) -> dict:
-    """The plan dict as it travels with the chunk (cache/spill/device):
-    bit-packed under the 'packed' codec, raw otherwise."""
-    if codec is not None and codec.mode == "packed":
-        return pack_plan_np(plan_np, pad_rows, p.n_cat, p.n_dims)
-    return plan_np
-
-
-def _raw_chunk_bytes(p: HashedLinearParams, pad_rows: int,
-                     sparse_plan: bool) -> int:
-    """f32-layout bytes of one cached chunk (+ its raw plan) — the
-    denominator of the bench's ``compression_ratio`` and the legacy term
-    in capacity estimates."""
+def _raw_chunk_bytes(p: HashedLinearParams, pad_rows: int) -> int:
+    """f32-layout bytes of one cached chunk — the denominator of the
+    bench's ``compression_ratio`` and the legacy term in capacity
+    estimates."""
     n = pad_rows * _chunk_cols(p) * 4
     if not p.label_in_chunk:
         n += 2 * pad_rows * 4
-    if sparse_plan:
-        shapes = plan_field_shapes(pad_rows, p.n_cat, p.n_dims,
-                                   p.value_weighted)
-        n += 4 * sum(int(np.prod(s)) for s in shapes.values())
     return n
 
 
 def estimate_cached_chunk_bytes(p: HashedLinearParams,
                                 session: TpuSession) -> int:
-    """Per-chunk HBM cache bytes under the RESOLVED codec/lowering — the
+    """Per-chunk HBM cache bytes under the RESOLVED codec — the
     estimate bench.py's overflow/fusion pre-gates use; it must agree with
     what ``fit_stream``'s cache accounting will actually see or the two
     gates disagree in a boundary window."""
     pad_rows = session.pad_rows(p.chunk_rows)
-    codec = resolve_chunk_codec(p, session)
-    optim = resolve_optim_update(p.optim_update)
-    sparse_plan = (is_sparse_update(optim)
-                   and resolve_sparse_lowering(p.sparse_lowering) == "plan")
-    specs = _chunk_field_specs(p, codec, pad_rows)
-    if sparse_plan:
-        specs = specs + _plan_store_specs(p, codec, pad_rows)
+    specs = _chunk_field_specs(p, resolve_chunk_codec(p, session), pad_rows)
     return sum(int(np.prod(s)) * dt.itemsize for _, s, dt in specs)
 
 
@@ -1225,7 +1050,7 @@ def _init_fit_state(p: HashedLinearParams, session: TpuSession):
     static_kw = dict(
         loss_kind=_row_loss_kind(p), n_dims=p.n_dims, n_dense=p.n_dense,
         compute_dtype=jnp.dtype(p.compute_dtype),
-        label_in_chunk=p.label_in_chunk, emb_update=resolve_emb_update(p),
+        label_in_chunk=p.label_in_chunk,
         value_weighted=p.value_weighted, impute_missing=_impute_flag(p),
         optim_update=optim, sparse_lowering=lowering,
         # static decay gate: reg == 0 compiles the sparse step without the
@@ -1332,20 +1157,6 @@ class StreamingHashedLinearEstimator(Estimator):
             zy = put_sharded(np.zeros((pad_rows,), np.float32),
                              session.vector_sharding)
             zw = zy
-        plan = None
-        if kw["sparse_lowering"] == "plan":
-            # the zero chunk's touched-row plan, through the same builder
-            # as the real fit (zero codes hash to one bucket per column —
-            # the skew is irrelevant to the compiled shapes)
-            zc = np.zeros((pad_rows, p.n_cat), np.float32)
-            plan_np0 = build_plan_np(
-                zc, salts_np, p.n_dims, pad_rows,
-                vals=(np.zeros((pad_rows, p.n_cat), np.float32)
-                      if p.value_weighted else None),
-                impute_missing=kw["impute_missing"])
-            plan = jax.device_put(
-                _plan_device_form(codec, plan_np0, pad_rows, p),
-                session.replicated)
         l1 = jnp.float32(p.l1_param)
         if not p.defer_epoch1:
             # theta/opt must have step-OUTPUT provenance (GSPMD-placed),
@@ -1355,16 +1166,13 @@ class StreamingHashedLinearEstimator(Estimator):
             theta, opt, _ = _hashed_step(
                 theta, opt, z, nv, zy, zw, salts,
                 jnp.float32(p.reg_param), jnp.float32(p.step_size),
-                plan, l1, **kw)
+                l1, **kw)
         n_rep = p.epochs - 1 + (1 if p.defer_epoch1 else 0)
         stacks = (
             jax.tree.map(lambda a: jnp.stack([a] * n_chunks), z),
             jnp.stack([nv] * n_chunks),
             jnp.stack([zy] * n_chunks), jnp.stack([zw] * n_chunks),
         )
-        if plan is not None:
-            stacks = stacks + (jax.tree.map(
-                lambda a: jnp.stack([a] * n_chunks), plan),)
         theta, opt, losses = _hashed_replay_epochs(
             theta, opt, stacks, salts,
             jnp.float32(p.reg_param), jnp.float32(p.step_size), l1,
@@ -1496,22 +1304,12 @@ class StreamingHashedLinearEstimator(Estimator):
         reg = jnp.float32(p.reg_param)
         lr = jnp.float32(p.step_size)
         l1 = jnp.float32(p.l1_param)
-        # sparse-optimizer plumbing (optim/ subsystem): under the 'plan'
-        # lowering every device chunk carries its host-presorted
-        # touched-row plan as a 5th tuple element — built once on the
-        # prefetch thread, cached/spilled/stacked alongside the chunk
         optim_resolved = static_kw["optim_update"]
-        sparse_plan = static_kw["sparse_lowering"] == "plan"
         # cache codec (io/codec.py), resolved once in _init_fit_state: all
         # storage surfaces — HBM cache, disk spill, h2d DMA — carry the
         # encoded blocks; decode happens inside the jitted step
         codec = static_kw["codec"]
         chunk_specs = _chunk_field_specs(p, codec, pad_rows)
-        plan_specs = (_plan_store_specs(p, codec, pad_rows)
-                      if sparse_plan else ())
-        # categorical block offset in the padded chunk ([label?] + dense +
-        # cats, or [label?] + idx pairs; n_dense == 0 in vw mode)
-        cats_off = (1 if p.label_in_chunk else 0) + p.n_dense
         # stage seconds are the sums of span durations (obs.trace.stage):
         # they collect for the caller's stage_times= dict AND for the run
         # report (obs/report.py); under OTPU_OBS=0 with no caller dict
@@ -1548,51 +1346,40 @@ class StreamingHashedLinearEstimator(Estimator):
                 return put_sharded(payload, row_sh)
             return _put_encoded(payload, session)
 
-        def record_arrays(payload, yp, wp, plan_store):
-            """Spill-record field tuple in ``chunk_specs``(+``plan_specs``)
-            declaration order."""
+        def record_arrays(payload, yp, wp):
+            """Spill-record field tuple in ``chunk_specs`` declaration
+            order."""
             if codec is None:
-                rec = (payload,) if p.label_in_chunk else (payload, yp, wp)
-            else:
-                rec = tuple(
-                    yp if name == "yv" else wp if name == "wv"
-                    else payload[name]
-                    for name, _, _ in chunk_specs
-                )
-            if plan_store is not None:
-                rec = rec + tuple(plan_store[name]
-                                  for name, _, _ in plan_specs)
-            return rec
+                return (payload,) if p.label_in_chunk else (payload, yp, wp)
+            return tuple(
+                yp if name == "yv" else wp if name == "wv"
+                else payload[name]
+                for name, _, _ in chunk_specs
+            )
 
         def record_to_host(arrays):
-            """Typed spill-record views -> (payload, y, w, plan) host
-            arrays — the inverse of ``record_arrays``."""
-            chunk_arr = arrays[:len(chunk_specs)]
+            """Typed spill-record views -> (payload, y, w) host arrays —
+            the inverse of ``record_arrays``."""
             y_np = w_np = None
             if codec is None:
-                payload = np.asarray(chunk_arr[0])
+                payload = np.asarray(arrays[0])
                 if not p.label_in_chunk:
-                    y_np = np.asarray(chunk_arr[1])
-                    w_np = np.asarray(chunk_arr[2])
+                    y_np = np.asarray(arrays[1])
+                    w_np = np.asarray(arrays[2])
             else:
                 payload = {}
-                for (name, _, _), a in zip(chunk_specs, chunk_arr):
+                for (name, _, _), a in zip(chunk_specs, arrays):
                     if name == "yv":
                         y_np = np.asarray(a)
                     elif name == "wv":
                         w_np = np.asarray(a)
                     else:
                         payload[name] = np.asarray(a)
-            plan_np = None
-            if plan_specs:
-                plan_np = {name: np.asarray(a) for (name, _, _), a
-                           in zip(plan_specs, arrays[len(chunk_specs):])}
-            return payload, y_np, w_np, plan_np
+            return payload, y_np, w_np
 
-        def encode_chunk(host_chunk, enc):
-            """One host chunk -> (payload, y, w, plan, n_valid) as the
-            cache, the spill and the DMA carry it; ``enc`` is the
-            surrounding "encode" span."""
+        def encode_chunk(host_chunk):
+            """One host chunk -> (payload, y, w, n_valid) as the cache,
+            the spill and the DMA carry it."""
             if p.label_in_chunk:
                 X_np = host_chunk if isinstance(
                     host_chunk, np.ndarray) else host_chunk[0]
@@ -1614,54 +1401,25 @@ class StreamingHashedLinearEstimator(Estimator):
             else:
                 Xp, yp, wp = _pad_chunk(X_np, y_np, w_np, pad_rows,
                                         n_cols)
-            # under the packed codec the chunk's indices are hashed ONCE
-            # on this thread and shared by the plan builder and the encode
-            idx_np = None
-            if codec is not None and codec.mode == "packed":
-                c = Xp[:, cats_off:cats_off + p.n_cat]
-                if codec.impute:
-                    c = np.where(np.isnan(c), np.float32(0.0), c)
-                idx_np = hash_columns_np(c, salts_np, p.n_dims)
-            plan_np = None
-            if sparse_plan:
-                # host-presorted touched-row plan (optim/sparse.py) —
-                # the stable argsort runs here on the prefetch thread,
-                # overlapping device steps, and is replayed every epoch
-                with staged("plan", "plan_s") as planned:
-                    plan_np = build_plan_np(
-                        Xp[:, cats_off:cats_off + p.n_cat], salts_np,
-                        p.n_dims, n,
-                        vals=(Xp[:, cats_off + p.n_cat:]
-                              if p.value_weighted else None),
-                        impute_missing=static_kw["impute_missing"],
-                        idx=idx_np)
-                enc.note(plan_s=round(planned.seconds, 6))
             # encode on the prefetch thread (io/codec.py): bf16 / u8 /
-            # bit-packed blocks — the cache, the spill AND the DMA all
+            # bit-packed blocks (the categorical columns hashed here under
+            # the packed codec) — the cache, the spill AND the DMA all
             # carry the compressed bytes from here on
             payload = Xp
-            plan_store = plan_np
             if codec is not None:
-                payload = _encode_chunk_np(codec, Xp, salts_np, idx=idx_np)
-                if plan_np is not None:
-                    plan_store = _plan_device_form(codec, plan_np,
-                                                   pad_rows, p)
-            return payload, yp, wp, plan_store, n
+                payload = _encode_chunk_np(codec, Xp, salts_np)
+            return payload, yp, wp, n
 
         def to_device(host_chunk):
-            """parse-thread side: encode (pad, hash, plan, codec) and
-            device_put one chunk."""
-            with stage("encode", pipe_stats, "encode_s") as enc:
-                payload, yp, wp, plan_store, n = encode_chunk(host_chunk,
-                                                              enc)
+            """parse-thread side: encode (pad, hash, codec) and device_put
+            one chunk."""
+            with stage("encode", pipe_stats, "encode_s"):
+                payload, yp, wp, n = encode_chunk(host_chunk)
             if spill_active[0]:
                 # sequential write of the already-encoded chunk — still
-                # on the prefetch thread, overlapping device steps. Plan
-                # arrays ride the same record, typed (packed u32 under
-                # the 'packed' codec).
+                # on the prefetch thread, overlapping device steps
                 with staged("spill", "spill_s"):
-                    spill.append(
-                        record_arrays(payload, yp, wp, plan_store), n)
+                    spill.append(record_arrays(payload, yp, wp), n)
             with staged("h2d", "h2d_s"):
                 Xd = put_payload(payload)
                 if p.label_in_chunk:
@@ -1669,11 +1427,7 @@ class StreamingHashedLinearEstimator(Estimator):
                 else:
                     yd = put_sharded(yp, vec_sh)
                     wd = put_sharded(wp, vec_sh)
-                out = (Xd, jnp.int32(n), yd, wd)
-                if plan_store is not None:
-                    out = out + (jax.device_put(plan_store,
-                                                session.replicated),)
-            return out
+            return (Xd, jnp.int32(n), yd, wd)
 
         _ZERO = jnp.zeros((1,), jnp.float32)
 
@@ -1744,10 +1498,9 @@ class StreamingHashedLinearEstimator(Estimator):
             # the spill records carry the SAME encoded fields as the HBM
             # cache (typed, versioned header — io/streaming.DiskChunkCache)
             # so spill I/O shrinks with the cache under a compressed codec
-            specs = chunk_specs + plan_specs
             spill = DiskChunkCache(cache_spill_dir,
-                                   tuple(s for _, s, _ in specs),
-                                   tuple(dt for _, _, dt in specs))
+                                   tuple(s for _, s, _ in chunk_specs),
+                                   tuple(dt for _, _, dt in chunk_specs))
             spill_active[0] = True
         use_disk = False
         holdout: list = []         # device-resident holdout chunks
@@ -1764,12 +1517,11 @@ class StreamingHashedLinearEstimator(Estimator):
 
         def run_step(dev_chunk):
             nonlocal theta, opt_state, n_steps, last_loss
-            Xd, n_valid, yd, wd = dev_chunk[:4]
-            plan = dev_chunk[4] if len(dev_chunk) > 4 else None
+            Xd, n_valid, yd, wd = dev_chunk
             with span("chunk", n_steps):
                 theta, opt_state, loss = _hashed_step(
                     theta, opt_state, Xd, n_valid, yd, wd, salts, reg, lr,
-                    plan, l1, **static_kw,
+                    l1, **static_kw,
                 )
                 n_steps += 1
                 last_loss = loss
@@ -1783,7 +1535,7 @@ class StreamingHashedLinearEstimator(Estimator):
         epoch_walls: list = []
         replay_fused_s = None
         # steps whose sort keys a hoisting replay dispatch had built ahead
-        # of its scan ('sort' lowering; feeds otpu_sparse_sorts_total)
+        # of its scan (feeds otpu_sparse_sorts_total)
         sorts_saved = 0
         # fused replay: epochs 2+ lower to ONE dispatch (see
         # _hashed_replay_epochs). Requires the full cache (same chunk set
@@ -1812,7 +1564,7 @@ class StreamingHashedLinearEstimator(Estimator):
 
             def rec_to_device(i):
                 arrays, n = spill.read(i)
-                payload, y_np, w_np, plan_np = record_to_host(arrays)
+                payload, y_np, w_np = record_to_host(arrays)
                 with staged("h2d", "h2d_s"):
                     Xd = put_payload(payload)
                     if p.label_in_chunk:
@@ -1820,11 +1572,7 @@ class StreamingHashedLinearEstimator(Estimator):
                     else:
                         yd = put_sharded(y_np, vec_sh)
                         wd = put_sharded(w_np, vec_sh)
-                    out = (Xd, jnp.int32(n), yd, wd)
-                    if plan_np is not None:
-                        out = out + (jax.device_put(plan_np,
-                                                    session.replicated),)
-                return out
+                return (Xd, jnp.int32(n), yd, wd)
 
             idxs = iter(range(start, spill.n_records - holdout_chunks))
             if p.prefetch_depth > 0:
@@ -1870,13 +1618,7 @@ class StreamingHashedLinearEstimator(Estimator):
                     else:
                         ys = stack_put([h[1] for h in hosts])
                         ws = stack_put([h[2] for h in hosts])
-                    stacks = (Xs, nv, ys, ws)
-                    if sparse_plan:
-                        plans = [h[3] for h in hosts]
-                        stacks = stacks + (jax.device_put(
-                            jax.tree.map(lambda *a: np.stack(a), *plans),
-                            session.replicated),)
-                return g, stacks
+                return g, (Xs, nv, ys, ws)
 
             starts = iter(range(0, n_full, group))
             if p.prefetch_depth > 0:
@@ -2041,14 +1783,13 @@ class StreamingHashedLinearEstimator(Estimator):
                     n_steps += n_rep * spe
                     break
                 with stage("replay_stack") as stacked:
-                    # stack the WHOLE chunk tuple as one pytree — the 5th
-                    # (plan) element's dict leaves stack right along under
-                    # the sparse 'plan' lowering
+                    # stack the WHOLE chunk tuple as one pytree
                     stacks = jax.tree.map(
                         lambda *xs: jnp.stack(xs), *cache.batches)
-                    # the stack is a SECOND device copy of the cache (chunk
-                    # arrays + sparse plans) — a distinct ledger tenant for
-                    # exactly as long as it lives. Name keyed per FIT (two
+                    # the stack is a SECOND device copy of the cache — a
+                    # distinct ledger tenant (owner "replay_plans": the
+                    # name its readers know it by) for exactly as long as
+                    # it lives. Name keyed per FIT (two
                     # concurrent replays must not share one entry); the
                     # guard releases on an aborted replay (device OOM while
                     # holding the copy is THE likely failure here), the
@@ -2139,9 +1880,7 @@ class StreamingHashedLinearEstimator(Estimator):
             # the "encode" spans feed the pipeline's counter (the goodput
             # accountant reads it with OTPU_OBS off too): one sum, two readers
             st["encode_s"] = pipe_stats.encode_s
-            # the resolved lowerings, so A/B records are self-describing
-            # (the 'auto' decisions are otherwise invisible post-hoc)
-            st["emb_update"] = static_kw["emb_update"]
+            # what 'auto' resolved to, so records are self-describing
             st["optim_update"] = optim_resolved
             st["sparse_lowering"] = static_kw["sparse_lowering"]
             # cache economics (io/codec.py): what the HBM cache actually
@@ -2153,7 +1892,7 @@ class StreamingHashedLinearEstimator(Estimator):
                 st["cache_chunks"] = len(cache.batches)
                 st["cache_raw_bytes"] = (
                     len(cache.batches)
-                    * _raw_chunk_bytes(p, pad_rows, sparse_plan))
+                    * _raw_chunk_bytes(p, pad_rows))
             st["epoch_s"] = [round(t, 3) for t in epoch_walls]
             if pipe_stats.items:
                 # measured prefetch overlap (exec/pipeline.py): 100% = all

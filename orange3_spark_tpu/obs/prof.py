@@ -36,7 +36,7 @@ behavior bitwise — no accounting, no ledger ticks, deep capture refused):
   (codec-aware bytes, the owner ``cache_chunks``), model/optimizer
   state (``model_state``), serving ``ExecutableCache`` entries
   (``serve_executables``, bytes best-effort via the executable's
-  ``memory_analysis``), and the fused-replay stacks incl. sparse plans
+  ``memory_analysis``), and the fused replay's stack of the cache
   (``replay_plans``). Live bytes per owner ride
   ``otpu_device_bytes{owner=}``; per-fit peak watermarks land in the
   report's ``device_memory`` section; :meth:`reconcile` compares the

@@ -126,7 +126,7 @@ def refresh() -> None:
 def refreshed_enabled() -> bool:
     """Re-resolve the knob, then report it — the fit-entry/activation
     chokepoints use this so a mid-process env flip takes effect at the
-    next run (the OTPU_DONATE/OTPU_SPARSE_UPDATE convention), while the
+    next run (the OTPU_DONATE convention), while the
     per-span hot path keeps reading the cached flag lock-free. A
     ``set_enabled``/``force_disabled`` override is env-backed too (the
     bench A/B uses force_disabled around whole probe arms), so the

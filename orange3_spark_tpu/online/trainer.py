@@ -122,11 +122,8 @@ class IncrementalTrainer:
         _theta0, _opt0, _salts_np, _salts, kw = _init_fit_state(
             p, self.session)
         # the trainer consumes raw f32 joined chunks, never cache-encoded
-        # ones, and the 'sort' lowering needs no host-side presort plan —
-        # the two statics that differ from the offline fit's program
+        # ones — the one static that differs from the offline fit's program
         kw["codec"] = None
-        if kw["sparse_lowering"] == "plan":
-            kw["sparse_lowering"] = "sort"
         self._kw = kw
         # warm-start the STANDBY from the serving model's state; the
         # serving object keeps its own arrays (never mutated under it)
@@ -204,11 +201,15 @@ class IncrementalTrainer:
         yd = jax.device_put(yp, self.session.vector_sharding)
         wd = jax.device_put(wp, self.session.vector_sharding)
         # theta/opt_state are DONATED (the offline fit's dispatch
-        # economics) — reassign or the next step reads freed buffers
-        self.theta, self.opt_state, loss = _hashed_step(
-            self.theta, self.opt_state, Xd, n_valid, yd, wd, self.salts,
-            jnp.float32(self._reg), jnp.float32(self._lr), None,
-            jnp.float32(0.0), **self._kw)
+        # economics) — reassign or the next step reads freed buffers, and
+        # do both under the lock: ``candidate_model`` reads theta on the
+        # publisher's thread, and a leaf donated between its two reads is
+        # a deleted array
+        with self._lock:
+            self.theta, self.opt_state, loss = _hashed_step(
+                self.theta, self.opt_state, Xd, n_valid, yd, wd, self.salts,
+                jnp.float32(self._reg), jnp.float32(self._lr),
+                jnp.float32(0.0), **self._kw)
         return float(loss)
 
     def _apply_label_skew(self, ordinal: int, y: np.ndarray) -> np.ndarray:

@@ -10,7 +10,6 @@ from orange3_spark_tpu.optim.sparse import (  # noqa: F401
     SPARSE_UPDATES,
     adopt_optim_state,
     apply_rule,
-    build_plan_np,
     dense_update,
     finalize_lazy_decay,
     init_optim_state,
@@ -19,7 +18,6 @@ from orange3_spark_tpu.optim.sparse import (  # noqa: F401
     note_sorts,
     occurrence_dead,
     optim_kind,
-    plan_field_shapes,
     plan_slots,
     slot_blocks,
     sort_keys,
@@ -28,5 +26,4 @@ from orange3_spark_tpu.optim.sparse import (  # noqa: F401
     resolve_optim_update,
     resolve_sparse_lowering,
     sparse_embedding_update,
-    sparse_updates_enabled,
 )

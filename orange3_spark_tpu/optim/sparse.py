@@ -34,52 +34,28 @@ This module is the one home of that machinery:
   tolerance only from pow-vs-repeated-multiply rounding). FTRL carries
   its own L2 inside the closed-form weight recovery and ignores the
   decay path entirely.
-* **two sparse lowerings** for the dedup/update, resolved per backend:
-
-  - ``'plan'`` — the sort is hoisted to the HOST at ingest time
-    (``build_plan_np``): the hashed indices of a chunk are static data,
-    so re-sorting them on device once per replay epoch (100x per fit) is
-    pure waste, and on XLA:CPU an in-step 6.8M-element sort costs
-    seconds. The plan (sort order by source row, segment ids, unique row
-    ids, and an inverse map) rides the device chunk cache / disk spill
-    next to the chunk, and the step becomes gather -> sorted
-    segment-scatter -> rule -> GATHER-based writeback
-    (``where(touched, new_rows[inv], emb)``) — no unsorted scatter
-    anywhere. Default on CPU.
-  - ``'sort'`` — the ISSUE-classic in-step form: ``argsort`` + segment
-    ids by ``cumsum`` of boundaries, then gather -> rule -> sorted unique
-    scatter over the LIVE prefix of the slots only, ``SLOT_BLOCK`` slots
-    a loop trip. Nothing rides the chunk cache. Default on TPU, where
-    HBM is the scarce resource. What the chip read at 2^29 rows and
-    6.8M occurrences a step (v5e, PERF.md §5): the table-wide gathers
-    were 0.42 s while they ran over the 6.8M-slot static bound, and cost
-    per INDEX (~14 ns), not per distinct row — hence the live prefix;
-    the part of the dedup that reads the keys alone (``sort_keys``: the
-    sort, the take of the sorted keys, the ``uniq`` scatter) was 0.10 s
-    of the 0.39 s step that left. A cached chunk's keys do not change
-    between epochs, so the fused replay builds that half once per chunk
-    and dispatch and hands it to its steps (``keys=``): ``sort_keys_bytes``
-    a chunk of temp in that one program, for as long as it runs, taken
-    only where the caller's cache budget holds it
-    (``models/hashed_linear._hoist_sort_keys``). ``_hashed_step`` and a
-    replay without the room sort in the step, as before.
-
-* **kill-switch** — ``OTPU_SPARSE_UPDATE=0`` resolves every ``sparse_*``
-  rule to its ``dense_*`` twin (mirroring ``OTPU_DONATE``'s convention):
-  the escape hatch if a backend ever miscompiles the touched-row
-  programs, and the bench's dense arm for like-for-like A/B. Resolution
-  happens ONCE at fit entry into a static argument, so flipping the env
-  var mid-process changes which program later fits compile without
-  poisoning the jit cache key space (pinned in tests/test_sparse_optim).
+* **one dedup lowering, ``'sort'``** — everything in the step:
+  ``argsort`` + segment ids by ``cumsum`` of boundaries, then gather ->
+  rule -> sorted unique scatter over the LIVE prefix of the slots only,
+  ``SLOT_BLOCK`` slots a loop trip. Nothing rides the chunk cache. What
+  the chip read at 2^29 rows and 6.8M occurrences a step (v5e, PERF.md
+  §5): the table-wide gathers were 0.42 s while they ran over the
+  6.8M-slot static bound, and cost per INDEX (~14 ns), not per distinct
+  row — hence the live prefix; the part of the dedup that reads the keys
+  alone (``sort_keys``: the sort, the take of the sorted keys, the
+  ``uniq`` scatter) was 0.10 s of the 0.39 s step that left. A cached
+  chunk's keys do not change between epochs, so the fused replay builds
+  that half once per chunk and dispatch and hands it to its steps
+  (``keys=``): ``sort_keys_bytes`` a chunk of temp in that one program,
+  for as long as it runs, taken only where the caller's cache budget
+  holds it (``models/hashed_linear._hoist_sort_keys``). ``_hashed_step``
+  and a replay without the room sort in the step.
 
 Layering: this module knows nothing about chunks, hashing or streams —
-``models/hashed_linear`` composes it into the step; ``ops/hashing``
-provides the host twin of the device hash the plan builder needs.
+``models/hashed_linear`` composes it into the step.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -89,12 +65,9 @@ from orange3_spark_tpu.obs.registry import REGISTRY
 
 __all__ = [
     "OPTIM_UPDATES", "SPARSE_UPDATES", "DENSE_UPDATES",
-    "sparse_updates_enabled", "resolve_optim_update",
+    "resolve_optim_update",
     "resolve_sparse_lowering", "optim_kind", "is_sparse_update",
     "init_optim_state", "adopt_optim_state", "plan_slots", "slot_blocks",
-    "build_plan_np", "plan_field_shapes",
-    "plan_pack_widths", "plan_packed_field_shapes", "pack_plan_np",
-    "unpack_plan",
     "occurrence_dead", "apply_rule", "dense_update",
     "sort_keys", "sort_slots", "sort_keys_bytes",
     "sparse_embedding_update", "note_slot_blocks", "note_sorts",
@@ -134,46 +107,31 @@ _M_SORTS = REGISTRY.counter(
     "steps). run/steps = share of the steps that paid for their own sort")
 
 
-def sparse_updates_enabled() -> bool:
-    """Global sparse-update switch — ``OTPU_SPARSE_UPDATE=0`` resolves
-    every ``sparse_*`` rule to its ``dense_*`` twin (read per resolution,
-    i.e. per fit entry, so a test can flip it mid-process; already-running
-    fits keep their resolved program)."""
-    from orange3_spark_tpu.utils import knobs
-
-    return knobs.get_bool("OTPU_SPARSE_UPDATE")
-
-
 def resolve_optim_update(value: str) -> str:
     """The concrete update rule for this fit — THE one resolver, applied
-    ONCE at fit entry so the resolved value is a static jit argument (the
-    compile cache is keyed on the resolution, never on the env var)."""
+    ONCE at fit entry so the resolved value is a static jit argument. The
+    ``dense_*`` twins are chosen by name and by nothing else."""
     if value not in OPTIM_UPDATES:
         raise ValueError(
             f"optim_update must be one of {OPTIM_UPDATES}, got {value!r}"
         )
-    if value in SPARSE_UPDATES and not sparse_updates_enabled():
-        return "dense_" + value[len("sparse_"):]
     return value
 
 
 def resolve_sparse_lowering(value: str) -> str:
-    """'auto' picks the dedup lowering per backend: ``'plan'``
-    (host-presorted, gather-based writeback) on CPU where an in-step
-    6.8M-element sort costs seconds and unsorted scatters ~240
-    ns/element; ``'sort'`` (in-jit argsort, nothing kept per cached chunk)
-    on TPU where HBM is the scarce resource — 'plan' keeps an O(n_dims)
-    inverse map per cached chunk. On a v5e at 2^29 rows the in-step sort
-    reads 0.06 s of a step (PERF.md §5); 'plan' has not been timed on the
-    chip, so 'sort' is the default there, not a measured best."""
-    if value == "auto":
-        return "sort" if jax.default_backend() == "tpu" else "plan"
-    if value not in ("plan", "sort"):
+    """``'sort'``, on every backend: the one dedup lowering there is (the
+    module docstring; on a v5e at 2^29 rows the in-step sort reads 0.06 s
+    of a step, PERF.md §5)."""
+    if value == "plan":
         raise ValueError(
-            f"sparse_lowering must be 'auto' | 'plan' | 'sort', "
-            f"got {value!r}"
+            "sparse_lowering='plan' was removed in PR 30: 'sort' is the "
+            "one dedup lowering there is (pass 'auto' or 'sort')"
         )
-    return value
+    if value not in ("auto", "sort"):
+        raise ValueError(
+            f"sparse_lowering must be 'auto' | 'sort', got {value!r}"
+        )
+    return "sort"
 
 
 def optim_kind(resolved: str) -> str:
@@ -263,7 +221,7 @@ def dense_update(kind: str, p, slots: dict, g, lr, decay, reg, l1, *,
     return apply_rule(kind, p, slots, g, lr, reg, l1)
 
 
-# ------------------------------------------------- plan building (host side)
+# ------------------------------------------------------- slot arithmetic
 
 def plan_slots(pad_rows: int, n_cat: int, n_dims: int) -> int:
     """Static bound on the per-chunk unique-row count, plus ONE spare slot
@@ -295,193 +253,9 @@ def note_sorts(run: int, steps: int) -> None:
     _M_SORTS.inc(steps, which="steps")
 
 
-def plan_field_shapes(pad_rows: int, n_cat: int, n_dims: int,
-                      value_weighted: bool) -> dict:
-    """Shapes (all i32 but 'val') of the per-chunk plan arrays — the one
-    authority the spill layout and warm-path builders share."""
-    M = pad_rows * n_cat
-    U = plan_slots(pad_rows, n_cat, n_dims)
-    shapes = {"row": (M,), "seg": (M,), "uniq": (U,), "inv": (n_dims,)}
-    if value_weighted:
-        shapes["val"] = (M,)
-    return shapes
-
-
-def build_plan_np(cats: np.ndarray, salts: np.ndarray, n_dims: int,
-                  n_valid: int, *, vals: np.ndarray | None = None,
-                  impute_missing: bool = False,
-                  idx: np.ndarray | None = None) -> dict:
-    """Host-side touched-row plan for one padded chunk — built ONCE on the
-    prefetch thread (overlapping device steps) and replayed every epoch.
-
-    ``cats``: [N, C] raw categorical codes (pre-hash, possibly NaN when
-    ``impute_missing``); ``vals``: the per-pair multipliers in
-    value-weighted mode. Dead occurrences (rows >= ``n_valid``, or vw
-    pairs with raw index < 0) sort behind a ``n_dims`` sentinel into the
-    spare slot ``plan_slots`` reserves — their gradients are zero anyway
-    (w == 0 rows / val == 0 pairs), so nothing masks them in-jit.
-
-    Returns {'row': i32[M] source row of each SORTED occurrence,
-    'seg': i32[M] its segment id (sorted, dense), 'uniq': i32[U] the
-    touched table row per segment (-1 on dead/pad slots), 'inv': i32[D]
-    table row -> segment id (-1 untouched), ['val': f32[M] sorted
-    multipliers]}. The argsort is STABLE so a row's occurrences keep
-    their original order — the exactness contract of the module
-    docstring.
-
-    'inv' is derivable from 'uniq' (one sorted scatter of U entries) but
-    is deliberately MATERIALIZED here: rebuilding it in-jit would put a
-    scatter back on every step — the exact op this lowering exists to
-    avoid (~240 ns/element on XLA:CPU; U is millions at Criteo shape) —
-    while caching it costs O(n_dims) bytes once per chunk. Callers that
-    cannot afford the per-chunk aux memory use the 'sort' lowering,
-    which carries no plan at all."""
-    from orange3_spark_tpu.ops.hashing import hash_columns_np
-
-    cats = np.asarray(cats)
-    if idx is None:
-        if impute_missing:
-            cats = np.where(np.isnan(cats), 0.0, cats)
-        idx = hash_columns_np(cats, salts, n_dims)        # [N, C] i32
-    # callers with the 'packed' chunk codec pass the idx their encode
-    # already hashed — the two host hashes of the same 26 columns per
-    # chunk were pure duplicated prefetch-thread work
-    N, C = idx.shape
-    M = N * C
-    U = plan_slots(N, C, n_dims)
-    dead = np.zeros((N, C), np.bool_)
-    if n_valid < N:
-        dead[n_valid:] = True
-    if vals is not None:
-        dead |= np.asarray(cats) < 0
-    flat = np.where(dead, np.int32(n_dims), idx).reshape(-1)
-    order = np.argsort(flat, kind="stable").astype(np.int32)
-    s = flat[order]
-    start = np.empty(M, np.bool_)
-    start[0] = True
-    np.not_equal(s[1:], s[:-1], out=start[1:])
-    seg = (np.cumsum(start, dtype=np.int64) - 1).astype(np.int32)
-    live_start = start & (s < n_dims)
-    uniq = np.full(U, -1, np.int32)
-    uniq[seg[live_start]] = s[live_start]
-    inv = np.full(n_dims, -1, np.int32)
-    inv[s[live_start]] = seg[live_start]
-    plan = {
-        "row": (order // C).astype(np.int32),
-        "seg": seg,
-        "uniq": uniq,
-        "inv": inv,
-    }
-    if vals is not None:
-        plan["val"] = np.ascontiguousarray(
-            np.asarray(vals, np.float32).reshape(-1)[order])
-    return plan
-
-
-def plan_pack_widths(pad_rows: int, n_cat: int, n_dims: int) -> dict:
-    """STATIC bit widths of the bit-packed plan arrays (io/codec.py) —
-    every plan quantity is bounded by chunk/table shape, never by data:
-    'row' < pad_rows, 'uniq'+1 <= n_dims (the -1 dead sentinel shifts to
-    0), 'inv'+1 <= U. 'seg' is not packed at a width at all: it is
-    nondecreasing with 0/1 steps, so its information content is the
-    boundary BIT array — stored 1 bit per occurrence and rebuilt in-jit
-    by one cumsum (a 32x shrink on the largest plan array)."""
-    U = plan_slots(pad_rows, n_cat, n_dims)
-    from orange3_spark_tpu.io.codec import bit_width
-
-    return {"row": bit_width(pad_rows), "uniq": bit_width(n_dims + 1),
-            "inv": bit_width(U + 1)}
-
-
-def plan_packed_field_shapes(pad_rows: int, n_cat: int, n_dims: int) -> dict:
-    """name -> (shape, dtype) of the packed plan's u32 carrier arrays, in
-    spill declaration order — the one authority the spill layout and the
-    warm-path builders share (the packed twin of ``plan_field_shapes``).
-    'segb' holds per-word boundary anchors AND the boundary bits (see
-    ``pack_plan_np``), hence the 2x word count."""
-    from orange3_spark_tpu.io.codec import flat_words
-
-    M = pad_rows * n_cat
-    U = plan_slots(pad_rows, n_cat, n_dims)
-    wb = plan_pack_widths(pad_rows, n_cat, n_dims)
-    return {
-        "rowp": ((flat_words(M, wb["row"]),), np.uint32),
-        "segb": ((2 * -(-M // 32),), np.uint32),
-        "uniqp": ((flat_words(U, wb["uniq"]),), np.uint32),
-        "invp": ((flat_words(n_dims, wb["inv"]),), np.uint32),
-    }
-
-
-def pack_plan_np(plan: dict, pad_rows: int, n_cat: int, n_dims: int) -> dict:
-    """Host-side losslessly bit-packed form of a touched-row plan — built
-    on the prefetch thread right after ``build_plan_np`` and cached/
-    spilled/stacked in place of the raw i32 arrays under the 'packed'
-    cache dtype. ``unpack_plan`` is the bit-exact in-jit inverse, so the
-    plan-lowering update stays BITWISE identical to the raw-plan path."""
-    from orange3_spark_tpu.io.codec import pack_flat_np
-
-    wb = plan_pack_widths(pad_rows, n_cat, n_dims)
-    seg = plan["seg"]
-    M = seg.shape[0]
-    start = np.empty(M, np.uint32)
-    start[0] = 1
-    start[1:] = (seg[1:] != seg[:-1]).astype(np.uint32)
-    # 'seg' is nondecreasing with 0/1 steps: store the boundary BITS (32x
-    # smaller) plus one running anchor per word — seg[j] then rebuilds as
-    # anchor[word] + popcount(bits up to j) - 1, a single vectorized
-    # popcount at decode instead of a full-length cumsum (which cost more
-    # than every other plan decode combined on XLA:CPU)
-    bitwords = pack_flat_np(start, 1)
-    pops = _popcount_u32(bitwords)
-    anchors = np.zeros(bitwords.shape[0], np.uint32)
-    np.cumsum(pops[:-1], out=anchors[1:], dtype=np.uint32)
-    return {
-        "rowp": pack_flat_np(plan["row"], wb["row"]),
-        "segb": np.concatenate([anchors, bitwords]),
-        "uniqp": pack_flat_np(plan["uniq"] + 1, wb["uniq"]),
-        "invp": pack_flat_np(plan["inv"] + 1, wb["inv"]),
-    }
-
-
-def _popcount_u32(words: np.ndarray) -> np.ndarray:
-    """Vectorized host popcount (numpy<2.0 has no ``bitwise_count``)."""
-    if hasattr(np, "bitwise_count"):
-        return np.bitwise_count(words).astype(np.uint32)
-    v = words.copy()
-    v = v - ((v >> np.uint32(1)) & np.uint32(0x55555555))
-    v = (v & np.uint32(0x33333333)) + ((v >> np.uint32(2))
-                                       & np.uint32(0x33333333))
-    v = (v + (v >> np.uint32(4))) & np.uint32(0x0F0F0F0F)
-    return ((v * np.uint32(0x01010101)) >> np.uint32(24)).astype(np.uint32)
-
-
-def unpack_plan(enc: dict, pad_rows: int, n_cat: int, n_dims: int) -> dict:
-    """In-jit decode of ``pack_plan_np``'s output back to the raw plan
-    dict — static shifts/masks plus one i32 cumsum for 'seg'; XLA fuses
-    the widen into the consuming gathers/segment-sum."""
-    from orange3_spark_tpu.io.codec import unpack_flat
-
-    M = pad_rows * n_cat
-    U = plan_slots(pad_rows, n_cat, n_dims)
-    wb = plan_pack_widths(pad_rows, n_cat, n_dims)
-    B = enc["segb"].shape[0] // 2
-    anchors, bitwords = enc["segb"][:B], enc["segb"][B:]
-    # inclusive-prefix popcount within each word + the per-word anchor
-    # rebuilds seg without any sequential scan (see pack_plan_np)
-    masks = np.array([0xFFFFFFFF >> (31 - j) for j in range(32)], np.uint32)
-    pc = jax.lax.population_count(bitwords[:, None] & masks[None, :])
-    seg = (anchors[:, None] + pc).reshape(B * 32)[:M].astype(jnp.int32) - 1
-    return {
-        "row": unpack_flat(enc["rowp"], wb["row"], M),
-        "seg": seg,
-        "uniq": unpack_flat(enc["uniqp"], wb["uniq"], U) - 1,
-        "inv": unpack_flat(enc["invp"], wb["inv"], n_dims) - 1,
-    }
-
-
 def occurrence_dead(n_rows: int, n_cat: int, n_valid, raw_cats=None):
-    """In-jit dead-occurrence mask for the 'sort' lowering — the traced
-    twin of ``build_plan_np``'s host-side rule."""
+    """In-jit dead-occurrence mask: rows at or past ``n_valid`` (padding)
+    and, in value-weighted mode, pairs whose raw index is negative."""
     dead = (jnp.arange(n_rows, dtype=jnp.int32)[:, None] >= n_valid)
     dead = jnp.broadcast_to(dead, (n_rows, n_cat))
     if raw_cats is not None:
@@ -494,10 +268,9 @@ def occurrence_dead(n_rows: int, n_cat: int, n_valid, raw_cats=None):
 def _touched_rows_update(kind, emb, t, slots, sums, rid, lr, decay, reg, l1,
                          step, *, use_decay):
     """Gather the touched rows (+ slots, + timestamps), apply catch-up
-    lazy decay and the rule — the core both lowerings share. ``rid`` is
-    a touched-row list (-1 on dead slots; gathers clamp, writeback
-    masks): the plan's whole [U] under 'plan', one block of the live
-    prefix under 'sort'. Returns the updated rows and slot rows."""
+    lazy decay and the rule. ``rid`` is one block of the live prefix of
+    the touched-row list (-1 on dead slots; gathers clamp, writeback
+    masks). Returns the updated rows and slot rows."""
     with jax.named_scope("step/gather"):
         rsafe = jnp.maximum(rid, 0)
         p_rows = jnp.take(emb, rsafe, axis=0)
@@ -583,21 +356,16 @@ def sort_keys_bytes(pad_rows: int, n_cat: int, n_dims: int) -> int:
 
 
 def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
-                            step, *, lowering: str, use_decay: bool,
-                            plan=None, n_valid=None, raw_cats=None,
-                            vals=None, keys=None):
+                            step, *, use_decay: bool, n_valid=None,
+                            raw_cats=None, vals=None, keys=None):
     """One touched-row-only table update. ``dl`` is the [N, k] logits
     gradient; per-occurrence gradients are ``dl[row] (* val)``.
 
-    'plan': the host-precomputed plan supplies sort order / segments /
-    unique rows / inverse map; writeback is a pure GATHER
-    (``where(touched, new_rows[inv], emb)``) — the whole step is
-    scatter-free except the one sorted segment-sum.
-    'sort': everything derived in-jit (argsort + cumsum-of-boundaries) —
-    or, where the caller has already run ``sort_keys`` over this chunk
-    (the fused replay, once per chunk and dispatch), handed in as ``keys``
-    and only the gradient half computed here: the same operations on the
-    same values either way. The slot arrays keep the static bound
+    The dedup is derived in-jit (argsort + cumsum-of-boundaries) — or,
+    where the caller has already run ``sort_keys`` over this chunk (the
+    fused replay, once per chunk and dispatch), handed in as ``keys`` and
+    only the gradient half computed here: the same operations on the same
+    values either way. The slot arrays keep the static bound
     ``plan_slots`` (a chunk of all-distinct keys fills it), but only their
     live prefix is gathered, run through the rule and written back: a
     ``fori_loop`` over blocks of
@@ -607,40 +375,13 @@ def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
     dropped; the tables are the loop's carries, updated in place.
 
     Returns ``(emb, t, slots, n_blocks)``: ``n_blocks`` is the i32 count
-    of trips this update ran (0 under 'plan', which has no loop).
+    of trips this update ran.
 
     Phases, as ``jax.named_scope``s a device trace is read by:
-    ``step/sort`` ('sort' only), ``step/segment``, ``step/gather``,
-    ``step/rule``, ``step/scatter`` (the write-back of either lowering);
-    under 'sort' the last three sit inside the block loop, so a trace
+    ``step/sort``, ``step/segment``, ``step/gather``, ``step/rule``,
+    ``step/scatter``; the last three sit inside the block loop, so a trace
     shows them once per trip (``.../while/body/step/gather/...``)."""
     D = emb.shape[0]
-    if lowering == "plan":
-        with jax.named_scope("step/segment"):
-            g = jnp.take(dl, plan["row"], axis=0)             # [M, k]
-            if "val" in plan:
-                g = g * plan["val"][:, None]
-            U = plan["uniq"].shape[0]
-            sums = _segment_sums(g, plan["seg"], U)
-            rid = plan["uniq"]
-        p_rows, slot_rows = _touched_rows_update(
-            kind, emb, t, slots, sums, rid, lr, decay, reg, l1, step,
-            use_decay=use_decay)
-        with jax.named_scope("step/scatter"):
-            inv = plan["inv"]
-            sel = inv >= 0
-            isafe = jnp.maximum(inv, 0)
-            emb = jnp.where(sel[:, None], jnp.take(p_rows, isafe, axis=0),
-                            emb)
-            slots = {n: jnp.where(sel[:, None], jnp.take(v, isafe, axis=0),
-                                  slots[n])
-                     for n, v in slot_rows.items()}
-            if use_decay:
-                t = jnp.where(sel, step + 1, t)
-        return emb, t, slots, jnp.int32(0)
-
-    if lowering != "sort":
-        raise ValueError(f"unknown sparse lowering {lowering!r}")
     N, C = idx.shape
     B = min(SLOT_BLOCK, plan_slots(N, C, D))
     if keys is None:

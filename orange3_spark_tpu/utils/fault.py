@@ -25,6 +25,22 @@ import jax
 import numpy as np
 
 
+#: estimator parameters that are gone, but that a snapshot written before
+#: their removal still carries under ``meta['params']``. Each chose among
+#: lowerings of the same arithmetic and never the result, so a saved value
+#: says nothing about the configuration: ``load`` drops it before it
+#: compares — THE one place, which every resume path (``fit_stream``, the
+#: online trainer, the multi-host wrapper) goes through.
+RETIRED_PARAMS = ("emb_update",)
+
+
+def _without_retired(meta):
+    if not (isinstance(meta, dict) and isinstance(meta.get("params"), dict)):
+        return meta
+    return {**meta, "params": {k: v for k, v in meta["params"].items()
+                               if k not in RETIRED_PARAMS}}
+
+
 class StreamCheckpointer:
     """Atomic pickle snapshots of (step, pytree-of-arrays) training state."""
 
@@ -71,7 +87,7 @@ class StreamCheckpointer:
             return 0, None
         with open(self.path, "rb") as f:
             blob = pickle.load(f)
-        saved_meta = blob.get("meta")
+        saved_meta = _without_retired(blob.get("meta"))
         if expect_meta is not None and saved_meta is not None                 and saved_meta != expect_meta:
             raise ValueError(
                 f"checkpoint {self.path!r} was written with a different "

@@ -60,9 +60,6 @@ _ALL = [
          "Chunk-cache codec override: f32 | bf16 | packed "
          "(outranks the params' cache_dtype; f32 = legacy bitwise)."),
     # ----------------------------------------------------------- optim/
-    Knob("OTPU_SPARSE_UPDATE", "flag", "1", "optim",
-         "Sparse touched-row optimizer kill-switch; 0 resolves sparse_* "
-         "rules to their dense twins at fit entry."),
     Knob("OTPU_OPTIM_UPDATE", "str", "sparse_adagrad", "optim",
          "bench.py criteo optimizer rule ('adam' reproduces the legacy "
          "records)."),

@@ -12,12 +12,11 @@ import warnings
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from orange3_spark_tpu.io.codec import (
-    BF16, bit_width, flat_words, force_cache_dtype, pack_flat_np,
-    pack_rows_np, resolve_cache_dtype, unpack_flat, unpack_rows,
+    BF16, bit_width, force_cache_dtype, pack_rows_np, resolve_cache_dtype,
+    unpack_rows,
 )
 from orange3_spark_tpu.io.streaming import (
     DiskChunkCache, StreamingLinearEstimator, _DeviceCache,
@@ -66,31 +65,7 @@ def test_bitpack_roundtrips_all_widths():
         out = np.asarray(unpack_rows(
             jnp.asarray(pack_rows_np(vals, bits)), bits, 26))
         np.testing.assert_array_equal(out, vals.astype(np.int32))
-        n = 4099
-        fv = rng.integers(0, 1 << bits, n, dtype=np.int64).astype(np.uint32)
-        packed = pack_flat_np(fv, bits)
-        assert packed.shape == (flat_words(n, bits),)
-        fo = np.asarray(unpack_flat(jnp.asarray(packed), bits, n))
-        np.testing.assert_array_equal(fo, fv.astype(np.int32))
     assert bit_width(1) == 1 and bit_width(1 << 22) == 22
-
-
-def test_plan_pack_roundtrip_bit_exact():
-    from orange3_spark_tpu.ops.hashing import column_salts
-    from orange3_spark_tpu.optim.sparse import (
-        build_plan_np, pack_plan_np, unpack_plan,
-    )
-
-    rng = np.random.default_rng(4)
-    for N, C, D in ((64, 3, 128), (1024, 26, 1 << 12), (128, 6, 1)):
-        salts = column_salts(C, 1)
-        cats = rng.integers(0, 5000, (N, C)).astype(np.float32)
-        plan = build_plan_np(cats, salts, D, N - 7)
-        dec = jax.jit(
-            lambda e, N=N, C=C, D=D: unpack_plan(e, N, C, D)
-        )(pack_plan_np(plan, N, C, D))
-        for k in ("row", "seg", "uniq", "inv"):
-            np.testing.assert_array_equal(np.asarray(dec[k]), plan[k]), k
 
 
 def test_resolver_gates():
@@ -125,15 +100,14 @@ def test_resolver_gates():
 
 def test_lossless_pack_replay_bitwise_identical(session):
     """The acceptance claim: with no dense block every cached quantity is
-    losslessly packed (u8 label via y, pre-hashed bit-packed indices,
-    bit-packed plan arrays), so the packed-cache fit must equal the
-    f32-cache fit BITWISE — across the legacy adam rule, a sparse rule
-    (plan lowering + packed plans) and a dense twin."""
+    losslessly packed (u8 label via y, pre-hashed bit-packed indices), so
+    the packed-cache fit must equal the f32-cache fit BITWISE — under the
+    legacy adam rule and under a sparse rule."""
     rng = np.random.default_rng(5)
     cats = rng.integers(0, 50_000, (4096, 8)).astype(np.float32)
     y = (cats[:, 0] % 3 == 0).astype(np.float32)
-    # adam = the dense-autodiff path, sparse_adagrad = the plan path with
-    # packed plans; between them every decode consumer is covered
+    # adam = the dense-autodiff path, sparse_adagrad = the touched-row
+    # path; between them every decode consumer is covered
     for optim in ("adam", "sparse_adagrad"):
         kw = dict(n_dense=0, n_cat=8, optim_update=optim, epochs=5)
         m32 = _fit(session, cats, y, "f32", **kw)
@@ -161,7 +135,7 @@ def test_bf16_divergence_bound_100_epochs(session):
 
 def test_compressed_replay_paths_agree(session, tmp_path, data):
     """fused('all') vs epoch-granular vs disk-spill replay under the
-    packed codec: the encoded chunks/plans ride the HBM stack AND the
+    packed codec: the encoded chunks ride the HBM stack AND the
     typed spill records — same numbers everywhere."""
     Xall, y = data
     fused = _fit(session, Xall, y, "packed")
@@ -203,9 +177,10 @@ def test_kill_switch_restores_legacy_zero_compiles(session, data,
 # ------------------------------------------------------- cache economics
 
 def test_capacity_compressed_cache_fuses_where_f32_degrades(session, data):
-    """The tentpole's point: at a budget the f32 layout overflows, the
-    compressed layout still holds the whole stream (and passes the 2x
-    fusion gate) — the fused-replay cliff moves ~2x out."""
+    """The codec's point: at a budget whose fusion gate (cache + its
+    stack) the f32 layout fails, so that it replays chunk by chunk, the
+    compressed layout still passes it and replays in one dispatch — the
+    fused-replay cliff moves out by the compression ratio."""
     Xall, y = data
     p_pk = StreamingHashedLinearEstimator(
         **BASE, cache_dtype="packed").params
@@ -213,20 +188,25 @@ def test_capacity_compressed_cache_fuses_where_f32_degrades(session, data):
         pk_chunk = estimate_cached_chunk_bytes(p_pk, session)
     with force_cache_dtype("f32"):
         f32_chunk = estimate_cached_chunk_bytes(p_pk, session)
-    assert f32_chunk / pk_chunk > 2.0   # criteo-shaped sparse-plan config
+    # read (CPU, PR 30): 48 B a row (4 dense + 6 codes + y + w, f32)
+    # against 28 (bf16 dense, 6 x 12 bits in 3 words, y + w f32)
+    assert (f32_chunk, pk_chunk) == (49152, 28672)      # 1.71x
     budget = 2 * 4 * pk_chunk + 4096    # fusion gate: 2x the 4-chunk cache
+    assert 4 * f32_chunk <= budget < 2 * 4 * f32_chunk
     st_pk: dict = {}
     mpk = _fit(session, Xall, y, "packed", cache_device_bytes=budget,
                stage_times=st_pk)
     assert st_pk["replay_source"] == "fused"
     assert st_pk["cache_overflow"] is False
     assert st_pk["cache_dtype"] == "packed"
-    assert st_pk["cache_raw_bytes"] / st_pk["cache_bytes"] > 2.0
+    # the cache counts each chunk's i32 n_valid beside its blocks
+    assert (st_pk["cache_raw_bytes"], st_pk["cache_bytes"]) == (
+        4 * f32_chunk, 4 * (pk_chunk + 4))
     st_32: dict = {}
-    with pytest.warns(RuntimeWarning, match="cache overflowed"):
-        m32 = _fit(session, Xall, y, "f32", cache_device_bytes=budget,
-                   stage_times=st_32)
-    assert st_32["replay_source"] == "stream"
+    m32 = _fit(session, Xall, y, "f32", cache_device_bytes=budget,
+               stage_times=st_32)
+    assert st_32["replay_source"] == "hbm"
+    assert st_32["cache_overflow"] is False
     # same math either way (bf16 rounding only)
     assert np.abs(_emb(mpk) - _emb(m32)).max() < 1e-3
 

@@ -154,8 +154,6 @@ def test_every_span_has_its_thread_parent_and_trace_id(traced_fit):
     assert [e[5]["i"] for e in _named(run, "input_wait")][:2] == [0, 1]
     for e in _named(run, "replay"):
         assert e[5]["n_epochs"] == 2 and e[5]["steps"] == 2 * 7
-    for e in _named(run, "encode"):     # 'plan' is this backend's lowering
-        assert e[5]["plan_s"] >= 0.0
 
 
 @pytest.mark.parametrize("key", sorted(STAGE_OF))
@@ -250,49 +248,41 @@ def test_profiler_trace_holds_an_otpu_twin_of_every_span(
 
 
 # ------------------------------------------------------- device scopes
-def _lowered_text(session, lowering: str, *, replay: bool) -> str:
+def _lowered_text(session, *, replay: bool) -> str:
     from orange3_spark_tpu.models.hashed_linear import (
         StreamingHashedLinearEstimator, _hashed_replay_epochs, _hashed_step,
         _init_fit_state,
     )
-    from orange3_spark_tpu.optim.sparse import build_plan_np
 
     p = StreamingHashedLinearEstimator(
         n_dims=1 << 10, n_dense=N_DENSE, n_cat=N_CAT, chunk_rows=CHUNK,
         loss="squared_hinge", optim_update="sparse_adagrad",
         reg_param=1e-4, cache_dtype="f32").params
     theta, opt, salts_np, salts, kw = _init_fit_state(p, session)
-    assert kw["codec"] is None
-    kw["sparse_lowering"] = lowering
+    assert kw["codec"] is None and kw["sparse_lowering"] == "sort"
     X, y = _data()
     chunk = (jnp.asarray(X[:CHUNK]), jnp.int32(CHUNK),
              jnp.asarray(y[:CHUNK]), jnp.ones((CHUNK,), jnp.float32))
-    plan = None
-    if lowering == "plan":
-        plan = jax.tree.map(jnp.asarray, build_plan_np(
-            X[:CHUNK, N_DENSE:], salts_np, p.n_dims, CHUNK))
     reg, lr = jnp.float32(1e-4), jnp.float32(0.05)
     if not replay:
         lowered = _hashed_step.plain.lower(
-            theta, opt, *chunk, salts, reg, lr, plan, **kw)
+            theta, opt, *chunk, salts, reg, lr, **kw)
     else:
-        stacks = chunk + ((plan,) if plan is not None else ())
-        stacks = jax.tree.map(lambda a: jnp.stack([a, a]), stacks)
+        stacks = jax.tree.map(lambda a: jnp.stack([a, a]), chunk)
         lowered = _hashed_replay_epochs.plain.lower(
             theta, opt, stacks, salts, reg, lr, n_epochs=2, **kw)
     return lowered.as_text(debug_info=True)
 
 
 STEP_SCOPES = ("step/decode", "step/forward", "step/loss_grad",
-               "step/dense_leaf", "step/segment", "step/gather", "step/rule",
-               "step/scatter")
+               "step/dense_leaf", "step/sort", "step/segment", "step/gather",
+               "step/rule", "step/scatter")
 
 
 @pytest.mark.parametrize("replay", [False, True], ids=["step", "replay"])
-@pytest.mark.parametrize("lowering", ["sort", "plan"])
-def test_lowered_programs_carry_the_phase_scopes(session, lowering, replay):
-    text = _lowered_text(session, lowering, replay=replay)
-    scopes = STEP_SCOPES + (("step/sort",) if lowering == "sort" else ())
+def test_lowered_programs_carry_the_phase_scopes(session, replay):
+    text = _lowered_text(session, replay=replay)
+    scopes = STEP_SCOPES
     if replay:
         scopes += ("replay/epoch", "replay/chunk")
     for scope in scopes:
@@ -300,8 +290,6 @@ def test_lowered_programs_carry_the_phase_scopes(session, lowering, replay):
         # at the scope ("replay/chunk/..."), autodiff's reads jvp(scope)
         assert re.search(rf'[/("]{scope}[/)]', text), \
             f"{scope} is in no op_name"
-    if lowering == "plan":
-        assert "step/sort" not in text
     if not replay:
         assert "replay/" not in text
 

@@ -276,20 +276,3 @@ def test_fused_replay_respects_holdout(session):
     assert "replay_fused_s" in st
     ev = model.evaluate_device(model.holdout_chunks_)
     assert 0.0 < ev["logloss"] < 2.0
-
-
-def test_emb_update_auto_resolves_per_backend(session):
-    """'auto' picks the measured-best lowering at fit time (currently
-    'fused' on every backend per the 2026-07-31 on-chip A/B — see
-    resolve_emb_update) and never reaches the jitted step unresolved."""
-    from orange3_spark_tpu.models.hashed_linear import (
-        HashedLinearParams, _init_fit_state,
-    )
-
-    p = HashedLinearParams()
-    assert p.emb_update == "auto"
-    *_, kw = _init_fit_state(p, session)
-    assert kw["emb_update"] == "fused"
-    # explicit values pass through untouched
-    *_, kw = _init_fit_state(p.replace(emb_update="per_column"), session)
-    assert kw["emb_update"] == "per_column"

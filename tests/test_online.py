@@ -476,11 +476,14 @@ def test_trainer_crash_typed_then_checkpoint_resume_bitwise(ctr, session,
     log.close()
 
 
+@pytest.mark.parametrize("older", ["no-block-counter", "retired-emb_update"])
 def test_trainer_resumes_checkpoint_without_block_counter(session,
-                                                         tmp_path):
-    """A trainer snapshot written before the sparse rules' opt_state
-    carried 'blocks' (same online-trainer-v1 meta) must resume, to the
-    same candidate bitwise — the crash-recovery loop across an upgrade."""
+                                                         tmp_path, older):
+    """The crash-recovery loop across an upgrade: a trainer snapshot
+    written before the sparse rules' opt_state carried 'blocks' (same
+    online-trainer-v1 meta) must resume, to the same candidate bitwise —
+    and so must one whose serving model a program before PR 30 pickled,
+    with the retired ``emb_update`` still on its params."""
     import pickle
 
     from orange3_spark_tpu.models.hashed_linear import (
@@ -508,7 +511,16 @@ def test_trainer_resumes_checkpoint_without_block_counter(session,
             crash.consume_available()
     with open(tmp_path / "old.ck", "rb") as f:
         blob = pickle.load(f)
-    assert blob["state"]["opt"].pop("blocks") > 0
+    if older == "no-block-counter":
+        assert blob["state"]["opt"].pop("blocks") > 0
+    else:
+        # the snapshot's meta names no estimator parameter; the model
+        # does: a frozen dataclass unpickles by __dict__.update, so the
+        # parent's field lands on the instance and has to be inert
+        assert blob["meta"][0] == "online-trainer-v1"
+        model = pickle.loads(pickle.dumps(model))
+        model.params.__dict__["emb_update"] = "sorted"
+        assert "emb_update" not in model.params.to_dict()
     with open(tmp_path / "old.ck", "wb") as f:
         pickle.dump(blob, f)
     resumed = _trainer(model, log, session, tmp_path / "old.ck",

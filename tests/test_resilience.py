@@ -645,22 +645,3 @@ def test_fault_matrix_tool_outcomes(session):
     assert by["label_skew"]["outcome"] == "recovered"
     assert by["trainer_crash"]["outcome"] == "raised:TrainerCrashInjected"
     assert not any(r["outcome"].startswith("UNEXPECTED") for r in rows)
-
-
-def test_replay_fault_diag_smoke():
-    """The diag tool's subprocess/JSON plumbing, promoted to a not-slow
-    smoke (no jax import in the cell, no device lock)."""
-    import json
-
-    env = dict(os.environ)
-    env["PYTHONPATH"] = ""
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools",
-                                      "replay_fault_diag.py"), "--smoke"],
-        capture_output=True, text=True, timeout=120, cwd=REPO, env=env)
-    assert r.returncode == 0, r.stderr[-2000:]
-    lines = [ln for ln in r.stdout.splitlines() if ln.startswith("{")]
-    verdict = json.loads(lines[-1])
-    assert verdict["metric"] == "replay_fault_diag"
-    assert verdict["value"] == 1 and verdict["cells_ok"] == 1
-    assert verdict["cells"][0]["stages_completed"] == ["noop"]

@@ -1,6 +1,6 @@
 """Sparse touched-row optimizer subsystem (optim/) — parity against the
-dense twins, lazy-decay equivalence, edge cases, replay-path parity, the
-kill-switch, and the recompile-regression guard.
+dense twins, lazy-decay equivalence, edge cases, replay-path parity, and
+the recompile-regression guard.
 
 Parity contract (docs/optim.md): the sparse and dense lowerings of one
 rule are the SAME math. The stable sort + ordered segment scatter make
@@ -30,7 +30,7 @@ from orange3_spark_tpu.ops.hashing import (
 )
 from orange3_spark_tpu.optim import sparse as sparse_mod
 from orange3_spark_tpu.optim.sparse import (
-    build_plan_np, plan_slots, resolve_optim_update, resolve_sparse_lowering,
+    plan_slots, resolve_optim_update, resolve_sparse_lowering,
     slot_blocks, sparse_embedding_update,
 )
 
@@ -60,7 +60,7 @@ def data():
 # ------------------------------------------------------------ host hashing
 
 def test_host_hash_matches_device_hash():
-    """The plan builder hashes on the HOST; one bit of drift against the
+    """The packed codec hashes on the HOST; one bit of drift against the
     in-jit hash silently updates the wrong table rows."""
     rng = np.random.default_rng(3)
     salts = column_salts(5, seed=7)
@@ -74,35 +74,6 @@ def test_host_hash_matches_device_hash():
             np.asarray(hash_columns(jnp.asarray(cats), salts, D)))
 
 
-def test_build_plan_invariants():
-    rng = np.random.default_rng(4)
-    N, C, D = 64, 3, 128
-    salts = column_salts(C, seed=1)
-    cats = rng.integers(0, 500, (N, C)).astype(np.float32)
-    n_valid = 50
-    plan = build_plan_np(cats, salts, D, n_valid)
-    U = plan_slots(N, C, D)
-    idx = hash_columns_np(cats, salts, D)
-    live = set(idx[:n_valid].ravel().tolist())
-    touched = set(plan["uniq"][plan["uniq"] >= 0].tolist())
-    assert touched == live          # exactly the live buckets, no pads
-    # inv is the inverse of uniq on live rows, -1 elsewhere
-    for d in range(D):
-        if d in live:
-            assert plan["uniq"][plan["inv"][d]] == d
-        else:
-            assert plan["inv"][d] == -1
-    # segment ids are sorted and occurrences of one bucket keep their
-    # original order (stable sort — the exactness contract)
-    assert (np.diff(plan["seg"]) >= 0).all()
-    flat = idx.reshape(-1)
-    order_rows = plan["row"] * C  # row-major lower bound of the occurrence
-    for s in range(plan["seg"].max() + 1):
-        occ = np.where(plan["seg"] == s)[0]
-        src = order_rows[occ]
-        assert (np.diff(src) >= 0).all()
-
-
 # ------------------------------------------------------- parity vs twins
 
 def _emb_diff(a, b):
@@ -110,31 +81,32 @@ def _emb_diff(a, b):
         np.asarray(a.theta["emb"]) - np.asarray(b.theta["emb"]))))
 
 
-def test_sparse_sgd_matches_dense_sgd_no_decay(session, data):
-    """The headline exactness claim: without decay, sparse SGD's per-row
-    sums are the dense backward's sums in the same order."""
+@pytest.mark.parametrize("optim", ["sgd", "adagrad"])
+def test_sparse_sgd_matches_dense_sgd_no_decay(session, data, optim):
+    """The headline exactness claim: without decay, the sparse step's
+    per-row sums are the dense backward's sums in the same order — so SGD
+    agrees to fusion rounding, and Adagrad (the same sums through one
+    rsqrt) nearly as closely."""
     Xall, y = data
-    dense = _fit(session, Xall, y, optim_update="dense_sgd")
-    for lowering in ("plan", "sort"):
-        sparse = _fit(session, Xall, y, optim_update="sparse_sgd",
-                      sparse_lowering=lowering)
-        assert _emb_diff(sparse, dense) <= 5e-9, lowering
-        np.testing.assert_allclose(
-            np.asarray(sparse.theta["coef"]), np.asarray(dense.theta["coef"]),
-            rtol=1e-6, atol=1e-7)
+    dense = _fit(session, Xall, y, optim_update=f"dense_{optim}")
+    sparse = _fit(session, Xall, y, optim_update=f"sparse_{optim}")
+    assert _emb_diff(sparse, dense) <= {"sgd": 5e-9, "adagrad": 1e-7}[optim]
+    np.testing.assert_allclose(
+        np.asarray(sparse.theta["coef"]), np.asarray(dense.theta["coef"]),
+        rtol=1e-6, atol=1e-7)
 
 
-def test_lazy_decay_equivalence(session, data):
+@pytest.mark.parametrize("optim", ["sgd", "adagrad"])
+def test_lazy_decay_equivalence(session, data, optim):
     """reg > 0: the sparse path applies (1-lr*reg)^dt lazily + a finalize
     sweep; the dense twin multiplies per step. Same product, pow-rounding
     tolerance only."""
     Xall, y = data
-    for optim in ("sgd", "adagrad"):
-        dense = _fit(session, Xall, y, optim_update=f"dense_{optim}",
-                     reg_param=1e-3)
-        sparse = _fit(session, Xall, y, optim_update=f"sparse_{optim}",
-                      reg_param=1e-3)
-        assert _emb_diff(sparse, dense) < 1e-6, optim
+    dense = _fit(session, Xall, y, optim_update=f"dense_{optim}",
+                 reg_param=1e-3)
+    sparse = _fit(session, Xall, y, optim_update=f"sparse_{optim}",
+                  reg_param=1e-3)
+    assert _emb_diff(sparse, dense) < 1e-6
 
 
 def test_sparse_ftrl_matches_dense_ftrl(session, data):
@@ -147,15 +119,6 @@ def test_sparse_ftrl_matches_dense_ftrl(session, data):
     # l1 shrinkage really produces exact zeros on rarely-hit rows
     emb = np.asarray(sparse.theta["emb"])
     assert (emb == 0.0).any()
-
-
-def test_sort_and_plan_lowerings_agree(session, data):
-    Xall, y = data
-    a = _fit(session, Xall, y, optim_update="sparse_adagrad",
-             sparse_lowering="plan", reg_param=1e-3)
-    b = _fit(session, Xall, y, optim_update="sparse_adagrad",
-             sparse_lowering="sort", reg_param=1e-3)
-    assert _emb_diff(a, b) < 1e-7
 
 
 def test_sparse_learns_like_dense(session, data):
@@ -352,7 +315,7 @@ def test_blocked_update_equals_unblocked_bitwise(monkeypatch, kind,
     kw = dict(use_decay=use_decay, n_valid=jnp.int32(n_valid),
               raw_cats=jnp.asarray(raw), vals=vals)
     got = jax.jit(lambda *a: sparse_embedding_update(
-        kind, *a, lowering="sort", **kw))(*args)
+        kind, *a, **kw))(*args)
     want = jax.jit(lambda *a: _unblocked_sort_update(kind, *a, **kw))(*args)
     assert int(got[3]) == -(-n_live // _BLOCK)
     assert int(got[3]) <= slot_blocks(N, C, D) == -(-plan_slots(N, C, D)
@@ -428,9 +391,52 @@ def test_key_half_and_gradient_half_equal_the_whole_bitwise(case):
     assert int(want[2]) == len(set(live.ravel().tolist()))
 
 
+@pytest.mark.parametrize("case", list(_KEY_CASES))
+def test_sort_keys_invariants(case):
+    """``sort_keys`` against a numpy oracle of the same keys: the order is
+    the STABLE sort's (occurrences of one row keep their original order —
+    the exactness contract), dead occurrences come last, segment ids are
+    dense and non-decreasing, ``uniq`` is strictly increasing over
+    ``[0, n_live)`` and -1 after, and ``n_live`` is the number of distinct
+    live keys."""
+    D, n_valid, draw, vw = _KEY_CASES[case]
+    N, C = 12, 4
+    rng = np.random.default_rng(zlib.crc32(f"inv/{case}".encode()))
+    idx = {"random": lambda: rng.integers(0, D, (N, C)),
+           "distinct": lambda: rng.permutation(D)[:N * C].reshape(N, C),
+           "equal": lambda: np.full((N, C), 5)}[draw]().astype(np.int32)
+    dead = np.broadcast_to(np.arange(N)[:, None] >= n_valid, (N, C)).copy()
+    raw = None
+    if vw:
+        raw = rng.integers(0, 1000, (N, C)).astype(np.float32)
+        raw[rng.permutation(N)[:5], rng.integers(0, C, 5)] = -1.0
+        dead |= raw < 0
+    n_slots = sparse_mod.sort_slots(N, C, D)
+    keys = jax.device_get(jax.jit(lambda idx, nv: sparse_mod.sort_keys(
+        idx, D, n_slots, nv, None if raw is None else jnp.asarray(raw)))(
+        jnp.asarray(idx), jnp.int32(n_valid)))
+    order, seg, uniq, n_live = (keys[n] for n in
+                                ("order", "seg", "uniq", "n_live"))
+    flat = np.where(dead, D, idx).reshape(-1)
+    np.testing.assert_array_equal(order, np.argsort(flat, kind="stable"))
+    s_idx = flat[order]
+    n_dead = int(dead.sum())
+    assert (s_idx[len(s_idx) - n_dead:] == D).all()         # dead ones last
+    assert (s_idx[:len(s_idx) - n_dead] < D).all()
+    # one segment a distinct key, numbered in sorted order from 0
+    assert seg[0] == 0 and set(np.diff(seg).tolist()) <= {0, 1}
+    np.testing.assert_array_equal(np.diff(seg) == 1, s_idx[1:] != s_idx[:-1])
+    for k in range(seg[-1] + 1):          # stable inside every segment
+        assert (np.diff(order[seg == k]) > 0).all()
+    live = np.unique(idx[~dead])
+    assert n_live == len(live)
+    np.testing.assert_array_equal(uniq[:n_live], live)   # strictly increasing
+    assert (uniq[n_live:] == -1).all()
+
+
 def test_slot_blocks_are_counted_per_fit(session, data):
     """The fit reads the device-side trip count once at its end into the
-    registry: run <= possible = steps x slot_blocks, and a 'plan' fit
+    registry: run <= possible = steps x slot_blocks, and a dense twin
     (no loop) counts nothing."""
     Xall, y = data
     c = REGISTRY.get("otpu_sparse_slot_blocks_total")
@@ -444,8 +450,7 @@ def test_slot_blocks_are_counted_per_fit(session, data):
     assert possible == m.n_steps_ * per_step
     # these chunks' slot bound is under one SLOT_BLOCK: one trip a step
     assert per_step == 1 and run == m.n_steps_
-    _fit(session, Xall, y, optim_update="sparse_adagrad",
-         sparse_lowering="plan", reg_param=1e-3)
+    _fit(session, Xall, y, optim_update="dense_adagrad", reg_param=1e-3)
     assert c.value(which="possible") - before[1] == possible
 
 
@@ -585,12 +590,11 @@ def test_hoisted_keys_are_gated_by_the_cache_budget(session, data):
     assert _emb_diff(epoch, roomy) == 0.0
     grouped, _, run = fit(replay_granularity="epoch", epochs_per_dispatch=2)
     assert run == 4 * (1 + 2) and _emb_diff(grouped, roomy) == 0.0
-    # no replay, no hoist; 'plan' builds no sort keys at all
+    # no replay, no hoist; a dense twin builds no sort keys at all
     _, _, run = fit(fused_replay=False)
     assert run == 16
     before = sorts.value(which="steps")
-    _fit(session, Xall, y, optim_update="sparse_adagrad",
-         sparse_lowering="plan", reg_param=1e-3)
+    _fit(session, Xall, y, optim_update="dense_adagrad", reg_param=1e-3)
     assert sorts.value(which="steps") == before
 
 
@@ -633,8 +637,7 @@ def test_warm_replay_compiles_the_replay_the_fit_dispatches(session, data,
 
 def test_fused_epoch_spill_replay_parity(session, tmp_path, data):
     """The acceptance triple: fused('all') vs epoch-granular vs disk-spill
-    replay under sparse_adagrad must produce the same table (the plan
-    rides the HBM cache AND the spill records)."""
+    replay under sparse_adagrad must produce the same table."""
     Xall, y = data
     kw = dict(optim_update="sparse_adagrad", reg_param=1e-3, epochs=4)
     fused = _fit(session, Xall, y, **kw)
@@ -649,15 +652,17 @@ def test_fused_epoch_spill_replay_parity(session, tmp_path, data):
     assert st_sp["replay_source"] == "disk"
     assert _emb_diff(epoch, fused) == 0.0
     assert _emb_diff(spill, fused) < 5e-9   # different program, same math
-    # grouped disk-scan replay (fused_replay=True over the spill): the
-    # plan stacks ride the grouped records too
+    # grouped disk-scan replay (fused_replay=True over the spill): 16
+    # chunks of 12,288 B overflow the budget, which holds 2 records a
+    # group (a group takes a quarter of it)
+    small = _fit(session, Xall, y, **kw, chunk_rows=256)
     st_gr: dict = {}
-    grouped = _fit(session, Xall, y, **kw,
-                   cache_device_bytes=300_000,  # chunks+plans overflow this
+    grouped = _fit(session, Xall, y, **kw, chunk_rows=256,
+                   cache_device_bytes=120_000,
                    cache_spill_dir=str(tmp_path / "g"), stage_times=st_gr)
-    assert st_gr["replay_source"] == "disk"
-    assert st_gr.get("disk_replay_group", 1) >= 1
-    assert _emb_diff(grouped, fused) < 5e-9
+    assert st_gr["replay_source"] == "disk" and st_gr["cache_overflow"]
+    assert st_gr["disk_replay_group"] == 2
+    assert _emb_diff(grouped, small) < 5e-9
 
 
 def test_checkpoint_resume_sparse_state(session, tmp_path, data,
@@ -681,10 +686,23 @@ def test_checkpoint_resume_sparse_state(session, tmp_path, data,
     assert resumed.n_steps_ == ref.n_steps_
 
 
-def test_checkpoint_without_block_counter_resumes(session, tmp_path, data,
-                                                  make_killing_checkpointer):
-    """A snapshot written before opt_state carried the 'sort' lowering's
-    block counter (no 'blocks' key) must still resume, to the same fit."""
+def _as_before_block_counter(blob):
+    assert blob["state"]["opt_state"].pop("blocks") > 0
+
+
+def _as_before_pr30(blob):
+    assert "emb_update" not in blob["meta"]["params"]
+    blob["meta"]["params"]["emb_update"] = "auto"
+
+
+@pytest.mark.parametrize("older", [_as_before_block_counter, _as_before_pr30],
+                         ids=["no-block-counter", "retired-emb_update"])
+def test_older_snapshot_resumes(session, tmp_path, data,
+                                make_killing_checkpointer, older):
+    """A snapshot as an earlier program wrote it — before opt_state
+    carried the block counter (no 'blocks' key), or with the retired
+    ``emb_update`` parameter in its saved meta (utils/fault.RETIRED_PARAMS)
+    — must still resume, to the same fit bit for bit."""
     import pickle
 
     from orange3_spark_tpu.utils.fault import StreamCheckpointer
@@ -699,7 +717,7 @@ def test_checkpoint_without_block_counter_resumes(session, tmp_path, data,
         _fit(session, Xall, y, **kw, checkpointer=killer)
     with open(path, "rb") as f:
         blob = pickle.load(f)
-    assert blob["state"]["opt_state"].pop("blocks") > 0
+    older(blob)
     with open(path, "wb") as f:
         pickle.dump(blob, f)
     resumed = _fit(session, Xall, y, **kw,
@@ -707,6 +725,13 @@ def test_checkpoint_without_block_counter_resumes(session, tmp_path, data,
     np.testing.assert_array_equal(np.asarray(resumed.theta["emb"]),
                                   np.asarray(ref.theta["emb"]))
     assert resumed.n_steps_ == ref.n_steps_
+    # a parameter that still exists and differs is still refused
+    killer = make_killing_checkpointer(path, every_steps=4, die_after=2)
+    with pytest.raises(RuntimeError, match="injected fault"):
+        _fit(session, Xall, y, **kw, checkpointer=killer)
+    with pytest.raises(ValueError, match="different configuration"):
+        _fit(session, Xall, y, **{**kw, "step_size": 0.06},
+             checkpointer=StreamCheckpointer(path, every_steps=4))
 
 
 # --------------------------------------------------- serving + sharding
@@ -728,9 +753,9 @@ def test_model_sharded_table_sparse_parity(session, data, monkeypatch,
                                            lowering):
     """The sharded-table oracle: a (4 data x 2 model) mesh fit under
     sparse updates matches the replicated fit — GSPMD lowers the gathers/
-    segment scatter/writeback against the P('model', None) table. 'sort'
-    (what a TPU resolves 'auto' to) takes its block loop several trips a
-    step here, the trip count a replicated scalar."""
+    segment scatter/writeback against the P('model', None) table. The
+    'sort' case takes its block loop several trips a step, the trip count
+    a replicated scalar; 'auto' the one trip of the default block."""
     from jax.sharding import Mesh
 
     from orange3_spark_tpu.core.session import TpuSession
@@ -757,31 +782,14 @@ def test_model_sharded_table_sparse_parity(session, data, monkeypatch,
         assert trips >= 2 * m_sh.n_steps_
 
 
-# ------------------------------------------------ kill-switch + compiles
-
-def test_kill_switch_resolves_to_dense_twin(session, data, monkeypatch):
-    Xall, y = data
-    monkeypatch.setenv("OTPU_SPARSE_UPDATE", "0")
-    assert resolve_optim_update("sparse_adagrad") == "dense_adagrad"
-    st: dict = {}
-    m_killed = _fit(session, Xall, y, optim_update="sparse_adagrad",
-                    reg_param=1e-3, stage_times=st)
-    assert st["optim_update"] == "dense_adagrad"
-    assert st["sparse_lowering"] == "none"
-    monkeypatch.delenv("OTPU_SPARSE_UPDATE")
-    m_dense = _fit(session, Xall, y, optim_update="dense_adagrad",
-                   reg_param=1e-3)
-    assert _emb_diff(m_killed, m_dense) == 0.0
-
+# ------------------------------------------------------------- compiles
 
 def test_sparse_step_compiles_once_per_bucket_and_rule(session, data,
-                                                      xla_compiles,
-                                                      monkeypatch):
+                                                      xla_compiles):
     """Recompile-regression guard: one compile set per (chunk bucket,
-    optim_update); repeats hit the jit cache, and flipping the
-    OTPU_SPARSE_UPDATE kill-switch mid-process selects a DIFFERENT static
-    (new programs) without poisoning the cache key space — flipping back
-    costs zero compiles."""
+    optim_update); repeats hit the jit cache, and the dense twin is a
+    DIFFERENT static (new programs) that leaves the sparse programs cached
+    — going back costs zero compiles."""
     Xall, y = data
     kw = dict(optim_update="sparse_adagrad", reg_param=1e-3, epochs=3)
     _fit(session, Xall, y, **kw)
@@ -795,27 +803,48 @@ def test_sparse_step_compiles_once_per_bucket_and_rule(session, data,
     assert per_bucket > 0
     _fit(session, Xall, y, **kw, chunk_rows=512)
     assert xla_compiles() == base + per_bucket
-    # kill-switch flip: resolves to the dense twin -> new statics compile
-    monkeypatch.setenv("OTPU_SPARSE_UPDATE", "0")
-    _fit(session, Xall, y, **kw)
+    # the dense twin: new statics compile
+    dense = {**kw, "optim_update": "dense_adagrad"}
+    _fit(session, Xall, y, **dense)
     flipped = xla_compiles()
     assert flipped > base + per_bucket
-    # flip BACK: the sparse programs are still cached — zero new compiles
-    monkeypatch.delenv("OTPU_SPARSE_UPDATE")
+    # BACK: the sparse programs are still cached — zero new compiles
     _fit(session, Xall, y, **kw)
     assert xla_compiles() == flipped
     # and the dense twin is cached too
-    monkeypatch.setenv("OTPU_SPARSE_UPDATE", "0")
-    _fit(session, Xall, y, **kw)
+    _fit(session, Xall, y, **dense)
     assert xla_compiles() == flipped
 
 
-def test_auto_lowering_resolves_per_backend():
-    assert resolve_sparse_lowering("plan") == "plan"
-    assert resolve_sparse_lowering("sort") == "sort"
-    # CPU test mesh: auto must be the host-presorted plan
-    assert resolve_sparse_lowering("auto") == "plan"
+def test_auto_lowering_resolves_per_backend(session, monkeypatch):
+    """The resolved statics do not depend on the backend: 'auto' is 'sort'
+    whatever ``jax.default_backend()`` says, and nothing else in a fit's
+    static key moves with it."""
+    from orange3_spark_tpu.models.hashed_linear import _init_fit_state
+
+    p = StreamingHashedLinearEstimator(
+        **BASE, optim_update="sparse_adagrad", cache_dtype="auto").params
+    statics = {}
+    for backend in ("cpu", "tpu", "gpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert resolve_sparse_lowering("auto") == "sort"
+        assert resolve_sparse_lowering("sort") == "sort"
+        statics[backend] = _init_fit_state(p, session)[4]
+    assert statics["cpu"] == statics["tpu"] == statics["gpu"]
+    assert statics["cpu"]["sparse_lowering"] == "sort"
+    assert resolve_optim_update("sparse_adagrad") == "sparse_adagrad"
     with pytest.raises(ValueError, match="sparse_lowering"):
         resolve_sparse_lowering("bogus")
     with pytest.raises(ValueError, match="optim_update"):
         resolve_optim_update("sparse_adam")
+
+
+def test_plan_lowering_is_refused(session, data):
+    """The host-presorted lowering is gone: its name is refused, by the
+    resolver and by a fit that asks for it, with a message that says so."""
+    with pytest.raises(ValueError, match=r"removed in PR 30.*'sort'"):
+        resolve_sparse_lowering("plan")
+    Xall, y = data
+    with pytest.raises(ValueError, match="removed in PR 30"):
+        _fit(session, Xall, y, optim_update="sparse_adagrad",
+             sparse_lowering="plan")

@@ -51,29 +51,24 @@ def data_dir(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def fits(data_dir):
-    """lowering -> (job, record of one job on the (2,2) mesh, its table on
-    the host). 'sort' is what a TPU resolves 'auto' to, 'plan' the CPU."""
-    out = {}
-    for lowering in ("plan", "sort"):
-        job = make_job(data_dir, sparse_lowering=lowering)
-        assert dict(job.part.mesh.shape) == {"data": 2, "model": 2}
-        record = job.run()
-        specs = job.model.table_specs_
-        job.take_last()
-        out[lowering] = (job, record, job.last_emb, specs)
-    return out
+    """(job, record of one job on the (2,2) mesh, its table on the host,
+    where the tables stood) — the configuration as the cell runs it."""
+    job = make_job(data_dir)
+    assert dict(job.part.mesh.shape) == {"data": 2, "model": 2}
+    record = job.run()
+    specs = job.model.table_specs_
+    job.take_last()
+    return job, record, job.last_emb, specs
 
 
 @pytest.fixture(scope="module")
 def reference(fits):
-    return fits["plan"][0].reference_for_check()
+    return fits[0].reference_for_check()
 
 
-@pytest.mark.parametrize("lowering", ["plan", "sort"])
-def test_fit_on_2x2_matches_the_sharded_reference(fits, reference,
-                                                  lowering):
-    job, record, emb, _ = fits[lowering]
-    assert record["resolved"]["sparse_lowering"] == lowering
+def test_fit_on_2x2_matches_the_sharded_reference(fits, reference):
+    job, record, emb, _ = fits
+    assert record["resolved"]["sparse_lowering"] == "sort"
     assert record["resolved"]["replay_source"] == "fused"
     numbers = job.compare([record["answer"]], reference, emb)
     assert set(numbers) == {"final_loss", "dense_leaf", "emb_slice",
@@ -82,12 +77,11 @@ def test_fit_on_2x2_matches_the_sharded_reference(fits, reference,
     assert record["answer"]["n_steps"] == 48
 
 
-@pytest.mark.parametrize("lowering", ["plan", "sort"])
-def test_fit_on_2x2_matches_the_one_device_fit(fits, data_dir, lowering):
+def test_fit_on_2x2_matches_the_one_device_fit(fits, data_dir):
     """The same chunks through the same entry points on ONE device (the
     one-chip cells' job kind, a one-device session)."""
-    _, record, emb, _ = fits[lowering]
-    one = make_job(data_dir, kind="fit_stream", sparse_lowering=lowering)
+    _, record, emb, _ = fits
+    one = make_job(data_dir, kind="fit_stream")
     with TpuSession(TpuSession.default_mesh(jax.devices()[:1])).use():
         alone = one.run()
     assert one.model.theta["emb"].sharding.device_set == {jax.devices()[0]}
@@ -105,13 +99,13 @@ def test_fit_on_2x2_matches_the_one_device_fit(fits, data_dir, lowering):
 # ------------------------------------------------------------- placement
 @pytest.fixture(scope="module")
 def stepped(fits):
-    """The fit's own state after two 'sort' steps on the (2,2) session."""
+    """The fit's own state after two steps on the (2,2) session."""
     from orange3_spark_tpu.models.hashed_linear import (
         StreamingHashedLinearEstimator, _encode_chunk_np, _hashed_step,
         _init_fit_state, _put_encoded,
     )
 
-    job = fits["sort"][0]
+    job = fits[0]
     session = job.part.session
     p = StreamingHashedLinearEstimator(epochs=1, **job.est_kw).params
     theta, opt, salts_np, salts, kw = _init_fit_state(p, session)
@@ -140,7 +134,7 @@ def test_table_holds_half_its_rows_per_model_shard(stepped, fits, name):
     p, session, tables, _ = stepped
     table = tables[name]
     assert table.sharding.spec[0] == "model"
-    assert fits["sort"][3][name].startswith("PartitionSpec('model'")
+    assert fits[3][name].startswith("PartitionSpec('model'")
     shards = {s.device: s for s in table.addressable_shards}
     assert len(shards) == 4
     mesh = session.mesh.devices                  # [data, model]
@@ -228,7 +222,7 @@ def test_fit_says_what_mesh_it_runs_on(fits):
         return [e for e in trace.events() if e[0] == "i" and e[1] == "mesh"]
 
     n = len(mesh_events())
-    fits["plan"][0].run()
+    fits[0].run()
     events = mesh_events()
     assert len(events) == n + 1
     args = events[-1][5]
@@ -242,9 +236,9 @@ def test_fit_says_what_mesh_it_runs_on(fits):
 
 
 def test_obs_off_changes_no_answer(fits, data_dir, monkeypatch):
-    _, record, emb, _ = fits["plan"]
+    _, record, emb, _ = fits
     monkeypatch.setenv("OTPU_OBS", "0")
-    job = make_job(data_dir, sparse_lowering="plan")
+    job = make_job(data_dir)
     off = job.run()
     job.take_last()
     monkeypatch.delenv("OTPU_OBS")
@@ -264,7 +258,7 @@ def test_sharded_reference_equals_the_one_device_reference(fits, reference):
     the bit on XLA:CPU."""
     from benchmark.reference import hashed_linear
 
-    job = fits["plan"][0]
+    job = fits[0]
     alone = hashed_linear.fit(
         (job._chunk, job.n_chunks), n_dims=job.n_dims,
         n_dense=job.est_kw["n_dense"], epochs=job.epochs,

@@ -1,8 +1,8 @@
 """Fused-replay fault mechanism experiment: HLO-dump comparison.
 
-tools/replay_fault_diag.py's one recorded run (BENCH_HW_r4.jsonl,
-2026-07-31, before PR 1): the giant fused-replay scan died UNAVAILABLE
-whenever ANY program executed before it in the same process, while the
+The fault diagnosis tool's one recorded run (BENCH_HW_r4.jsonl,
+2026-07-31, before PR 1; the tool went in PR 30): the giant
+fused-replay scan died UNAVAILABLE whenever ANY program executed before it in the same process, while the
 identical Python call ran clean standalone — and n_epochs=1 scans were
 immune in every order. That run could NOT say *why*: does the poisoned
 process compile a *different* XLA program (program-content hypothesis:
@@ -81,7 +81,6 @@ def make_est(e):
     return StreamingHashedLinearEstimator(
         n_dims=1 << 22, n_dense=13, n_cat=26, epochs=e,
         chunk_rows=chunk_rows, label_in_chunk=True, prefetch_depth=2,
-        emb_update="sorted",
     )
 
 for stage in stages:
