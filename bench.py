@@ -71,6 +71,23 @@ N_DENSE = 13
 N_CAT = 26
 N_DIMS = 1 << 22     # 5.2M distinct codes: 2^20 would alias ~5 codes/bucket
 CHUNK_ROWS = 1 << 18
+
+
+def chunk_rows_for(n_rows: int) -> int:
+    """CHUNK_ROWS — or, for a dataset smaller than one chunk (the contract
+    tests' 30,000 rows), the power of two that holds it. A chunk is padded
+    to its full length and the step sorts a padded occurrence like a real
+    one: three sorts of rows x 26 pairs a streamed step, ~2 s each on
+    XLA:CPU at 2^18 rows, where the gathers they replaced were nearly free
+    there (the reverse of the chip: a known CPU loss, PERF.md §6 PR 31).
+    The smaller chunk runs the same program: 30,000 rows still pad (to
+    32,768), so ``n_valid`` masks dead rows behind the sentinel; the slot
+    bound is rows x 26 + 1 as ever; and the block loop takes the one trip
+    it took at 2^18 (780,000 occurrences are under one SLOT_BLOCK either
+    way). What it no longer shows is a static bound of 7 blocks with 1
+    live: tests/test_sparse_optim.py (an injected SLOT_BLOCK) and the AOT
+    compiles of tests/test_tpu_compile.py at 2^18 rows hold that."""
+    return min(CHUNK_ROWS, 1 << max(n_rows - 1, 1).bit_length())
 # 100 dataset passes = MLlib LogisticRegression's default maxIter (its
 # L-BFGS scans the cached RDD once per iteration — the convention this
 # metric quotes). Quality is epoch-flat once converged (measured 16 vs 48
@@ -180,6 +197,7 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
     )
 
     path = ensure_criteo_csv(n_rows)
+    chunk_rows = chunk_rows_for(n_rows)
 
     # persistent compilation cache BEFORE the first jit: the warm phase's
     # scan/eval compiles load from disk on every run after the first
@@ -240,7 +258,7 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
         return StreamingHashedLinearEstimator(
             n_dims=dims, n_dense=N_DENSE, n_cat=N_CAT,
             epochs=e, step_size=step_size, reg_param=reg,
-            chunk_rows=CHUNK_ROWS,
+            chunk_rows=chunk_rows,
             label_in_chunk=True, prefetch_depth=2,
             fused_replay=fused_env, replay_granularity=granularity,
             epochs_per_dispatch=epochs_per_dispatch,
@@ -249,7 +267,7 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
             cache_dtype=cache_dtype,
         )
 
-    source = csv_raw_chunk_source(path, chunk_rows=CHUNK_ROWS)
+    source = csv_raw_chunk_source(path, chunk_rows=chunk_rows)
 
     # the many-epoch config is priced on FUSED replay (~30 ms/epoch device
     # time); if the chunk cache cannot hold the dataset (plus the transient
@@ -259,7 +277,7 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
     # than silently running a multi-hour bench. This check runs BEFORE any
     # warm-up so the warm_replay below never materializes a dataset-sized
     # stack the timed fit would not use (round-3 advisor finding).
-    n_chunks = -(-n_rows // session.pad_rows(CHUNK_ROWS))
+    n_chunks = -(-n_rows // session.pad_rows(chunk_rows))
     holdout_chunks = max(min(HOLDOUT_CHUNKS, n_chunks - 1), 0)
     cache_budget = cache_bytes
     # per-chunk cache bytes under the RESOLVED codec — one shared estimator
@@ -444,7 +462,7 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
         chunks = model.device_chunks_[:4]
         probe_rows = float(np.mean([int(c[1]) for c in chunks]))
         salts = jnp.asarray(model.salts)
-        buf = np.empty((CHUNK_ROWS, 1 + N_DENSE + N_CAT), np.float32)
+        buf = np.empty((chunk_rows, 1 + N_DENSE + N_CAT), np.float32)
         t0 = time.perf_counter()
         jax.block_until_ready(jax.device_put(buf))
         h2d_blocked_gbps = round(
@@ -690,7 +708,7 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
     steps_per_epoch = model.n_steps_ // max(epochs, 1)
     if device_epoch and steps_per_epoch:
         step_s = device_epoch / steps_per_epoch
-        step_bytes = CHUNK_ROWS * (41 * 4 + 26 * 12) + 6 * dims * 4
+        step_bytes = chunk_rows * (41 * 4 + 26 * 12) + 6 * dims * 4
         hbm_gbps = round(step_bytes / step_s / 1e9, 1)
     return {
         "metric": "criteo_hashed_logreg_rows_per_sec_per_chip",
@@ -756,7 +774,7 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
             round(_raw_ratio_est, 3) if _raw_ratio_est else None),
         "cache_rows_capacity": (
             int(cache_budget * stage_times["cache_chunks"]
-                * session.pad_rows(CHUNK_ROWS)
+                * session.pad_rows(chunk_rows)
                 // stage_times["cache_bytes"])
             if stage_times.get("cache_bytes") else None),
         "pure_step_ms_f32cache": pure_step_ms_f32cache,
@@ -903,14 +921,15 @@ def bench_serving(n_rows: int, *, dims: int = 1 << 18) -> dict:
     # (serving latency does not depend on fit quality)
     fit_chunks = 4
     def head_source():
-        it = csv_raw_chunk_source(path, chunk_rows=CHUNK_ROWS)()
+        it = csv_raw_chunk_source(path, chunk_rows=chunk_rows_for(n_rows))()
         for i, c in enumerate(it):
             if i >= fit_chunks:
                 break
             yield c
     est = StreamingHashedLinearEstimator(
         n_dims=dims, n_dense=N_DENSE, n_cat=N_CAT, epochs=1,
-        step_size=STEP_SIZE, chunk_rows=CHUNK_ROWS, label_in_chunk=True,
+        step_size=STEP_SIZE, chunk_rows=chunk_rows_for(n_rows),
+        label_in_chunk=True,
     )
     _log(f"[serving] fitting the CTR model on {fit_chunks} chunks ...")
     model = est.fit_stream(head_source, session=session)

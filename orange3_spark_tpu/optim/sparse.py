@@ -34,18 +34,27 @@ This module is the one home of that machinery:
   tolerance only from pow-vs-repeated-multiply rounding). FTRL carries
   its own L2 inside the closed-form weight recovery and ignores the
   decay path entirely.
-* **one dedup lowering, ``'sort'``** — everything in the step:
-  ``argsort`` + segment ids by ``cumsum`` of boundaries, then gather ->
+* **one dedup lowering, ``'sort'``** — everything in the step: a stable
+  key-value sort + segment ids by ``cumsum`` of boundaries, then gather ->
   rule -> sorted unique scatter over the LIVE prefix of the slots only,
   ``SLOT_BLOCK`` slots a loop trip. Nothing rides the chunk cache. What
-  the chip read at 2^29 rows and 6.8M occurrences a step (v5e, PERF.md
-  §5): the table-wide gathers were 0.42 s while they ran over the
-  6.8M-slot static bound, and cost per INDEX (~14 ns), not per distinct
-  row — hence the live prefix; the part of the dedup that reads the keys
-  alone (``sort_keys``: the sort, the take of the sorted keys, the
-  ``uniq`` scatter) was 0.10 s of the 0.39 s step that left. A cached
-  chunk's keys do not change between epochs, so the fused replay builds
-  that half once per chunk and dispatch and hands it to its steps
+  the chip read at 2^29 rows and M = 6.8M occurrences a step (v5e,
+  PERF.md §5, §6): the table-wide gathers were 0.42 s while they ran over
+  the 6.8M-slot static bound, and cost per INDEX (~14 ns), not per
+  distinct row — hence the live prefix. **A permutation of the M
+  occurrences is a sort's payload, never an M-index gather:** XLA's
+  gather costs 7 ns an index from a 1 MB source as from a 27 MB one
+  (49 ms for M), a sort of M ``(i32 key, 32-bit payload)`` pairs 12–13 ms
+  (PR 31). So the sorted keys are the key sort's own first output, and
+  the key half hands on ``inv`` — each occurrence's rank, one more sort —
+  so that the gradient half carries its M per-occurrence gradients to
+  sorted order as the payload of a sort keyed by ``inv`` (a multiclass
+  fit's ``k`` columns are ``k`` payloads of the one sort: 30 ms for three
+  where the take of ``[M, 3]`` rows read 84).
+  The part of the dedup that reads the keys alone (``sort_keys``: the two
+  sorts, segment ids, the ``uniq`` scatter) does not change between the
+  epochs of a cached chunk, so the fused replay builds that half once per
+  chunk and dispatch and hands it to its steps
   (``keys=``): ``sort_keys_bytes`` a chunk of temp in that one program,
   for as long as it runs, taken only where the caller's cache budget
   holds it (``models/hashed_linear._hoist_sort_keys``). ``_hashed_step``
@@ -300,11 +309,15 @@ def sort_keys(idx, n_dims: int, n_slots: int, n_valid, raw_cats=None):
     """The key half of the 'sort' lowering's in-jit dedup — everything
     that reads the chunk's hashed keys and ``n_valid`` and nothing else:
     sort the occurrences (dead ones behind the sentinel ``n_dims``) and
-    number the segments in sorted order. Returns ``{'order': i32[M] the
-    stable sort's permutation, 'seg': i32[M] each sorted occurrence's
-    segment, 'uniq': i32[n_slots] table row per segment (-1 on
-    dead/unused slots), 'n_live': i32[]}``: the dead sentinel sorts last,
-    so the live slots are exactly the prefix ``[0, n_live)`` of ``uniq``.
+    number the segments in sorted order. Returns ``{'inv': i32[M] each
+    occurrence's rank in the stable sort (the inverse of its
+    permutation), 'seg': i32[M] each sorted occurrence's segment,
+    'uniq': i32[n_slots] table row per segment (-1 on dead/unused slots),
+    'n_live': i32[]}``: the dead sentinel sorts last, so the live slots
+    are exactly the prefix ``[0, n_live)`` of ``uniq``. The sorted keys
+    are the sort's own first output, and ``inv`` is one more sort (of the
+    permutation, carrying an iota): on the chip a sort moves M values for
+    a quarter of what an M-index gather costs (module docstring).
     A cached chunk's keys do not change between epochs, so the fused
     replay builds this once per chunk and dispatch (``sort_keys_bytes``
     is what it then holds) and every step reuses it."""
@@ -312,8 +325,10 @@ def sort_keys(idx, n_dims: int, n_slots: int, n_valid, raw_cats=None):
     with jax.named_scope("step/sort"):
         dead = occurrence_dead(N, C, n_valid, raw_cats)
         flat = jnp.where(dead, jnp.int32(n_dims), idx).reshape(-1)
-        order = jnp.argsort(flat)                         # stable sort
-        s_idx = jnp.take(flat, order)
+        iota = jnp.arange(N * C, dtype=jnp.int32)
+        s_idx, order = jax.lax.sort_key_val(flat, iota)   # stable sort
+        # a permutation's keys are unique: nothing rests on stability
+        _, inv = jax.lax.sort_key_val(order, iota, is_stable=False)
     with jax.named_scope("step/segment"):
         start = jnp.concatenate(
             [jnp.ones((1,), bool), s_idx[1:] != s_idx[:-1]])
@@ -326,16 +341,27 @@ def sort_keys(idx, n_dims: int, n_slots: int, n_valid, raw_cats=None):
         ].set(s_idx.astype(jnp.int32), mode="drop")
         # every segment but the dead one
         n_live = seg[-1] + 1 - (s_idx[-1] >= n_dims).astype(jnp.int32)
-    return {"order": order, "seg": seg, "uniq": uniq, "n_live": n_live}
+    return {"inv": inv, "seg": seg, "uniq": uniq, "n_live": n_live}
 
 
 def _sorted_sums(dl, vals, keys: dict, n_cat: int):
     """The gradient half: the per-occurrence gradients ``dl[row] (* val)``
-    taken in ``sort_keys``' order and summed per segment."""
+    carried to ``sort_keys``' order and summed per segment. ``inv`` is a
+    permutation of ``0..M-1``, so a sort keyed by it is that permutation
+    applied to its payloads, one per logit column: ``g[j]`` is the value
+    of the occurrence the stable sort put in place ``j``, what
+    ``jnp.take(dl, order // n_cat)`` gave, bit for bit."""
     with jax.named_scope("step/segment"):
-        g = jnp.take(dl, keys["order"] // n_cat, axis=0)
+        n, k = dl.shape
+        # the occurrences in their ORIGINAL order need no index: row i's
+        # gradient stands at [i, :, j]
+        g = jnp.broadcast_to(dl[:, None, :], (n, n_cat, k))
         if vals is not None:
-            g = g * jnp.take(vals.reshape(-1), keys["order"])[:, None]
+            g = g * vals[:, :, None]
+        _, *cols = jax.lax.sort(
+            (keys["inv"], *(g[:, :, j].reshape(-1) for j in range(k))),
+            num_keys=1, is_stable=False)
+        g = jnp.stack(cols, axis=1)
         return _segment_sums(g, keys["seg"], keys["uniq"].shape[0])
 
 
@@ -361,7 +387,7 @@ def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
     """One touched-row-only table update. ``dl`` is the [N, k] logits
     gradient; per-occurrence gradients are ``dl[row] (* val)``.
 
-    The dedup is derived in-jit (argsort + cumsum-of-boundaries) — or,
+    The dedup is derived in-jit (key-value sort + cumsum-of-boundaries) — or,
     where the caller has already run ``sort_keys`` over this chunk (the
     fused replay, once per chunk and dispatch), handed in as ``keys`` and
     only the gradient half computed here: the same operations on the same

@@ -12,6 +12,7 @@ replaces N per-step multiplies by one pow of the same factor, so the
 decay'd comparisons carry a slightly looser tolerance."""
 
 import os
+import re
 import zlib
 
 import numpy as np
@@ -343,16 +344,19 @@ _KEY_CASES = {
 }
 
 
+@pytest.mark.parametrize("k", [1, 3], ids=["k=1", "k=3"])
 @pytest.mark.parametrize("case", list(_KEY_CASES))
-def test_key_half_and_gradient_half_equal_the_whole_bitwise(case):
+def test_key_half_and_gradient_half_equal_the_whole_bitwise(case, k):
     """``sort_keys`` (keys and n_valid only) then ``_sorted_sums``
-    (gradients, by the keys' order) is the dedup that used to be one
-    function: sums, uniq and n_live equal it bit for bit, computed in one
-    program or the key half in a program of its own (as the fused replay
-    computes it, ahead of its scan)."""
+    (gradients, carried to the keys' order as the k payloads of one sort)
+    is the dedup that used to be one function of gathers (``jnp.take(dl, order // C)``, kept in
+    ``_sorted_slots_whole`` as the oracle): sums, uniq and n_live equal
+    it bit for bit, with the keys built in the step or handed in (as the
+    fused replay builds them, in a program part of their own ahead of its
+    scan)."""
     D, n_valid, draw, vw = _KEY_CASES[case]
-    N, C, k = 12, 4, 2
-    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    N, C = 12, 4
+    rng = np.random.default_rng(zlib.crc32(f"{case}/{k}".encode()))
     idx = {"random": lambda: rng.integers(0, D, (N, C)),
            "distinct": lambda: rng.permutation(D)[:N * C].reshape(N, C),
            "equal": lambda: np.full((N, C), 5)}[draw]().astype(np.int32)
@@ -378,10 +382,8 @@ def test_key_half_and_gradient_half_equal_the_whole_bitwise(case):
     keys = jax.jit(lambda idx, nv: sparse_mod.sort_keys(
         idx, D, n_slots, nv, raw))(idx, nv)
     assert {n: (v.shape, v.dtype.name) for n, v in keys.items()} == {
-        "order": ((N * C,), "int32"), "seg": ((N * C,), "int32"),
+        "inv": ((N * C,), "int32"), "seg": ((N * C,), "int32"),
         "uniq": ((n_slots,), "int32"), "n_live": ((), "int32")}
-    assert sparse_mod.sort_keys_bytes(N, C, D) == sum(
-        v.nbytes for n, v in keys.items() if n != "n_live")
     for got in (jax.jit(halves)(dl, idx, nv),
                 jax.jit(halves)(dl, idx, nv, keys)):
         for a, b in zip(got, want):
@@ -393,12 +395,13 @@ def test_key_half_and_gradient_half_equal_the_whole_bitwise(case):
 
 @pytest.mark.parametrize("case", list(_KEY_CASES))
 def test_sort_keys_invariants(case):
-    """``sort_keys`` against a numpy oracle of the same keys: the order is
-    the STABLE sort's (occurrences of one row keep their original order —
-    the exactness contract), dead occurrences come last, segment ids are
-    dense and non-decreasing, ``uniq`` is strictly increasing over
-    ``[0, n_live)`` and -1 after, and ``n_live`` is the number of distinct
-    live keys."""
+    """``sort_keys`` against a numpy oracle of the same keys: ``inv`` is
+    each occurrence's rank in the STABLE sort (occurrences of one row keep
+    their original order — the exactness contract), dead occurrences come
+    last, segment ids are dense and non-decreasing, ``uniq`` is strictly
+    increasing over ``[0, n_live)`` and -1 after, ``n_live`` is the number
+    of distinct live keys, and ``sort_keys_bytes`` is what the dict's
+    arrays hold."""
     D, n_valid, draw, vw = _KEY_CASES[case]
     N, C = 12, 4
     rng = np.random.default_rng(zlib.crc32(f"inv/{case}".encode()))
@@ -415,10 +418,14 @@ def test_sort_keys_invariants(case):
     keys = jax.device_get(jax.jit(lambda idx, nv: sparse_mod.sort_keys(
         idx, D, n_slots, nv, None if raw is None else jnp.asarray(raw)))(
         jnp.asarray(idx), jnp.int32(n_valid)))
-    order, seg, uniq, n_live = (keys[n] for n in
-                                ("order", "seg", "uniq", "n_live"))
+    assert set(keys) == {"inv", "seg", "uniq", "n_live"}
+    assert sparse_mod.sort_keys_bytes(N, C, D) == sum(
+        v.nbytes for n, v in keys.items() if n != "n_live")
+    inv, seg, uniq, n_live = (keys[n] for n in
+                              ("inv", "seg", "uniq", "n_live"))
     flat = np.where(dead, D, idx).reshape(-1)
-    np.testing.assert_array_equal(order, np.argsort(flat, kind="stable"))
+    order = np.argsort(flat, kind="stable")
+    np.testing.assert_array_equal(inv, np.argsort(order))
     s_idx = flat[order]
     n_dead = int(dead.sum())
     assert (s_idx[len(s_idx) - n_dead:] == D).all()         # dead ones last
@@ -452,6 +459,28 @@ def test_slot_blocks_are_counted_per_fit(session, data):
     assert per_step == 1 and run == m.n_steps_
     _fit(session, Xall, y, optim_update="dense_adagrad", reg_param=1e-3)
     assert c.value(which="possible") - before[1] == possible
+
+
+@pytest.mark.parametrize("vw", [False, True], ids=["plain", "valued"])
+@pytest.mark.parametrize("k", [1, 3], ids=["k=1", "k=3"])
+def test_gradient_half_permutes_by_one_sort_and_no_gather(k, vw):
+    """The program itself, at every ``k``: ``_sorted_sums`` carries its
+    ``[M, k]`` per-occurrence gradients to sorted order as the ``k``
+    payloads of ONE sort keyed by ``inv`` — no gather in it, value-weighted
+    or not (``_segment_sums`` scatters)."""
+    N, C, D = 64, 4, 97
+    n_slots = N * C + 1
+    keys = {"inv": jnp.zeros(N * C, jnp.int32),
+            "seg": jnp.zeros(N * C, jnp.int32),
+            "uniq": jnp.zeros(n_slots, jnp.int32),
+            "n_live": jnp.int32(0)}
+    vals = jnp.ones((N, C), jnp.float32) if vw else None
+    text = jax.jit(lambda dl, vals, keys: sparse_mod._sorted_sums(
+        dl, vals, keys, C)).lower(
+        jnp.zeros((N, k), jnp.float32), vals, keys).compile().as_text()
+    sorts = re.findall(r"= \(?([^=]*?)\)? sort\(", text)
+    assert len(sorts) == 1 and sorts[0].count(f"[{N * C}]") == 1 + k, sorts
+    assert " gather(" not in text
 
 
 # ------------------------------------------- the replay's hoisted sort keys

@@ -31,6 +31,7 @@ HBM_BYTES = 16 << 30            # one v5e chip
 ROWS, N_DIMS = 1 << 18, 1 << 22  # chip_smoke's fit: 262,144-row chunks, 2^22
 BENCH_DIMS = 1 << 29             # the benchmark's Criteo table (PERF.md §4)
 MESH_DIMS = 1 << 30              # ... and the one a (2,2) mesh holds
+M = ROWS * 26                    # a chunk's occurrences (26 categoricals)
 HIST_REAL = (1 << 20, 28, 3, 16, 32)   # (N, d, s, nodes, bins): HIGGS level
 
 
@@ -213,6 +214,15 @@ def _at_dims(hashed, n_dims: int):
             salts_np, chunk, {**kw, "n_dims": n_dims, "codec": codec})
 
 
+def _no_occurrence_gather(text: str):
+    """A permutation of a chunk's M occurrences is a sort's payload, never
+    an M-index gather (optim/sparse.py; on the chip 12 ms against 49): the
+    program's gathers are the forward's ([rows, 26] out of the table) and
+    the block loop's three and no other — none has an [M]-long result."""
+    assert " gather(" in text
+    assert not re.search(rf"= \w+\[{M}[,\]][^ ]* gather\(", text)
+
+
 def _tables_stay_in_place(compiled, n_dims: int):
     """The sparse step's three tables (weight, Adagrad accumulator,
     last-seen step) are donated, carried through the block loop of
@@ -222,6 +232,7 @@ def _tables_stay_in_place(compiled, n_dims: int):
     less temp than ONE table."""
     text = compiled.as_text()
     assert " while(" in text                   # the block loop is there
+    _no_occurrence_gather(text)
     assert not re.search(rf"= \w+\[{n_dims}[,\]][^ ]* copy\(", text)
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 3 * 4 * n_dims
@@ -278,13 +289,16 @@ def test_hashed_replay_epochs_compiles(one_chip, hashed, n_dims, hoist):
     (``hoist_keys``, what a fit whose cache budget holds them runs) — and
     as a fit with a tight budget runs it, the sort inside each step.
 
-    By hand at 2^29 (PR 29, this sandbox; PR 27 read temp 332,963,840 for
-    the sort-in-step form, which still reads that): args 6,645,879,296 /
-    temp 1,055,901,696 / alias 6,442,454,016 bytes — 0.503 GB of it the
+    By hand at 2^29 (PR 31, this sandbox; PR 29 read temp 1,055,901,696
+    hoisted and 332,963,840 with the sort in the step): args 6,645,879,296
+    / temp 1,001,020,928 / alias 6,442,454,016 bytes — 0.503 GB of it the
     stacked keys (three i32 vectors a chunk, lane-tiled [6, M/128, 128]:
     stacked [6, M] the TPU's (8, 128) tiling pads 6 chunks to 8 and the
-    temp reads 1,169,083,392); two sorts in the program (the argsort and
-    the ``uniq`` scatter's own), both in the loop that builds the keys."""
+    temp read 1,169,083,392); four sorts in the program: the key sort,
+    the sort that inverts its permutation and the ``uniq`` scatter's own
+    in the loop that builds the keys, and ONE under the epoch scan — the
+    sort that carries a step's per-occurrence gradients to sorted order.
+    ``_hashed_step`` at 2^29: temp 303,973,376 (PR 29: 331,204,096)."""
     from orange3_spark_tpu.models.hashed_linear import _hashed_replay_epochs
 
     (theta, opt, X, nv, y, w, salts, reg, lr), kw = _step_args(
@@ -297,10 +311,11 @@ def test_hashed_replay_epochs_compiles(one_chip, hashed, n_dims, hoist):
     _tables_stay_in_place(compiled, n_dims)
     text = compiled.as_text()
     in_scan = _sorts_in_loops_over_tables(text, n_dims)
-    assert in_scan and text.count(" sort(") >= 2
-    # the epoch scan (and every loop under it) runs a sort only where the
-    # keys were not hoisted
-    assert all(n == 0 for n in in_scan) if hoist else max(in_scan) >= 2
+    assert in_scan and text.count(" sort(") >= 3
+    # a step under the epoch scan runs exactly one sort, the carrier,
+    # where the keys were hoisted; with the sort in the step it runs the
+    # key sort, its inverse's and the carrier (and the uniq scatter's own)
+    assert max(in_scan) == 1 if hoist else max(in_scan) >= 3
 
 
 def test_hashed_predict_compiles_at_bucket(one_chip, hashed):
@@ -360,9 +375,12 @@ def _tables_stay_sharded(compiled, n_dims: int):
     one: the program's arguments are 3 x 4 B x n_dims / 2 and a little, its
     temp is far under one half table, no operation has a result of the
     table's whole shape (an all-gather of one would be 4.3 GB at 2^30),
-    and the only all-gather is of a chunk's 6.8M occurrence indices over
-    `data`. The gathers and write-backs are per-shard masked lookups whose
-    partial rows an all-reduce over `model` adds up."""
+    and what is all-gathered over `data` is a chunk's 6.8M occurrence
+    indices (ahead of the key sort) and its 6.8M per-occurrence gradients
+    (27 MB, ahead of the sort that carries them to sorted order: a sort
+    along an axis wants it whole) and nothing else; no all-reduce is
+    M-long. The gathers and write-backs are per-shard masked lookups
+    whose partial rows an all-reduce over `model` adds up."""
     text = compiled.as_text()
     m = compiled.memory_analysis()
     half = 3 * 4 * n_dims // 2
@@ -372,9 +390,10 @@ def _tables_stay_sharded(compiled, n_dims: int):
     assert not re.search(rf"= \(?\w+\[{n_dims}[,\]]", text)
     gathered = re.findall(r"= (\w+\[[\d,]*\])\S* all-gather(?:-start)?\(",
                           text)
-    assert gathered and all(g == f"s32[{ROWS * 26}]" for g in gathered), \
-        gathered
+    assert gathered and set(gathered) <= {f"s32[{M}]", f"f32[{M}]"}, gathered
     assert " all-reduce(" in text and " while(" in text
+    assert not re.search(rf"\[{M}[,\]][^=]* all-reduce(?:-start)?\(", text)
+    _no_occurrence_gather(text)
 
 
 def test_hashed_step_compiles_model_sharded_at_2_30(topo, hashed):
@@ -411,7 +430,7 @@ def test_hashed_replay_epochs_compiles_model_sharded_at_2_30(topo, hashed):
     _fits(compiled)
     _tables_stay_sharded(compiled, MESH_DIMS)
     in_scan = _sorts_in_loops_over_tables(compiled.as_text(), MESH_DIMS // 2)
-    assert in_scan and all(n == 0 for n in in_scan)
+    assert in_scan and max(in_scan) == 1         # the carrier, a step
 
 
 # ------------------------------------------------------------------- kmeans
