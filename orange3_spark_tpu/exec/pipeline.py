@@ -1,7 +1,7 @@
 """PipelinedExecutor — the chunk pipeline's measured overlap engine.
 
 JAX dispatch is async, so a streaming fit gets double buffering "for free"
-only if the host work (parse, pad, ``device_put`` enqueue) for chunk t+1
+only if the host work (parse, encode, ``device_put`` enqueue) for chunk t+1
 actually runs while the device executes step t. This module makes that
 overlap a first-class, MEASURED property instead of a hoped-for one:
 
@@ -81,8 +81,10 @@ class PipelinedExecutor:
     """Bounded background-thread prefetch with measured overlap.
 
     ``prep(item)`` runs on the worker thread — for the streaming fits it is
-    parse+pad+``device_put``, so the DMA enqueue of chunk t+1 overlaps the
-    device step on chunk t. ``depth`` bounds how far the producer runs
+    encode (pad, label/dense narrowing, the categoricals' hash + bit-pack
+    under the cache codec) + ``device_put``, the parse being the pull of the
+    next item on the same thread — so chunk t+1 is ready and its DMA
+    enqueued while the device steps on chunk t. ``depth`` bounds how far the producer runs
     ahead (double buffering at the default 2); ``depth=0`` still prefetches
     with a queue of one.
 
@@ -153,7 +155,8 @@ class PipelinedExecutor:
         try:
             while True:
                 # time the PULL too: the upstream iterator is where the
-                # parse/rechunk work lives (prep is only pad+device_put),
+                # parse/rechunk work lives (prep is encode + h2d: pad, the
+                # cache codec's narrowing and hash + bit-pack, device_put),
                 # and both run on this thread — prep_s must carry the
                 # whole host-side cost or overlap_pct overstates waits
                 # (it is the sum of the "prefetch" spans, the pull that

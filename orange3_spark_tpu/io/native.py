@@ -8,6 +8,13 @@ Python-level per-cell work. The library is compiled on first use with g++
 (-O3 -pthread) and cached next to the source. ``read_csv_native`` falls back to the pyarrow
 reader (io/readers.py) when no toolchain is available; the chunked
 ``NativeCsvReader`` API raises ``NativeUnavailable`` explicitly.
+
+The same library holds the categorical half of a chunk's encode under the
+'packed' cache codec (``hash_pack_rows``: impute, bucket hash, bit-pack in
+one pass over the parsed rows); without the library it returns ``None`` and
+the caller runs the numpy pair it is held bit-identical to. A process tries
+the build ONCE: a failure is remembered, so the prefetch thread that asks
+per chunk does not run g++ per chunk.
 """
 
 from __future__ import annotations
@@ -25,6 +32,7 @@ _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 _LIB = os.path.join(os.path.dirname(_SRC), "_fastcsv.so")
 _lock = threading.Lock()
 _lib = None
+_lib_error: str | None = None  # why this process has no library (tried once)
 
 
 class NativeUnavailable(RuntimeError):
@@ -91,41 +99,108 @@ def tune_malloc() -> None:
 
 
 def get_lib():
-    """Load (building if stale) the fastcsv shared library."""
-    global _lib
+    """Load (building if stale) the fastcsv shared library. One attempt a
+    process: a failed build or load raises ``NativeUnavailable`` now and on
+    every later call, without running the compiler again."""
+    global _lib, _lib_error
     with _lock:
         if _lib is not None:
             return _lib
-        if (not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-            _build()
-        lib = ctypes.CDLL(_LIB)
-        lib.fcsv_open.restype = ctypes.c_void_p
-        lib.fcsv_open.argtypes = [ctypes.c_char_p, ctypes.c_char, ctypes.c_int]
-        lib.fcsv_ncols.restype = ctypes.c_int
-        lib.fcsv_ncols.argtypes = [ctypes.c_void_p]
-        lib.fcsv_colname.restype = ctypes.c_char_p
-        lib.fcsv_colname.argtypes = [ctypes.c_void_p, ctypes.c_int]
-        lib.fcsv_read_chunk.restype = ctypes.c_long
-        lib.fcsv_read_chunk.argtypes = [
-            ctypes.c_void_p,
-            ctypes.POINTER(ctypes.c_float),
-            ctypes.c_long,
-            ctypes.c_int,
-        ]
-        lib.fcsv_close.restype = None
-        lib.fcsv_close.argtypes = [ctypes.c_void_p]
-        lib.fcsv_set_categorical.restype = ctypes.c_int
-        lib.fcsv_set_categorical.argtypes = [
-            ctypes.c_void_p, ctypes.c_int, ctypes.c_int,
-        ]
-        lib.fcsv_write.restype = ctypes.c_int
-        lib.fcsv_write.argtypes = [
-            ctypes.c_char_p, ctypes.POINTER(ctypes.c_float), ctypes.c_long,
-            ctypes.c_int, ctypes.c_char_p, ctypes.c_char,
-        ]
-        _lib = lib
+        if _lib_error is not None:
+            raise NativeUnavailable(_lib_error)
+        try:
+            _lib = _load()
+        except NativeUnavailable as e:
+            _lib_error = str(e)
+            raise
         return _lib
+
+
+def _load():
+    if (not os.path.exists(_LIB)
+            or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
+        _build()
+    try:
+        lib = ctypes.CDLL(_LIB)
+        _declare(lib)
+    except (OSError, AttributeError) as e:
+        # unloadable, or a build of an older source that lacks a symbol
+        raise NativeUnavailable(f"fastcsv load failed: {e}") from e
+    return lib
+
+
+def _declare(lib) -> None:
+    lib.fcsv_open.restype = ctypes.c_void_p
+    lib.fcsv_open.argtypes = [ctypes.c_char_p, ctypes.c_char, ctypes.c_int]
+    lib.fcsv_ncols.restype = ctypes.c_int
+    lib.fcsv_ncols.argtypes = [ctypes.c_void_p]
+    lib.fcsv_colname.restype = ctypes.c_char_p
+    lib.fcsv_colname.argtypes = [ctypes.c_void_p, ctypes.c_int]
+    lib.fcsv_read_chunk.restype = ctypes.c_long
+    lib.fcsv_read_chunk.argtypes = [
+        ctypes.c_void_p,
+        ctypes.POINTER(ctypes.c_float),
+        ctypes.c_long,
+        ctypes.c_int,
+    ]
+    lib.fcsv_close.restype = None
+    lib.fcsv_close.argtypes = [ctypes.c_void_p]
+    lib.fcsv_set_categorical.restype = ctypes.c_int
+    lib.fcsv_set_categorical.argtypes = [
+        ctypes.c_void_p, ctypes.c_int, ctypes.c_int,
+    ]
+    lib.fcsv_write.restype = ctypes.c_int
+    lib.fcsv_write.argtypes = [
+        ctypes.c_char_p, ctypes.POINTER(ctypes.c_float), ctypes.c_long,
+        ctypes.c_int, ctypes.c_char_p, ctypes.c_char,
+    ]
+    lib.fcsv_hash_pack_rows.restype = ctypes.c_int
+    lib.fcsv_hash_pack_rows.argtypes = [
+        ctypes.POINTER(ctypes.c_float), ctypes.c_long, ctypes.c_long,
+        ctypes.c_int, ctypes.POINTER(ctypes.c_uint32), ctypes.c_uint32,
+        ctypes.c_int, ctypes.c_int, ctypes.POINTER(ctypes.c_uint32),
+        ctypes.c_int,
+    ]
+
+
+def hash_pack_rows(cats: np.ndarray, salts: np.ndarray, n_dims: int,
+                   bits: int, *, impute: bool) -> np.ndarray | None:
+    """``pack_rows_np(hash_columns_np(cats, salts, n_dims), bits)`` — after
+    ``NaN -> 0`` under ``impute`` — in ONE native pass over the rows of
+    ``cats``: ``[N, C]`` f32 codes (any row stride: a column slice of the
+    parsed chunk is read where it lies) -> ``[N, ceil(C*bits/32)]`` u32,
+    the same bits as the numpy pair. ctypes releases the GIL for the call.
+
+    Returns ``None`` where the native pass cannot run — no library, or a
+    block that is not float32 rows with unit column stride — and the caller
+    runs the numpy pair."""
+    if n_dims & (n_dims - 1):
+        raise ValueError(f"n_dims must be a power of two, got {n_dims}")
+    if not 1 <= bits <= 31:
+        raise ValueError(f"pack bit width must be in [1, 31], got {bits}")
+    if (not isinstance(cats, np.ndarray) or cats.dtype != np.float32
+            or cats.ndim != 2 or cats.strides[1] != 4
+            or cats.strides[0] % 4 or cats.strides[0] < 0):
+        return None
+    try:
+        lib = get_lib()
+    except NativeUnavailable:
+        return None
+    n_rows, n_cat = cats.shape
+    salts = np.ascontiguousarray(
+        np.broadcast_to(np.asarray(salts, np.uint32), (n_cat,)))
+    words = np.empty((n_rows, -(-(n_cat * bits) // 32)), np.uint32)
+    u32_p = ctypes.POINTER(ctypes.c_uint32)
+    rc = lib.fcsv_hash_pack_rows(
+        cats.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+        cats.strides[0] // 4, n_rows, n_cat, salts.ctypes.data_as(u32_p),
+        n_dims - 1, bits, int(impute), words.ctypes.data_as(u32_p),
+        words.shape[1],
+    )
+    if rc != 0:
+        raise ValueError(
+            f"fcsv_hash_pack_rows refused [{n_rows}, {n_cat}] at {bits} bits")
+    return words
 
 
 class NativeCsvReader:

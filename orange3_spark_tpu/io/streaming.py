@@ -363,8 +363,9 @@ def prefetch_map(fn: Callable, items: Iterator, *, depth: int = 2,
     order through a bounded queue.
 
     This is the chunk pipeline's overlap engine: with
-    ``fn = parse+pad+device_put`` the host prepares (and DMAs) chunk t+1
-    while the device runs step t. The native parser and ``device_put`` both
+    ``fn = encode + device_put`` (pad, the cache codec's narrowing, hash and
+    bit-pack; the parse is the pull of ``items`` on the same thread) the
+    host prepares (and DMAs) chunk t+1 while the device runs step t. The native parser and ``device_put`` both
     release the GIL, so the worker genuinely overlaps the main thread's
     dispatch work even on a single-core host (the transfer's wait-on-DMA
     time is free CPU for the parser). Worker exceptions re-raise at the

@@ -62,6 +62,7 @@ from orange3_spark_tpu.io.codec import (
     BF16, bit_width, pack_rows_np, resolve_cache_dtype, unpack_rows,
 )
 from orange3_spark_tpu.io.multihost import put_sharded
+from orange3_spark_tpu.io.native import hash_pack_rows
 from orange3_spark_tpu.models._linear import EPS_TOTAL_WEIGHT, per_row_loss
 from orange3_spark_tpu.models.base import Estimator, Model, Params
 from orange3_spark_tpu.ops.hashing import (
@@ -74,6 +75,7 @@ from orange3_spark_tpu.optim.sparse import (
     sort_keys, sort_keys_bytes, sort_slots, sparse_embedding_update,
 )
 from orange3_spark_tpu.obs import prof
+from orange3_spark_tpu.obs.registry import REGISTRY
 from orange3_spark_tpu.obs.report import RunReport
 from orange3_spark_tpu.obs.trace import span, span_iter, stage, traced
 from orange3_spark_tpu.obs.trace import refreshed_enabled as obs_enabled
@@ -86,6 +88,14 @@ _ADAM_UNIT = optax.adam(1.0)
 
 #: per-process ledger-entry numbering for hashed fits (obs/prof.py)
 _FIT_LEDGER_SEQ = itertools.count()
+
+_M_ENCODE_CHUNKS = REGISTRY.counter(
+    "otpu_encode_chunks_total",
+    "chunks encoded under the 'packed' cache codec, by what hashed and "
+    "bit-packed their categoricals: how=native (one pass of the fastcsv "
+    "library over the parsed rows) | numpy (the library did not build or "
+    "load here: hash_columns_np + pack_rows_np, the same bytes, ~5x the "
+    "host seconds)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -827,10 +837,19 @@ def _encode_chunk_np(codec: _ChunkCodec, Xp: np.ndarray,
             Xp[:, off:off + codec.n_dense]).astype(BF16)
     cats = Xp[:, off + codec.n_dense:]
     if codec.mode == "packed":
-        if codec.impute:
-            cats = np.where(np.isnan(cats), np.float32(0.0), cats)
-        enc["cats"] = pack_rows_np(
-            hash_columns_np(cats, salts_np, codec.n_dims), codec.idx_bits)
+        # impute + bucket hash + bit-pack: one native pass over the rows
+        # where the parser left them; without the library, the numpy pair
+        # it is held bit-identical to (tests/test_cache_codec.py)
+        words = hash_pack_rows(cats, salts_np, codec.n_dims, codec.idx_bits,
+                               impute=codec.impute)
+        _M_ENCODE_CHUNKS.inc(how="numpy" if words is None else "native")
+        if words is None:
+            if codec.impute:
+                cats = np.where(np.isnan(cats), np.float32(0.0), cats)
+            words = pack_rows_np(
+                hash_columns_np(cats, salts_np, codec.n_dims),
+                codec.idx_bits)
+        enc["cats"] = words
     else:
         enc["cats"] = np.ascontiguousarray(cats, np.float32)
     return enc

@@ -393,6 +393,55 @@ void parse_rows(const char* buf, const std::vector<size_t>& starts,
   }
 }
 
+// ------------------------------------------------- hash + bit-pack (encode)
+// The categorical half of a chunk's encode under the 'packed' cache codec
+// (models/hashed_linear.py _encode_chunk_np), one pass over n_rows rows:
+// read the row's n_cat f32 codes where the parser left them (row stride in
+// floats: the block is a column slice of the parsed chunk, never copied
+// contiguous), NaN -> 0 under impute, the murmur3-finaliser bucket hash of
+// ops/hashing.py hash_columns_np, then io/codec.py pack_rows_np's layout:
+// value c at bit c*bits of the row's little-endian word string, spilling
+// into the next word. Bit-identical to that numpy pair, which stays the
+// fallback and the oracle (tests/test_cache_codec.py).
+void hash_pack_rows(const float* cats, size_t row_stride, size_t n_rows,
+                    int n_cat, const uint32_t* salts, uint32_t mask, int bits,
+                    bool impute, uint32_t* out, int n_words) {
+  std::vector<uint32_t> hbuf(n_cat);
+  uint32_t* h = hbuf.data();
+  for (size_t r = 0; r < n_rows; ++r) {
+    const float* row = cats + r * row_stride;
+    for (int c = 0; c < n_cat; ++c) {
+      const float f = row[c];
+      // numpy's f32 -> i32 cast on this platform, spelled out: NaN and
+      // values outside int32 give 0x80000000 (x86's "integer indefinite");
+      // the plain C cast of either is undefined behaviour
+      const bool in_range = f >= -2147483648.0f && f < 2147483648.0f;
+      int32_t i = static_cast<int32_t>(in_range ? f : 0.0f);
+      if (!in_range) i = (impute && f != f) ? 0 : INT32_MIN;
+      uint32_t x = static_cast<uint32_t>(i) ^ salts[c];
+      x ^= x >> 16;
+      x *= 0x85EBCA6Bu;
+      x ^= x >> 13;
+      x *= 0xC2B2AE35u;
+      x ^= x >> 16;
+      h[c] = x & mask;
+    }
+    uint32_t* words = out + r * (size_t)n_words;
+    uint64_t acc = 0;  // bits < 32 pending + one value of <= 31 bits: < 64
+    int pending = 0, w = 0;
+    for (int c = 0; c < n_cat; ++c) {
+      acc |= (uint64_t)h[c] << pending;
+      pending += bits;
+      if (pending >= 32) {
+        words[w++] = (uint32_t)acc;
+        acc >>= 32;
+        pending -= 32;
+      }
+    }
+    if (pending) words[w] = (uint32_t)acc;
+  }
+}
+
 }  // namespace
 
 extern "C" {
@@ -621,6 +670,24 @@ int fcsv_write(const char* path, const float* data, long nrows, int ncols,
   bool fail = ok != buf.size();
   if (std::fclose(f) != 0) fail = true;
   return fail ? -1 : 0;
+}
+
+// Hash and bit-pack rows [0, n_rows) of an f32 categorical block into
+// out[n_rows, n_words] u32 (see hash_pack_rows above). cats points at the
+// first categorical column of row 0; row_stride is the distance between
+// rows in floats. mask is n_dims - 1; values are also masked to `bits`
+// bits, as pack_rows_np masks them. Runs on the caller's thread. Returns 0,
+// or -1 on arguments that do not describe a packing.
+int fcsv_hash_pack_rows(const float* cats, long row_stride, long n_rows,
+                        int n_cat, const uint32_t* salts, uint32_t mask,
+                        int bits, int impute, uint32_t* out, int n_words) {
+  if (n_rows < 0 || n_cat < 0 || bits < 1 || bits > 31 || row_stride < 0
+      || (long)n_words != ((long)n_cat * bits + 31) / 32)
+    return -1;
+  mask &= (1u << bits) - 1u;
+  hash_pack_rows(cats, (size_t)row_stride, (size_t)n_rows, n_cat, salts, mask,
+                 bits, impute != 0, out, n_words);
+  return 0;
 }
 
 }  // extern "C"

@@ -55,12 +55,15 @@ def hash_columns(cats, salts, n_dims: int):
 
 def hash_columns_np(cats: np.ndarray, salts: np.ndarray,
                     n_dims: int) -> np.ndarray:
-    """Host twin of ``hash_columns`` — BIT-IDENTICAL buckets, needed by the
-    sparse-optimizer plan builder (optim/sparse.py) which pre-sorts a
-    chunk's touched rows on the prefetch thread. Any drift between the two
-    would silently update the wrong table rows, so tests/test_sparse_optim
-    pins equality over random codes including negatives and the f32
-    carrier dtype."""
+    """Host twin of ``hash_columns`` — BIT-IDENTICAL buckets. The packed
+    cache codec hashes a chunk's categoricals on the prefetch thread and
+    the step only unpacks them, so any drift between host and device
+    buckets would silently update the wrong table rows. The encode's one
+    native pass (io/native.py ``hash_pack_rows``) does this arithmetic per
+    value; this function is its fallback on a host without the library and
+    the oracle it is held to (tests/test_cache_codec.py), as
+    tests/test_sparse_optim pins this one to ``hash_columns`` over random
+    codes including negatives and the f32 carrier dtype."""
     if n_dims & (n_dims - 1):
         raise ValueError(f"n_dims must be a power of two, got {n_dims}")
     u = np.asarray(cats).astype(np.int32).astype(np.uint32)

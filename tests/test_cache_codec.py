@@ -3,7 +3,9 @@ primitive roundtrips, LOSSLESS packed-replay bitwise parity vs the f32
 cache, the bf16 divergence bound, the OTPU_CACHE_DTYPE kill-switch
 (bitwise legacy + zero new compiles), capacity/fusion-gate economics, the
 versioned spill format (old flat-f32 files stay readable), spill-file
-hygiene on aborted fits, and the _DeviceCache degrade un-latch."""
+hygiene on aborted fits, the _DeviceCache degrade un-latch, and the
+encode's one native hash + bit-pack pass held bitwise to the numpy pair
+(with its fallback when the library cannot be had)."""
 
 import gc
 import os
@@ -14,6 +16,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from orange3_spark_tpu.io import native
 from orange3_spark_tpu.io.codec import (
     BF16, bit_width, force_cache_dtype, pack_rows_np, resolve_cache_dtype,
     unpack_rows,
@@ -23,8 +26,12 @@ from orange3_spark_tpu.io.streaming import (
     array_chunk_source,
 )
 from orange3_spark_tpu.models.hashed_linear import (
-    StreamingHashedLinearEstimator, estimate_cached_chunk_bytes,
-    resolve_chunk_codec,
+    StreamingHashedLinearEstimator, _encode_chunk_np,
+    estimate_cached_chunk_bytes, resolve_chunk_codec,
+)
+from orange3_spark_tpu.obs.registry import REGISTRY
+from orange3_spark_tpu.ops.hashing import (
+    column_salts, hash_columns, hash_columns_np,
 )
 
 from tests.test_hashed_linear import _criteo_shaped
@@ -94,6 +101,187 @@ def test_resolver_gates():
         p, label_in_chunk=True, n_classes=300)).label_u8
     assert not resolve_chunk_codec(dataclasses.replace(
         p, label_in_chunk=True, loss="squared")).label_u8
+
+
+# ------------------------- the encode's native pass against the numpy pair
+
+ENCODE_COUNTER = "otpu_encode_chunks_total"
+
+
+@pytest.fixture(scope="module")
+def fastcsv():
+    try:
+        return native.get_lib()
+    except native.NativeUnavailable as e:
+        pytest.skip(f"no native library here: {e}")
+
+
+def _cat_block(n, n_cat, strided, seed):
+    """[n, n_cat] f32 codes as the parser leaves them — 24-bit codes, with
+    zeros, negatives and NaN cells among them — contiguous, or the
+    categorical columns of a 40-wide parsed chunk (row stride 40)."""
+    rng = np.random.default_rng(seed)
+    cats = rng.integers(0, 1 << 24, (n, n_cat)).astype(np.float32)
+    cats[rng.random(cats.shape) < 0.1] = 0.0
+    cats[rng.random(cats.shape) < 0.1] *= -1.0
+    cats[rng.random(cats.shape) < 0.1] = np.nan
+    if not strided:
+        return cats
+    chunk = rng.standard_normal((n, 40)).astype(np.float32)
+    chunk[:, 40 - n_cat:] = cats
+    view = chunk[:, 40 - n_cat:]
+    assert view.strides == (160, 4) and not (n > 1 and view.flags.c_contiguous)
+    return view
+
+
+def _numpy_pair(cats, salts, n_dims, bits, impute):
+    if impute:
+        cats = np.where(np.isnan(cats), np.float32(0.0), cats)
+    with np.errstate(invalid="ignore"):     # NaN -> int32 under 'keep'
+        return pack_rows_np(hash_columns_np(cats, salts, n_dims), bits)
+
+
+@pytest.mark.parametrize("impute", [True, False], ids=["impute", "keep"])
+@pytest.mark.parametrize("strided", [False, True],
+                         ids=["contiguous", "stride40"])
+@pytest.mark.parametrize("n_cat", [1, 26, 27])
+@pytest.mark.parametrize("bits", [1, 7, 24, 29, 30, 31])
+def test_native_hash_pack_is_the_numpy_pair_bitwise(fastcsv, bits, n_cat,
+                                                    strided, impute):
+    """Every row count (one row, an odd count, a rehearsal chunk): the same
+    u32 words as ``pack_rows_np(hash_columns_np(...))``, word for word."""
+    n_dims = 1 << bits
+    salts = column_salts(n_cat, seed=bits)
+    for n in (1, 1000, 32768):
+        cats = _cat_block(n, n_cat, strided, seed=n + n_cat)
+        want = _numpy_pair(cats, salts, n_dims, bits, impute)
+        got = native.hash_pack_rows(cats, salts, n_dims, bits, impute=impute)
+        assert got.dtype == np.uint32 and got.shape == want.shape
+        np.testing.assert_array_equal(got, want, err_msg=f"{n=}")
+
+
+@pytest.mark.parametrize("n_dims", [1 << 29, 1 << 30])
+def test_native_words_unpack_to_the_device_buckets(fastcsv, n_dims):
+    """What the step reads: ``unpack_rows`` of the native words is
+    ``hash_columns`` of the imputed codes, the in-jit hash."""
+    bits = bit_width(n_dims)
+    cats = _cat_block(1000, 26, strided=True, seed=3)
+    salts = column_salts(26, seed=0)
+    words = native.hash_pack_rows(cats, salts, n_dims, bits, impute=True)
+    assert words.shape == (1000, -(-(26 * bits) // 32))
+    imputed = jnp.where(jnp.isnan(cats), 0.0, jnp.asarray(cats))
+    np.testing.assert_array_equal(
+        np.asarray(unpack_rows(jnp.asarray(words), bits, 26)),
+        np.asarray(hash_columns(imputed, salts, n_dims)))
+
+
+def test_native_hash_pack_refuses_what_numpy_refuses(fastcsv):
+    cats = _cat_block(8, 3, strided=False, seed=1)
+    salts = column_salts(3)
+    with pytest.raises(ValueError, match="power of two"):
+        native.hash_pack_rows(cats, salts, 1000, 10, impute=True)
+    with pytest.raises(ValueError, match="bit width"):
+        native.hash_pack_rows(cats, salts, 1 << 10, 32, impute=True)
+    # a pack wider than the hash's mask, and a block the pass does not
+    # read in place (f64, or columns not adjacent): the numpy pair's
+    # bytes, or None for the caller to run it
+    np.testing.assert_array_equal(
+        native.hash_pack_rows(cats, salts, 1 << 10, 16, impute=True),
+        _numpy_pair(cats, salts, 1 << 10, 16, True))
+    assert native.hash_pack_rows(cats.astype(np.float64), salts, 1 << 10,
+                                 10, impute=True) is None
+    assert native.hash_pack_rows(cats[:, ::2], salts[::2], 1 << 10, 10,
+                                 impute=True) is None
+
+
+def _packed_codec(n_dims=1 << 29):
+    return resolve_chunk_codec(StreamingHashedLinearEstimator(
+        n_dims=n_dims, n_dense=13, n_cat=26, loss="squared_hinge",
+        label_in_chunk=True, cache_dtype="packed").params)
+
+
+def _parsed_chunk(n, seed):
+    rng = np.random.default_rng(seed)
+    chunk = np.empty((n, 40), np.float32)
+    chunk[:, 0] = rng.integers(0, 2, n)
+    chunk[:, 1:14] = rng.standard_normal((n, 13))
+    chunk[:, 14:] = _cat_block(n, 26, strided=False, seed=seed)
+    return chunk
+
+
+def test_failed_build_falls_back_to_numpy_once(monkeypatch, tmp_path):
+    """A host without a toolchain: the first chunk's encode tries the build,
+    every chunk gets the numpy pair's bytes, the counter says ``numpy``,
+    and g++ is not asked again per chunk."""
+    attempts = []
+
+    def no_toolchain():
+        attempts.append(1)
+        raise native.NativeUnavailable("fastcsv build failed: no g++")
+
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_lib_error", None)
+    monkeypatch.setattr(native, "_LIB", str(tmp_path / "_fastcsv.so"))
+    monkeypatch.setattr(native, "_build", no_toolchain)
+    REGISTRY.reset([ENCODE_COUNTER])
+    codec, salts = _packed_codec(), column_salts(26, seed=0)
+    assert codec.mode == "packed" and codec.impute
+    for i in range(5):
+        chunk = _parsed_chunk(512, seed=i)
+        enc = _encode_chunk_np(codec, chunk, salts)
+        np.testing.assert_array_equal(
+            enc["cats"],
+            _numpy_pair(chunk[:, 14:], salts, codec.n_dims, codec.idx_bits,
+                        True))
+    assert len(attempts) == 1
+    with pytest.raises(native.NativeUnavailable, match="no g[+][+]"):
+        native.get_lib()
+    assert len(attempts) == 1
+    counter = REGISTRY.get(ENCODE_COUNTER)
+    assert counter.value(how="numpy") == 5 and counter.value(how="native") == 0
+
+
+def test_encode_keeps_its_dict_and_counts_native(fastcsv):
+    """Same keys, dtypes and shapes as before the native pass; labels and
+    the dense block stay numpy; one count a chunk."""
+    REGISTRY.reset([ENCODE_COUNTER])
+    codec, salts = _packed_codec(1 << 30), column_salts(26, seed=0)
+    chunk = _parsed_chunk(512, seed=9)
+    enc = _encode_chunk_np(codec, chunk, salts)
+    assert {k: (v.dtype, v.shape) for k, v in enc.items()} == {
+        "y": (np.dtype(np.uint8), (512,)),
+        "dense": (np.dtype(BF16), (512, 13)),
+        "cats": (np.dtype(np.uint32), (512, 25))}
+    np.testing.assert_array_equal(
+        enc["cats"], _numpy_pair(chunk[:, 14:], salts, 1 << 30, 30, True))
+    counter = REGISTRY.get(ENCODE_COUNTER)
+    assert counter.value(how="native") == 1 and counter.value(how="numpy") == 0
+
+
+def test_fit_is_bitwise_the_same_native_or_numpy(fastcsv, session, data,
+                                                 monkeypatch):
+    """The same tiny stream fitted with the native pass and with the
+    library taken away: every leaf of ``theta`` equal to the bit."""
+    Xall, y = data
+    Xall = Xall.copy()
+    Xall[::7, 5] = np.nan               # an empty categorical cell
+    REGISTRY.reset([ENCODE_COUNTER])
+    counter = REGISTRY.get(ENCODE_COUNTER)
+    m_native = _fit(session, Xall, y, "packed")
+    chunks = counter.value(how="native")
+    assert chunks >= 4 and counter.value(how="numpy") == 0
+
+    def no_library():
+        raise native.NativeUnavailable("taken away")
+
+    monkeypatch.setattr(native, "get_lib", no_library)
+    m_numpy = _fit(session, Xall, y, "packed")
+    assert counter.value(how="numpy") == chunks
+    assert counter.value(how="native") == chunks
+    assert m_native.n_steps_ == m_numpy.n_steps_
+    for leaf in m_native.theta:
+        np.testing.assert_array_equal(np.asarray(m_native.theta[leaf]),
+                                      np.asarray(m_numpy.theta[leaf]), leaf)
 
 
 # ------------------------------------------------- parity vs the f32 cache
