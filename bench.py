@@ -3105,6 +3105,8 @@ def bench_taxi_pipeline(*, rows: int = 2_000_000, requests: int = 24,
     jax.block_until_ready(out_staged.X)
     wall_staged = time.perf_counter() - t0
 
+    # the eagerly fitted models: a staged refit puts its own on the ports
+    eager_models = [g.nodes[nid].outputs["model"] for nid in (sc, pca, km)]
     refit_staged = stage_graph(g, km, refit=True)
     jax.block_until_ready(refit_staged().X)
     t0 = time.perf_counter()
@@ -3115,8 +3117,8 @@ def bench_taxi_pipeline(*, rows: int = 2_000_000, requests: int = 24,
 
     def eager_transform():
         t = table
-        for nid in (sc, pca, km):
-            t = g.nodes[nid].outputs["model"].transform(t)
+        for model in eager_models:
+            t = model.transform(t)
         return t
 
     jax.block_until_ready(eager_transform().X)
@@ -3162,13 +3164,13 @@ def bench_taxi_pipeline(*, rows: int = 2_000_000, requests: int = 24,
     wall_fit_stream = time.perf_counter() - t0
     # semantics: the one-pass streaming moments must agree with the
     # in-memory scaler fit (same population-variance convention)
-    scaler_b = g.nodes[sc].outputs["model"]
+    scaler_b = eager_models[0]
     stream_scaler_diff = float(np.max(np.abs(
         np.asarray(scaler_b.shift) - sh)))
 
     # ---- whole-workflow serving A/B: fused DAG vs stage-by-stage ----
     _log("[taxi] workflow serving A/B (fused vs stage-by-stage) ...")
-    models = [g.nodes[nid].outputs["model"] for nid in (sc, pca, km)]
+    models = eager_models
     wf = ServedWorkflow.from_stages(models, table, name="taxi-dag")
     rng2 = np.random.default_rng(11)
     reqs = [

@@ -261,6 +261,8 @@ def bench_taxi_pipeline(scale: float) -> dict:
     # fit-in-trace: the whole pipeline INCLUDING the scaler/PCA/KMeans fits
     # as one XLA program (stage_graph refit=True) vs the eager widget walk
     # measured above as wall_fit_eager
+    # the eagerly fitted models: a staged refit puts its own on the ports
+    eager_models = [g.nodes[nid].outputs["model"] for nid in (sc, pca, km)]
     refit_staged = stage_graph(g, km, refit=True)
     jax.block_until_ready(refit_staged().X)  # compile + drain
     t0 = time.perf_counter()
@@ -271,8 +273,7 @@ def bench_taxi_pipeline(scale: float) -> dict:
 
     def eager_transform():
         t = table
-        for nid in (sc, pca, km):
-            model = g.nodes[nid].outputs["model"]
+        for model in eager_models:
             t = model.transform(t)
         return t
 

@@ -136,6 +136,11 @@ class Model(Transformer):
     """
 
     params: Params
+    #: device values a fit computed beside ``state_pytree`` and that no
+    #: transform needs (KMeans: cost, iterations run, cluster sizes, the
+    #: initial centres it drew); a staged refit hands them out of its one
+    #: program with the state (workflow/staging.py)
+    fit_summary: dict = {}
 
     def __getstate__(self):
         return jax.tree.map(
@@ -172,6 +177,14 @@ class Model(Transformer):
         for k, v in state.items():
             setattr(self, k, v)
         self._touch_serving_state()
+
+    def load_fit_summary(self, summary: dict[str, Any],
+                         fit: str = "eager") -> None:
+        """Take a fit's summary: from ``_fit`` itself (tracers under a
+        staged refit) and again, concrete and with ``fit='staged'``, when
+        a staged refit's states come back. Models with host-side mirrors
+        of it override this."""
+        self.fit_summary = summary
 
 
 class Estimator:

@@ -30,6 +30,14 @@ from orange3_spark_tpu.core.domain import DiscreteVariable, Domain
 from orange3_spark_tpu.core.table import TpuTable
 from orange3_spark_tpu.exec.donate import donating_jit
 from orange3_spark_tpu.models.base import concrete_or_none, Estimator, Model, Params
+from orange3_spark_tpu.obs.registry import REGISTRY
+from orange3_spark_tpu.ops.stats import rows_dot
+
+_M_ITERATIONS = REGISTRY.counter(
+    "otpu_kmeans_iterations_total",
+    "Lloyd iterations run by finished KMeans fits, by fit: eager (counted "
+    "at the end of the fit) | staged (a staged refit, workflow/staging.py: "
+    "counted when its states come back)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,9 +57,11 @@ def live_cluster_sizes(W, assign, num_segments: int):
     """MLlib ``summary.clusterSizes``: live ROW counts per cluster (Spark
     counts rows, not weights — W only gates padding/filtered membership).
     THE one implementation, shared by KMeans / BisectingKMeans / GMM."""
-    return jax.ops.segment_sum(
-        (W > 0).astype(jnp.float32), assign.astype(jnp.int32),
-        num_segments=num_segments)
+    # a compare-and-sum over [N, k], fused into one reduction: a
+    # segment_sum here is a scatter-add of N values, 1.2 s at 2^27 rows on
+    # a v5e where this pass reads its two inputs once (PERF.md, PR 35)
+    hit = (assign[:, None] == jnp.arange(num_segments)) & (W > 0)[:, None]
+    return jnp.sum(hit, axis=0, dtype=jnp.int32).astype(jnp.float32)
 
 
 @partial(jax.jit, static_argnames=("compute_dtype",))
@@ -59,7 +69,11 @@ def _assign(X, centers, w, compute_dtype=jnp.float32):
     """Nearest-center ids + weighted cost. Distances via the matmul identity."""
     Xc = X.astype(compute_dtype)
     Cc = centers.astype(compute_dtype)
-    cross = jnp.dot(Xc, Cc.T, preferred_element_type=jnp.float32)  # [N,k] on MXU
+    # [N,k] on the MXU. HIGHEST: the TPU's default rounds both operands of
+    # a float32 matmul to bfloat16, which is compute_dtype='bfloat16' under
+    # another name (bfloat16 operands pass through unchanged)
+    cross = jnp.dot(Xc, Cc.T, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
     x2 = jnp.sum(X * X, axis=1, keepdims=True)
     c2 = jnp.sum(centers * centers, axis=1)
     d2 = x2 - 2.0 * cross + c2
@@ -80,7 +94,8 @@ def _lloyd(X, w, centers0, tol, *, k: int, max_iter: int, compute_dtype=jnp.floa
         centers, _, it, _ = carry
         assign, cost = _assign(X, centers, w, compute_dtype)
         onehot = jax.nn.one_hot(assign, k, dtype=jnp.float32) * w[:, None]  # [N,k]
-        sums = onehot.T @ X          # [k,d] MXU matmul, all-reduced by GSPMD
+        # [k,d] MXU matmuls over row blocks, all-reduced by GSPMD
+        sums = rows_dot(onehot, X)
         counts = jnp.sum(onehot, axis=0)
         new_centers = jnp.where(
             counts[:, None] > 0, sums / jnp.maximum(counts, 1e-12)[:, None], centers
@@ -147,6 +162,17 @@ class KMeansModel(Model):
     def state_pytree(self):
         return {"centers": self.centers}
 
+    def load_fit_summary(self, summary, fit: str = "eager") -> None:
+        """``cost``, ``n_iter``, ``cluster_sizes``, ``init_centers`` as
+        the fit left them on the device; the host mirrors (and the
+        iteration counter) follow once they are concrete."""
+        self.fit_summary = summary
+        self.cluster_sizes_ = summary["cluster_sizes"]
+        self.n_iter_ = concrete_or_none(summary["n_iter"], int)
+        self.training_cost_ = concrete_or_none(summary["cost"])
+        if self.n_iter_ is not None:
+            _M_ITERATIONS.inc(self.n_iter_, fit=fit)
+
     @property
     def cluster_centers_(self) -> np.ndarray:
         return np.asarray(self.centers)
@@ -190,7 +216,11 @@ def device_sample_live(X, W, cap: int, key):
     N = X.shape[0]
     live = W > 0
     g = jnp.where(live, jax.random.gumbel(key, (N,)), -jnp.inf)
-    gv, idx = jax.lax.top_k(g, min(cap, N))
+    # approx_max_k: the winners of ~cap buckets of rows, each uniform over
+    # its bucket's live rows — a uniform sample all the same, where an
+    # exact top_k sorts all N (0.44 s at 2^27 rows on a v5e against 2 ms,
+    # and 34 s of compile; PERF.md, PR 35). Exact on the CPU
+    gv, idx = jax.lax.approx_max_k(g, min(cap, N))
     return X[idx], jnp.isfinite(gv).astype(jnp.float32)
 
 
@@ -308,29 +338,37 @@ class KMeans(Estimator):
                         compute_dtype=jnp.dtype(p.compute_dtype))
         tol = jnp.float32(p.tol)
         if p.n_init <= 1:
-            centers, assign, cost, n_iter = _lloyd(
-                table.X, table.W, self._init_centers(table), tol, **lloyd_kw)
+            with jax.named_scope("kmeans/init"):
+                init = self._init_centers(table)
+            with jax.named_scope("kmeans/lloyd"):
+                # _lloyd consumes its seed centres: the summary keeps a copy
+                centers, assign, cost, n_iter = _lloyd(
+                    table.X, table.W, jnp.copy(init), tol, **lloyd_kw)
         else:
             # all restarts advance in lockstep inside one vmapped while_loop —
             # n_init independent Lloyd runs for roughly the cost of one.
             # Donation under a vmap trace is a silent no-op, so call the
             # undonated twin rather than compile a donating executable
             # whose aliasing can never engage.
-            inits = jnp.stack([
-                self.replace_seed(s)._init_centers(table)
-                for s in range(p.seed, p.seed + p.n_init)
-            ])
-            centers_v, assign_v, cost_v, iter_v = jax.vmap(
-                lambda c0: _lloyd.plain(table.X, table.W, c0, tol, **lloyd_kw)
-            )(inits)
+            with jax.named_scope("kmeans/init"):
+                inits = jnp.stack([
+                    self.replace_seed(s)._init_centers(table)
+                    for s in range(p.seed, p.seed + p.n_init)
+                ])
+            with jax.named_scope("kmeans/lloyd"):
+                centers_v, assign_v, cost_v, iter_v = jax.vmap(
+                    lambda c0: _lloyd.plain(table.X, table.W, c0, tol,
+                                            **lloyd_kw)
+                )(inits)
             best = jnp.argmin(cost_v)
             centers, cost, n_iter = centers_v[best], cost_v[best], iter_v[best]
-            assign = assign_v[best]
+            assign, init = assign_v[best], inits[best]
         model = KMeansModel(p, centers)
-        model.n_iter_ = concrete_or_none(n_iter, int)
-        model.training_cost_ = concrete_or_none(cost)
-        # reuses the converged Lloyd assignment — no extra distance pass
-        model.cluster_sizes_ = live_cluster_sizes(table.W, assign, p.k)
+        model.load_fit_summary({
+            "cost": cost, "n_iter": n_iter, "init_centers": init,
+            # reuses the converged Lloyd assignment — no extra distance pass
+            "cluster_sizes": live_cluster_sizes(table.W, assign, p.k),
+        })
         return model
 
     def replace_seed(self, seed: int) -> "KMeans":

@@ -58,7 +58,10 @@ class PCAModel(Model):
     @staticmethod
     @jax.jit
     def _project(X, components, mean):
-        return (X - mean) @ components  # [N,d]@[d,k] on the MXU
+        # [N,d]@[d,k] on the MXU, float32 products (the TPU's default
+        # rounds the operands to bfloat16)
+        return jnp.dot(X - mean, components,
+                       precision=jax.lax.Precision.HIGHEST)
 
     def transform(self, table: TpuTable) -> TpuTable:
         Z = self._project(table.X, self.components, self.mean)
@@ -79,12 +82,15 @@ class PCA(Estimator):
         p = self.params
         if p.k > table.n_attrs:
             raise ValueError(f"k={p.k} exceeds n_features={table.n_attrs}")
-        G, mean, tot = distributed_gramian(table.X, table.W, center=p.center)
+        with jax.named_scope("pca/cov"):
+            G, mean, tot = distributed_gramian(table.X, table.W,
+                                               center=p.center)
         return self._finalize(G / tot, mean)
 
     def _finalize(self, cov, mean) -> PCAModel:
         p = self.params
-        eigvals, eigvecs = jnp.linalg.eigh(cov)   # ascending
+        with jax.named_scope("pca/eigh"):
+            eigvals, eigvecs = jnp.linalg.eigh(cov)   # ascending
         order = jnp.argsort(eigvals)[::-1][: p.k]
         components = eigvecs[:, order]
         explained = jnp.maximum(eigvals[order], 0.0)

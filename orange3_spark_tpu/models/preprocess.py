@@ -43,6 +43,15 @@ def _col_indices(table: TpuTable, input_cols: Sequence[str] | None) -> np.ndarra
     return np.asarray(idxs, dtype=np.int32)
 
 
+def _select_cols(table: TpuTable, idxs: np.ndarray):
+    """``table.X[:, idxs]``; every column in order is the table itself. A
+    take would copy it whole — on the TPU as thousands of gathers: 4.3 GB
+    of temp and minutes of compile at 2^27 x 8 (PERF.md, PR 35)."""
+    if np.array_equal(idxs, np.arange(table.X.shape[1])):
+        return table.X
+    return jnp.take(table.X, idxs, axis=1)
+
+
 def _scale_transform(X, idxs, shift, scale):
     """X'[:, idxs] = (X[:, idxs] - shift) * scale, fused as one scatter-free op."""
     full_shift = jnp.zeros((X.shape[1],), X.dtype).at[idxs].set(shift)
@@ -98,8 +107,9 @@ class StandardScaler(Estimator):
     def _fit(self, table: TpuTable) -> StandardScalerModel:
         p = self.params
         idxs = _col_indices(table, p.input_cols)
-        Xsel = jnp.take(table.X, idxs, axis=1)
-        mean, var, _ = weighted_moments(Xsel, table.W)
+        Xsel = _select_cols(table, idxs)
+        with jax.named_scope("scale/moments"):
+            mean, var, _ = weighted_moments(Xsel, table.W)
         return self._finalize(mean, var, jnp.asarray(idxs))
 
     def _finalize(self, mean, var, idxs) -> StandardScalerModel:
@@ -144,7 +154,7 @@ class MinMaxScaler(Estimator):
     def _fit(self, table: TpuTable) -> _ColumnScaleModel:
         p = self.params
         idxs = _col_indices(table, p.input_cols)
-        Xsel = jnp.take(table.X, idxs, axis=1)
+        Xsel = _select_cols(table, idxs)
         live = (table.W > 0)[:, None]
         big = jnp.float32(np.finfo(np.float32).max)
         mn = jnp.min(jnp.where(live, Xsel, big), axis=0)
@@ -201,7 +211,7 @@ class MaxAbsScaler(Estimator):
     def _fit(self, table: TpuTable) -> _ColumnScaleModel:
         p = self.params
         idxs = _col_indices(table, p.input_cols)
-        Xsel = jnp.take(table.X, idxs, axis=1)
+        Xsel = _select_cols(table, idxs)
         live = (table.W > 0)[:, None]
         mabs = jnp.max(jnp.where(live, jnp.abs(Xsel), 0.0), axis=0)
         scale = jnp.where(mabs > 1e-12, 1.0 / mabs, 1.0)
@@ -244,7 +254,7 @@ class Imputer(Estimator):
     def _fit(self, table: TpuTable) -> ImputerModel:
         p = self.params
         idxs = _col_indices(table, p.input_cols)
-        Xsel = jnp.take(table.X, idxs, axis=1)
+        Xsel = _select_cols(table, idxs)
         mv = p.missing_value
         miss = jnp.isnan(Xsel) if np.isnan(mv) else (Xsel == mv)
         w_eff = jnp.where(miss, 0.0, table.W[:, None])

@@ -64,14 +64,19 @@ def data_parallel_sum(values, session: TpuSession | None = None):
 
 @partial(jax.jit, static_argnames=("center",))
 def _gramian_kernel(X, W, center: bool):
-    from orange3_spark_tpu.ops.stats import weighted_moments
+    from orange3_spark_tpu.ops.stats import rows_dot, weighted_moments
 
     w = W[:, None]
     mean, _, tot = weighted_moments(X, W)
     Xc = X - mean if center else X  # center is trace-time static
     # (d,d) matmul contraction over the sharded row axis — GSPMD turns this
     # into local matmuls + one all-reduce over ICI (the treeAggregate moment).
-    G = (Xc * w).T @ Xc
+    # rows_dot, because one dot over 2^27 rows is not float32-exact on the
+    # TPU (ops/stats.py); ONE operand used twice, because a row block's
+    # product needs its operands as arrays and two [N, d] temporaries are
+    # one too many at that size
+    Y = Xc * jnp.sqrt(w)
+    G = rows_dot(Y, Y)
     return G, mean, tot
 
 

@@ -18,8 +18,17 @@ import dataclasses
 import json
 from typing import Any
 
+from orange3_spark_tpu.core.table import TpuTable
+from orange3_spark_tpu.obs.registry import REGISTRY
 from orange3_spark_tpu.widgets.base import Widget
 from orange3_spark_tpu.widgets.catalog import WIDGET_REGISTRY
+
+M_DISPATCHES = REGISTRY.counter(
+    "otpu_canvas_dispatches_total",
+    "device dispatches a run of a canvas cost, by mode: staged (ONE fused "
+    "program a StagedGraph call, workflow/staging.py) | eager (one a "
+    "table-consuming widget WorkflowGraph.run fires: a floor, such a "
+    "widget dispatches at least once)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -151,6 +160,8 @@ class WorkflowGraph:
                 )
             t0 = time.perf_counter()
             node.outputs = node.widget.process(**inputs)
+            if any(isinstance(v, TpuTable) for v in inputs.values()):
+                M_DISPATCHES.inc(mode="eager")
             if verbose:  # per-widget wall clock (SURVEY §5 tracing)
                 print(f"[workflow] {node.widget.name}: "
                       f"{time.perf_counter() - t0:.3f}s")

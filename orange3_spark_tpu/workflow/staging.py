@@ -19,12 +19,24 @@ device (views, evaluators, info) cannot be staged and terminate the path.
 
 from __future__ import annotations
 
+import copy
 from typing import Callable
 
 import jax
 
 from orange3_spark_tpu.core.table import TpuTable
-from orange3_spark_tpu.workflow.graph import WorkflowGraph
+from orange3_spark_tpu.obs.context import trace_scope
+from orange3_spark_tpu.obs.registry import REGISTRY
+from orange3_spark_tpu.obs.trace import span
+from orange3_spark_tpu.workflow.graph import M_DISPATCHES, WorkflowGraph
+
+_M_REFITS = REGISTRY.counter(
+    "otpu_canvas_refits_total",
+    "calls of a StagedGraph built with refit=True: whole-canvas refits")
+_M_FALLBACKS = REGISTRY.counter(
+    "otpu_canvas_refit_fallbacks_total",
+    "estimator nodes that kept their eager fitted state in a refit=True "
+    "program (fit not traceable, or a restored model), counted per call")
 
 
 class StagedTransform:
@@ -171,7 +183,7 @@ class StagedGraph:
 
     def __init__(self, fn, input_keys, templates, out_domain, out_meta,
                  session, frontier, refit_fallbacks=(),
-                 donate_inputs: bool = False):
+                 donate_inputs: bool = False, refit_nodes=None):
         # donate_inputs: each boundary input's (X, Y, W) buffers are
         # consumed by the call — for the refit-loop case (fresh batches
         # through replacements= every call, staged fit+transform in one
@@ -193,6 +205,10 @@ class StagedGraph:
         # estimator nodes that stayed on closed-over fitted state under
         # refit=True because their fit would not trace
         self.refit_fallbacks = list(refit_fallbacks)
+        # {nid: graph node} of the estimators the program re-fits: their
+        # 'model' outputs are refreshed from every call's states. None =
+        # a program built without refit (no states, no spans)
+        self._refit_nodes = refit_nodes
 
     @property
     def _jitted(self):
@@ -218,7 +234,46 @@ class StagedGraph:
     def __call__(self, replacements: dict[int, TpuTable] | None = None) -> TpuTable:
         """Execute the fused program; ``replacements`` substitutes new tables
         for boundary input nodes (same domains/shapes — the compiled program
-        is reused)."""
+        is reused). The table alone: ``run`` hands the states too."""
+        return self.run(replacements)[0]
+
+    def run(self, replacements: dict[int, TpuTable] | None = None
+            ) -> tuple[TpuTable, dict]:
+        """-> (sink table, fitted states). Built with ``refit=True``:
+        ``{node id: {**model.state_pytree, **model.fit_summary}}`` of every
+        re-fitted estimator, device arrays out of the SAME dispatch that
+        made the table; the call waits for them and puts a fresh model,
+        loaded from them, on each re-fitted node's 'model' port (the
+        nodes' cached 'data' tables are the eager run's still). One trace
+        a call: ``canvas_refit`` > ``canvas_dispatch`` (host seconds until
+        the program is enqueued), ``canvas_drain`` (until its outputs are
+        ready), ``canvas_models``. Built without: ``{}``, no span, no wait
+        — as before."""
+        if self._refit_nodes is None:
+            return self._dispatch(replacements)
+        with trace_scope("canvas", reuse=True), span("canvas_refit"):
+            with span("canvas_dispatch"):
+                table, states = self._dispatch(replacements)
+            with span("canvas_drain"):
+                jax.block_until_ready((table.X, table.Y, table.W, states))
+            with span("canvas_models"):
+                for nid, state in states.items():
+                    outs = self._refit_nodes[nid].outputs
+                    if outs is None:        # invalidated since staging
+                        continue
+                    model = copy.copy(outs["model"])
+                    own = model.state_pytree.keys()
+                    model.load_state_pytree(
+                        {k: v for k, v in state.items() if k in own})
+                    model.load_fit_summary(
+                        {k: v for k, v in state.items() if k not in own},
+                        fit="staged")
+                    outs["model"] = model
+                _M_REFITS.inc()
+                _M_FALLBACKS.inc(len(self.refit_fallbacks))
+        return table, states
+
+    def _dispatch(self, replacements) -> tuple[TpuTable, dict]:
         jitted = self._jitted
         if jitted is self._donating and jitted is not self._plain:
             # donating call: every input buffer is consumed. Any input not
@@ -243,9 +298,10 @@ class StagedGraph:
             # serving path: staged-graph executables share the context's
             # AOT cache/counters (see StagedTransform.__call__)
             compiled = ctx.staged_executable(self, args)
-            X, Y, W = compiled(*args)
+            X, Y, W, states = compiled(*args)
         else:
-            X, Y, W = jitted(*args)
+            X, Y, W, states = jitted(*args)
+        M_DISPATCHES.inc(mode="staged")
         if replacements:
             # every staged widget is row-preserving, so the output's LOGICAL
             # row count follows the (row-aligned) inputs of THIS call — the
@@ -258,7 +314,9 @@ class StagedGraph:
             metas = None  # host-side metas do not flow through the device path
         else:
             metas, n_rows = self._out_meta
-        return TpuTable(self.out_domain, X, Y, W, metas, n_rows, self.session)
+        table = TpuTable(self.out_domain, X, Y, W, metas, n_rows,
+                         self.session)
+        return table, states
 
     def lower_text(self) -> str:
         """StableHLO of the fused program (one module = one XLA computation)."""
@@ -333,14 +391,16 @@ def _node_stage_fn(graph: WorkflowGraph, nid: int, outputs):
 
 
 def _refit_fn(widget):
-    """Staged fn for an estimator widget that re-FITS inside the trace."""
+    """Staged fn for an estimator widget that re-FITS inside the trace:
+    -> (its 'data' table, the fitted state it scored that table with)."""
     def fn(ins, w=widget):
         est = w.estimator_cls(w.params)
         m = est.fit(ins["data"])
+        state = {**m.state_pytree, **m.fit_summary}
         try:
-            return m.transform(ins["data"])
+            return m.transform(ins["data"]), state
         except NotImplementedError:
-            return ins["data"]
+            return ins["data"], state
     return fn
 
 
@@ -356,7 +416,7 @@ def _fit_traces(widget, template: TpuTable) -> tuple[bool, str | None]:
 
     def probe(X, Y, W):
         t = TpuTable(domain, X, Y, W, None, n_rows, session)
-        return fn({"data": t}).X
+        return fn({"data": t})[0].X
 
     try:
         jax.eval_shape(probe, template.X, template.Y, template.W)
@@ -390,7 +450,12 @@ def stage_graph(
     cannot trace keep the closed-over state and are listed in
     ``refit_fallbacks``. OWApplyModel always applies its eagerly-fitted
     upstream model (models do not flow through the staged region as
-    signals).
+    signals). What a refit hands back: ``staged()`` the sink's table, as
+    without ``refit``; ``staged.run()`` that table AND the fitted state of
+    every re-fitted node out of the same single dispatch
+    (``StagedGraph.run``), and either call leaves a fresh model, loaded
+    from that state, on each re-fitted widget's 'model' port — an eager
+    ``model.transform`` after a staged refit agrees with the staged table.
 
     ``donate_inputs=True`` (exec/donate.py sweep): every call consumes its
     input tables' buffers — pair with ``refit=True`` serving/refit loops
@@ -443,6 +508,7 @@ def stage_graph(
     visit(sink)
 
     refit_fallbacks: list = []
+    refits: dict[int, Callable] = {}    # nid -> fit-in-trace fn
     if refit:
         for nid in list(staged):
             node = graph.nodes[nid]
@@ -469,7 +535,7 @@ def stage_graph(
             template = outputs[e.src][e.src_port]
             traces, why = _fit_traces(w, template)
             if traces:
-                staged[nid] = _refit_fn(w)
+                refits[nid] = _refit_fn(w)
             else:
                 refit_fallbacks.append({
                     "node": nid, "widget": w.name,
@@ -488,6 +554,7 @@ def stage_graph(
             feeds[e.dst].append((e.dst_port, (e.src, e.src_port)))
 
     in_templates = dict(inputs)
+    scopes = {n: f"canvas/{graph.nodes[n].widget.name}" for n in topo}
 
     def fused(*flat):
         tables: dict[tuple[int, str], TpuTable] = {}
@@ -496,18 +563,26 @@ def stage_graph(
             tables[key] = TpuTable(
                 t.domain, X, Y, W, t.metas, t.n_rows, session
             )
-        for nid in topo:
-            ins = {port: tables[src_key] for port, src_key in feeds[nid]}
-            out = staged[nid](ins)
-            tables[(nid, "data")] = out
+        states: dict[int, dict] = {}
+        # the body runs only while jax traces it: one span a (re)trace
+        with span("canvas_stage", nodes=len(topo), refits=len(refits)):
+            for nid in topo:
+                ins = {port: tables[src_key] for port, src_key in feeds[nid]}
+                with jax.named_scope(scopes[nid]):
+                    if nid in refits:
+                        out, states[nid] = refits[nid](ins)
+                    else:
+                        out = staged[nid](ins)
+                tables[(nid, "data")] = out
         final = tables[(sink, sink_port)]
-        return final.X, final.Y, final.W
+        return final.X, final.Y, final.W, states
 
     sink_table = outputs[sink][sink_port]
     return StagedGraph(
         fused, input_keys, in_templates, sink_table.domain,
         (sink_table.metas, sink_table.n_rows), session, frontier,
         refit_fallbacks, donate_inputs=donate_inputs,
+        refit_nodes=({n: graph.nodes[n] for n in refits} if refit else None),
     )
 
 

@@ -444,3 +444,43 @@ def test_kmeans_lloyd_compiles(one_chip):
         sds((k, d), jnp.float32), sds((), jnp.float32),
         k=k, max_iter=10).compile()
     _fits(compiled)
+
+
+# ------------------------------------------------------------------- canvas
+def test_staged_canvas_refit_compiles_at_the_cells_size(one_chip, session):
+    """The benchmark's canvas (scaler -> PCA(4) -> KMeans(10),
+    ``stage_graph(refit=True)``) at the cell's 2^27 x 8: the ONE program
+    fits the chip beside nothing but its table, and holds none of what
+    made it unusable there (PERF.md section 6, PR 35): no copy of the table
+    by a column take (4,096 gathers and minutes of compile), no sort of
+    all N rows for the seeding's sample, no scatter-add of N values for
+    the cluster sizes, and the row contractions as blocked products."""
+    from orange3_spark_tpu.core.domain import ContinuousVariable, Domain
+    from orange3_spark_tpu.core.table import TpuTable
+    from orange3_spark_tpu.widgets.catalog import WIDGET_REGISTRY, OWTable
+    from orange3_spark_tpu.workflow.graph import WorkflowGraph
+    from orange3_spark_tpu.workflow.staging import stage_graph
+
+    n = 1 << 27
+    domain = Domain([ContinuousVariable(f"c{i}") for i in range(8)])
+    head = np.random.default_rng(0).standard_normal((1024, 8))
+    g = WorkflowGraph()
+    src = g.add(OWTable(TpuTable.from_numpy(domain, head, session=session)))
+    sc = g.add(WIDGET_REGISTRY["OWStandardScaler"](with_mean=True))
+    pca = g.add(WIDGET_REGISTRY["OWPCA"](k=4))
+    km = g.add(WIDGET_REGISTRY["OWKMeans"](k=10, max_iter=20))
+    g.connect(src, "data", sc, "data")
+    g.connect(sc, "data", pca, "data")
+    g.connect(pca, "data", km, "data")
+    staged = stage_graph(g, km, refit=True)
+    assert staged.refit_fallbacks == []
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = staged._plain.lower(
+        (sds((n, 8), jnp.float32), None, sds((n,), jnp.float32))).compile()
+    total = _fits(compiled)
+    assert total < 14 << 30, total      # 13.0 GB read, 16.9 on the chip
+    text = compiled.as_text()
+    assert text.count(" gather(") < 16, text.count(" gather(")
+    assert not re.search(r" sort\([^)]*\[134217728\]", text)
+    assert not re.search(r"= \S+\[10\]\S* scatter\(", text)
+    assert "8192,10,4" in text and "8192,8,8" in text   # rows_dot's partials

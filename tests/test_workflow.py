@@ -373,6 +373,9 @@ def test_staged_refit_fits_inside_the_trace(session):
 
     staged = stage_graph(g, pca, refit=True)
     assert staged.refit_fallbacks == []
+    # staged now: it closes over the t0-fitted models (a refit puts fresh
+    # models on the widgets' ports and leaves these objects as they are)
+    serve_t0_models = stage_graph(g, pca)
 
     # same data: staged refit == the eager run
     out0 = staged()
@@ -395,7 +398,7 @@ def test_staged_refit_fits_inside_the_trace(session):
         np.asarray(out1.X), np.asarray(eager1.X), atol=1e-4
     )
     # and it is genuinely different from serving the t0-fitted models
-    served = stage_graph(g, pca)(replacements={src: t1})
+    served = serve_t0_models(replacements={src: t1})
     assert not np.allclose(np.asarray(out1.X), np.asarray(served.X),
                            atol=1e-4)
 
