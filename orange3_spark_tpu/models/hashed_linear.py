@@ -47,7 +47,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import time
-from functools import partial
+from functools import partial, reduce
 from typing import Callable, Iterator
 
 import jax
@@ -73,6 +73,7 @@ from orange3_spark_tpu.optim.sparse import (
     init_optim_state, is_sparse_update, note_slot_blocks, note_sorts,
     optim_kind, resolve_optim_update, resolve_sparse_lowering, slot_blocks,
     sort_keys, sort_keys_bytes, sort_slots, sparse_embedding_update,
+    touched_rows,
 )
 from orange3_spark_tpu.obs import prof
 from orange3_spark_tpu.obs.registry import REGISTRY
@@ -243,6 +244,44 @@ def _hashed_logits(theta, dense, idx, compute_dtype, vals=None):
     if vals is not None:
         emb_rows = emb_rows * vals[:, :, None]
     logits = jnp.sum(emb_rows, axis=1, dtype=jnp.float32)        # [N, k]
+    return _add_dense_logits(logits, theta, dense, compute_dtype)
+
+
+def _sum_columns(occ):
+    """``occ[C, N, k]`` summed over its columns in float32, in a fixed
+    order written out here: neighbouring columns in pairs, then the pairs
+    one after the other — ``((c0 + c1) + (c2 + c3)) + (c4 + c5) …``. It is
+    the order in which the chip's compiler adds the 26 columns of
+    ``_hashed_logits``' ``jnp.sum(axis=1)`` (a v5e, jax 0.9.0: of 37
+    orders tried against 262,144 of its logits the one that gives every
+    bit, PERF.md §6 PR 36), so a sparse fit keeps the bits it had while
+    its forward still gathered; and written out, it is this program's
+    order and not a layout's."""
+    x = occ.astype(jnp.float32)
+    odd = x.shape[0] % 2
+    pairs = [x[c] + x[c + 1] for c in range(0, x.shape[0] - odd, 2)]
+    return reduce(jnp.add, pairs + [x[-1]] * odd)
+
+
+def _touched_logits(theta, dense, keys, shape, compute_dtype, vals=None):
+    """The sparse step's forward: ``_hashed_logits``' values on every
+    live row of a chunk whose ``optim.sparse.sort_keys`` are ``keys``
+    (``shape`` = the chunk's ``(N, C)``), from ONE read of each distinct
+    table row (``optim.sparse.touched_rows``) instead of a gather at the
+    ``N x C`` occurrences. Returns ``(rows, logits)``: the rows read, for
+    the update that follows, and the ``[N, k]`` logits. Not
+    differentiable through the table — the ``sparse_*`` rules never ask."""
+    rows, occ = touched_rows(theta["emb"], keys, *shape)
+    occ = occ.astype(compute_dtype)                   # [C, N, k]
+    if vals is not None:
+        occ = occ * vals.T[:, :, None]
+    return rows, _add_dense_logits(_sum_columns(occ), theta, dense,
+                                   compute_dtype)
+
+
+def _add_dense_logits(logits, theta, dense, compute_dtype):
+    """The table's share of the logits plus the dense block's matmul and
+    the intercept."""
     if theta["coef"].shape[0]:
         logits = logits + jnp.dot(
             dense.astype(compute_dtype),
@@ -312,7 +351,8 @@ def _step_core(
     optim_update == 'adam' is the legacy path: in-loss L2 + a dense optax
     adam sweep over the whole table. Every other rule (optim/ subsystem)
     reports the pure data loss, treats reg as decoupled weight decay, and
-    — for the sparse_* rules — updates only the touched rows, with
+    — for the sparse_* rules — reads each distinct touched row once for
+    its forward (``_touched_logits``) and updates only those rows, with
     ``keys`` this chunk's ``optim.sparse.sort_keys`` where the fused
     replay has built them ahead of its scan (None: the step sorts for
     itself). ``sparse_lowering`` ('sort' | 'none') names the dedup in the
@@ -362,14 +402,22 @@ def _step_core(
     slots = opt_state["slots"]
     if is_sparse_update(optim_update):
         # forward only — no autodiff through the table: the [N, k] logits
-        # gradient is all the touched-row engine needs
-        logits = forward(theta)
+        # gradient is all the touched-row engine needs. So the forward is
+        # free to read each DISTINCT row once and reach the occurrences
+        # through the dedup's own sort (touched_rows: _hashed_logits'
+        # values, bit for bit, on every live row), and the update takes
+        # its weight rows from that one read
+        if keys is None:
+            keys = sort_keys(idx, n_dims, sort_slots(*idx.shape, n_dims),
+                             n_valid, cats if value_weighted else None)
+        with jax.named_scope("step/forward"):
+            rows, logits = _touched_logits(theta, dense, keys, idx.shape,
+                                           compute_dtype, vals)
         loss, dl = jax.value_and_grad(data_loss)(logits)
         emb, t, eslots, n_blocks = sparse_embedding_update(
             kind, theta["emb"], opt_state["t"], slots["emb"], dl, idx,
-            lr, decay, reg, l1, step, use_decay=use_decay, n_valid=n_valid,
-            raw_cats=(cats if value_weighted else None), vals=vals,
-            keys=keys,
+            lr, decay, reg, l1, step, use_decay=use_decay, vals=vals,
+            keys=keys, rows=rows,
         )
         # dense small parameters: the same rule, full-array (they are tiny)
         with jax.named_scope("step/dense_leaf"):

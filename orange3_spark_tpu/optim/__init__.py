@@ -26,4 +26,5 @@ from orange3_spark_tpu.optim.sparse import (  # noqa: F401
     resolve_optim_update,
     resolve_sparse_lowering,
     sparse_embedding_update,
+    touched_rows,
 )

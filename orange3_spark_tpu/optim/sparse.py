@@ -39,26 +39,39 @@ This module is the one home of that machinery:
   rule -> sorted unique scatter over the LIVE prefix of the slots only,
   ``SLOT_BLOCK`` slots a loop trip. Nothing rides the chunk cache. What
   the chip read at 2^29 rows and M = 6.8M occurrences a step (v5e,
-  PERF.md §5, §6): the table-wide gathers were 0.42 s while they ran over
-  the 6.8M-slot static bound, and cost per INDEX (~14 ns), not per
-  distinct row — hence the live prefix. **A permutation of the M
-  occurrences is a sort's payload, never an M-index gather:** XLA's
-  gather costs 7 ns an index from a 1 MB source as from a 27 MB one
+  PERF.md §5, §6): a gather out of the 2 GB table costs per INDEX
+  (~14 ns) and nothing else, with or without locality — hence the live
+  prefix (PR 27), and hence **the forward reads each DISTINCT row once**
+  (PR 36: ~0.93M of a Zipf chunk's 6.8M occurrences; the occurrence
+  gather was 92 ms of a 243 ms step). **What moves M values is a sort's
+  payload, never an M-index gather or an unsorted M-index scatter:**
+  XLA's gather costs 7 ns an index from a 1 MB source as from a 27 MB one
   (49 ms for M), a sort of M ``(i32 key, 32-bit payload)`` pairs 12–13 ms
-  (PR 31). So the sorted keys are the key sort's own first output, and
-  the key half hands on ``inv`` — each occurrence's rank, one more sort —
-  so that the gradient half carries its M per-occurrence gradients to
-  sorted order as the payload of a sort keyed by ``inv`` (a multiclass
-  fit's ``k`` columns are ``k`` payloads of the one sort: 30 ms for three
-  where the take of ``[M, 3]`` rows read 84).
-  The part of the dedup that reads the keys alone (``sort_keys``: the two
-  sorts, segment ids, the ``uniq`` scatter) does not change between the
+  (PR 31). So the sorted keys are the key sort's own first output; the
+  key half hands on ``inv`` — each occurrence's rank, one more sort — so
+  that the gradient half carries its M per-occurrence gradients to sorted
+  order as the payload of a sort keyed by ``inv`` (a multiclass fit's
+  ``k`` columns are ``k`` payloads of the one sort), and ``order``, the
+  permutation itself, so that the forward carries the rows' bits the
+  other way (``touched_rows``: the distinct rows gathered block by block,
+  their bit patterns' differences scattered at the segments' first sorted
+  places, a ``uint32`` prefix sum, one sort keyed by ``order`` — exact,
+  because sums mod 2^32 are); the update's block loop takes its weight
+  rows from that same read. ``uniq`` and ``head`` (a segment's table row
+  and first sorted place) come out of a third sort of the key half, which
+  compacts the segments' starts to the front.
+  The part of the dedup that reads the keys alone (``sort_keys``: the
+  three sorts, segment ids) does not change between the
   epochs of a cached chunk, so the fused replay builds that half once per
   chunk and dispatch and hands it to its steps
   (``keys=``): ``sort_keys_bytes`` a chunk of temp in that one program,
   for as long as it runs, taken only where the caller's cache budget
   holds it (``models/hashed_linear._hoist_sort_keys``). ``_hashed_step``
   and a replay without the room sort in the step.
+  XLA:CPU prices these the other way round (sorts ~0.3 us a pair,
+  gathers nearly free): a full-size step pays five ~2 s sorts there
+  (README, sparse-optimizer entry); the CPU runs the chip's program all
+  the same.
 
 Layering: this module knows nothing about chunks, hashing or streams —
 ``models/hashed_linear`` composes it into the step.
@@ -78,7 +91,7 @@ __all__ = [
     "resolve_sparse_lowering", "optim_kind", "is_sparse_update",
     "init_optim_state", "adopt_optim_state", "plan_slots", "slot_blocks",
     "occurrence_dead", "apply_rule", "dense_update",
-    "sort_keys", "sort_slots", "sort_keys_bytes",
+    "sort_keys", "sort_slots", "sort_keys_bytes", "touched_rows",
     "sparse_embedding_update", "note_slot_blocks", "note_sorts",
     "finalize_lazy_decay",
 ]
@@ -274,15 +287,16 @@ def occurrence_dead(n_rows: int, n_cat: int, n_valid, raw_cats=None):
 
 # ------------------------------------------------- the touched-row engines
 
-def _touched_rows_update(kind, emb, t, slots, sums, rid, lr, decay, reg, l1,
-                         step, *, use_decay):
-    """Gather the touched rows (+ slots, + timestamps), apply catch-up
-    lazy decay and the rule. ``rid`` is one block of the live prefix of
-    the touched-row list (-1 on dead slots; gathers clamp, writeback
-    masks). Returns the updated rows and slot rows."""
+def _touched_rows_update(kind, p_rows, t, slots, sums, rid, lr, decay, reg,
+                         l1, step, *, use_decay):
+    """Gather the touched rows' slots and timestamps, apply catch-up lazy
+    decay and the rule. ``rid`` is one block of the live prefix of the
+    touched-row list (-1 on dead slots; gathers clamp, writeback masks),
+    ``p_rows`` the same block of the weight rows as the forward read them
+    (``touched_rows``: the table is not written between the two). Returns
+    the updated rows and slot rows."""
     with jax.named_scope("step/gather"):
         rsafe = jnp.maximum(rid, 0)
-        p_rows = jnp.take(emb, rsafe, axis=0)
         slot_rows = {n: jnp.take(v, rsafe, axis=0) for n, v in slots.items()}
         if use_decay:
             t_rows = jnp.take(t, rsafe)
@@ -305,27 +319,46 @@ def _segment_sums(g_sorted, seg, n_slots: int):
         seg].add(g_sorted, indices_are_sorted=True)
 
 
+def _to_slots(v, n_slots: int, fill):
+    """A compacted ``[M]`` vector as a slot array: cut or padded (with
+    ``fill``, the dead value) to the slots' static length."""
+    pad = jnp.full((max(n_slots - v.shape[0], 0),), fill, v.dtype)
+    return jnp.concatenate([v, pad])[:n_slots]
+
+
 def sort_keys(idx, n_dims: int, n_slots: int, n_valid, raw_cats=None):
     """The key half of the 'sort' lowering's in-jit dedup — everything
     that reads the chunk's hashed keys and ``n_valid`` and nothing else:
     sort the occurrences (dead ones behind the sentinel ``n_dims``) and
     number the segments in sorted order. Returns ``{'inv': i32[M] each
     occurrence's rank in the stable sort (the inverse of its
-    permutation), 'seg': i32[M] each sorted occurrence's segment,
+    permutation), 'order': i32[M] that permutation itself, sorted place
+    -> occurrence, the occurrence named by its place in COLUMN-major
+    order (``c * N + i`` for row ``i``, column ``c``: the forward sums
+    over the columns, and wants a column's rows side by side),
+    'seg': i32[M] each sorted occurrence's segment,
     'uniq': i32[n_slots] table row per segment (-1 on dead/unused slots),
-    'n_live': i32[]}``: the dead sentinel sorts last, so the live slots
-    are exactly the prefix ``[0, n_live)`` of ``uniq``. The sorted keys
-    are the sort's own first output, and ``inv`` is one more sort (of the
-    permutation, carrying an iota): on the chip a sort moves M values for
-    a quarter of what an M-index gather costs (module docstring).
+    'head': i32[n_slots] the sorted place at which the segment starts
+    (M, out of range, on dead/unused slots), 'n_live': i32[]}``: the dead
+    sentinel sorts last, so the live slots are exactly the prefix
+    ``[0, n_live)`` of ``uniq`` and ``head``. The sorted keys and the
+    permutation are the key sort's own two outputs, ``inv`` is one more sort
+    (of the permutation, carrying an iota), and ``uniq`` / ``head`` come
+    out of a third: the places in sorted order, those that are not a live
+    segment's start pushed behind by their top bit, carrying the sorted
+    keys — so its first ``n_live`` pairs are (where the segment starts,
+    its table row). On the chip a sort moves M values for a quarter of
+    what an M-index gather or an unsorted M-index scatter costs (module
+    docstring).
     A cached chunk's keys do not change between epochs, so the fused
     replay builds this once per chunk and dispatch (``sort_keys_bytes``
     is what it then holds) and every step reuses it."""
     N, C = idx.shape
+    M = N * C
     with jax.named_scope("step/sort"):
         dead = occurrence_dead(N, C, n_valid, raw_cats)
         flat = jnp.where(dead, jnp.int32(n_dims), idx).reshape(-1)
-        iota = jnp.arange(N * C, dtype=jnp.int32)
+        iota = jnp.arange(M, dtype=jnp.int32)
         s_idx, order = jax.lax.sort_key_val(flat, iota)   # stable sort
         # a permutation's keys are unique: nothing rests on stability
         _, inv = jax.lax.sort_key_val(order, iota, is_stable=False)
@@ -333,15 +366,79 @@ def sort_keys(idx, n_dims: int, n_slots: int, n_valid, raw_cats=None):
         start = jnp.concatenate(
             [jnp.ones((1,), bool), s_idx[1:] != s_idx[:-1]])
         seg = jnp.cumsum(start.astype(jnp.int32)) - 1
-        # unique row id per segment slot: scatter the segment-start
-        # values; non-starts and the dead sentinel route out of range and
-        # drop
-        uniq = jnp.full((n_slots,), -1, jnp.int32).at[
-            jnp.where(start & (s_idx < n_dims), seg, n_slots)
-        ].set(s_idx.astype(jnp.int32), mode="drop")
+        # one slot per live segment, in sorted order: the starts keep
+        # their place as their key, every other place (and the dead
+        # sentinel's start) sorts behind them all; keys unique again
+        behind = jnp.uint32(1 << 31)
+        place, row = jax.lax.sort_key_val(
+            iota.astype(jnp.uint32) | jnp.where(
+                start & (s_idx < n_dims), jnp.uint32(0), behind),
+            s_idx, is_stable=False)
+        live = place < behind
+        head = _to_slots(jnp.where(live, place, M).astype(jnp.int32),
+                         n_slots, M)
+        uniq = _to_slots(jnp.where(live, row, -1), n_slots, -1)
         # every segment but the dead one
         n_live = seg[-1] + 1 - (s_idx[-1] >= n_dims).astype(jnp.int32)
-    return {"inv": inv, "seg": seg, "uniq": uniq, "n_live": n_live}
+    return {"inv": inv, "order": order % C * N + order // C, "seg": seg,
+            "uniq": uniq, "head": head, "n_live": n_live}
+
+
+def _slot_block(n_slots: int) -> int:
+    """Slots a trip of a loop over the live prefix takes (``sort_slots``
+    is a whole number of them)."""
+    return min(SLOT_BLOCK, n_slots)
+
+
+def touched_rows(emb, keys: dict, n_rows: int, n_cat: int):
+    """The sparse step's forward read: each DISTINCT touched row of
+    ``emb`` (f32) gathered ONCE, and carried to the chunk's ``M = n_rows x
+    n_cat`` occurrences with no M-index gather. Returns ``(rows
+    f32[n_slots, k], occ f32[n_cat, n_rows, k])``: ``rows[s]`` is
+    ``emb[uniq[s]]`` over the live prefix of the slots (the update's
+    block loop takes its weight rows from here instead of gathering them
+    again), ``occ[c, i]`` holds the bits of ``emb[idx[i, c]]`` for every
+    live occurrence — what ``jnp.take(emb, idx.T, axis=0)`` gave, -0.0 and
+    denormals included.
+
+    Block by block over the live prefix, as the update walks it: gather
+    the block's rows, take their bit patterns' differences from the slot
+    before (uint32, wrapping; the previous block's last pattern is the
+    loop's carry) and scatter those at the segments' first sorted places
+    (``head``: sorted, unique, dead slots dropped) into a zero
+    ``uint32[M]`` a logit column. A prefix sum mod 2^32 then leaves at
+    every sorted place exactly the bits of its segment's row, and one
+    sort keyed by ``order`` (a permutation: unstable) carries them to the
+    occurrences' own places, the ``k`` columns as its ``k`` payloads. Dead
+    occurrences sort behind every live one and inherit the last live
+    row's bits (finite, like the table); their rows weigh nothing."""
+    uniq, head = keys["uniq"], keys["head"]
+    n_slots, k = uniq.shape[0], emb.shape[1]
+    B = _slot_block(n_slots)
+    sc = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
+
+    def block(i, carry):
+        rows, deltas, last = carry
+        rid = jax.lax.dynamic_slice_in_dim(uniq, i * B, B)
+        w = jnp.take(emb, jnp.maximum(rid, 0), axis=0)
+        bits = jax.lax.bitcast_convert_type(w, jnp.uint32)
+        delta = bits - jnp.concatenate([last[None], bits[:-1]])
+        at = jax.lax.dynamic_slice_in_dim(head, i * B, B)
+        deltas = tuple(d.at[at].set(delta[:, j], **sc)
+                       for j, d in enumerate(deltas))
+        rows = jax.lax.dynamic_update_slice_in_dim(rows, w, i * B, axis=0)
+        return rows, deltas, bits[-1]
+
+    rows, deltas, _ = jax.lax.fori_loop(
+        0, (keys["n_live"] + (B - 1)) // B, block,
+        (jnp.zeros((n_slots, k), emb.dtype),
+         tuple(jnp.zeros((n_rows * n_cat,), jnp.uint32) for _ in range(k)),
+         jnp.zeros((k,), jnp.uint32)))
+    _, *cols = jax.lax.sort(
+        (keys["order"], *(jnp.cumsum(d, dtype=jnp.uint32) for d in deltas)),
+        num_keys=1, is_stable=False)
+    occ = jax.lax.bitcast_convert_type(jnp.stack(cols, axis=1), emb.dtype)
+    return rows, occ.reshape(n_cat, n_rows, k)
 
 
 def _sorted_sums(dl, vals, keys: dict, n_cat: int):
@@ -375,26 +472,32 @@ def sort_slots(pad_rows: int, n_cat: int, n_dims: int) -> int:
 
 
 def sort_keys_bytes(pad_rows: int, n_cat: int, n_dims: int) -> int:
-    """Device bytes of one chunk's ``sort_keys`` (its three i32 arrays) —
-    what the fused replay holds per cached chunk while it runs,
-    and what its caller's budget is asked for."""
-    return 4 * (2 * pad_rows * n_cat + sort_slots(pad_rows, n_cat, n_dims))
+    """Device bytes of one chunk's ``sort_keys`` (its five i32 arrays:
+    ``inv``, ``order``, ``seg`` of the occurrences' length, ``uniq`` and
+    ``head`` of the slots') — what the fused replay holds per cached chunk
+    while it runs, and what its caller's budget is asked for."""
+    return 4 * (3 * pad_rows * n_cat
+                + 2 * sort_slots(pad_rows, n_cat, n_dims))
 
 
 def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
                             step, *, use_decay: bool, n_valid=None,
-                            raw_cats=None, vals=None, keys=None):
+                            raw_cats=None, vals=None, keys=None, rows=None):
     """One touched-row-only table update. ``dl`` is the [N, k] logits
     gradient; per-occurrence gradients are ``dl[row] (* val)``.
 
     The dedup is derived in-jit (key-value sort + cumsum-of-boundaries) — or,
     where the caller has already run ``sort_keys`` over this chunk (the
-    fused replay, once per chunk and dispatch), handed in as ``keys`` and
-    only the gradient half computed here: the same operations on the same
-    values either way. The slot arrays keep the static bound
-    ``plan_slots`` (a chunk of all-distinct keys fills it), but only their
-    live prefix is gathered, run through the rule and written back: a
-    ``fori_loop`` over blocks of
+    step, ahead of its forward; the fused replay, once per chunk and
+    dispatch), handed in as ``keys`` and only the gradient half computed
+    here: the same operations on the same values either way. Likewise
+    ``rows``: the touched weight rows as the step's forward read them
+    (``touched_rows``) — the table is not written between that read and
+    this update, so the block loop slices its weight rows out of them and
+    gathers only the rule's slots and the timestamps. The slot arrays keep
+    the static bound ``plan_slots`` (a chunk of all-distinct keys fills
+    it), but only their live prefix is gathered, run through the rule and
+    written back: a ``fori_loop`` over blocks of
     ``SLOT_BLOCK`` slots whose trip count ``ceil(n_live / SLOT_BLOCK)`` is
     computed on the device from the chunk's own keys. Each trip's
     writeback is a sorted unique scatter with out-of-range dead slots
@@ -409,11 +512,13 @@ def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
     shows them once per trip (``.../while/body/step/gather/...``)."""
     D = emb.shape[0]
     N, C = idx.shape
-    B = min(SLOT_BLOCK, plan_slots(N, C, D))
     if keys is None:
         keys = sort_keys(idx, D, sort_slots(N, C, D), n_valid, raw_cats)
+    if rows is None:
+        rows, _ = touched_rows(emb, keys, N, C)
     sums = _sorted_sums(dl, vals, keys, C)
     uniq = keys["uniq"]
+    B = _slot_block(uniq.shape[0])
     n_blocks = (keys["n_live"] + (B - 1)) // B
     sc = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
 
@@ -421,7 +526,8 @@ def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
         emb, t, slots = tables
         rid = jax.lax.dynamic_slice_in_dim(uniq, i * B, B)
         p_rows, slot_rows = _touched_rows_update(
-            kind, emb, t, slots, jax.lax.dynamic_slice_in_dim(sums, i * B, B),
+            kind, jax.lax.dynamic_slice_in_dim(rows, i * B, B), t, slots,
+            jax.lax.dynamic_slice_in_dim(sums, i * B, B),
             rid, lr, decay, reg, l1, step, use_decay=use_decay)
         with jax.named_scope("step/scatter"):
             wb = jnp.where(rid >= 0, rid, D)              # D drops
@@ -432,7 +538,7 @@ def sparse_embedding_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
                 t = t.at[wb].set(step + 1, **sc)
         return emb, t, slots
 
-    # gather -> rule -> write-back over the live prefix only, B slots a
+    # slots -> rule -> write-back over the live prefix only, B slots a
     # trip, the trip count read off the chunk itself; the tables are the
     # loop's carries and are updated in place
     emb, t, slots = jax.lax.fori_loop(0, n_blocks, block, (emb, t, slots))

@@ -157,7 +157,9 @@ def test_one_dispatch_a_refit_zero_fallbacks_and_its_iterations(fitted):
     after = counters()
     assert after["refits"] - before["refits"] == 3
     assert after["staged"] - before["staged"] == 3
-    assert after["fallbacks"] == before["fallbacks"] == 0
+    # the counter is the process's: another file's hostile widget may have
+    # counted before this one under --dist loadfile; this job adds none
+    assert after["fallbacks"] == before["fallbacks"]
     assert after["eager"] == before["eager"]
     assert after["iterations"] - before["iterations"] == sum(
         a["n_iter"] for a in answers)

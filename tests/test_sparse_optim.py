@@ -250,8 +250,8 @@ def _unblocked_sort_update(kind, emb, t, slots, dl, idx, lr, decay, reg, l1,
     sums, uniq, _n_live = _sorted_slots_whole(
         dl, idx, D, plan_slots(*idx.shape, D), n_valid, raw_cats, vals)
     p_rows, slot_rows = sparse_mod._touched_rows_update(
-        kind, emb, t, slots, sums, uniq, lr, decay, reg, l1, step,
-        use_decay=use_decay)
+        kind, jnp.take(emb, jnp.maximum(uniq, 0), axis=0), t, slots, sums,
+        uniq, lr, decay, reg, l1, step, use_decay=use_decay)
     wb = jnp.where(uniq >= 0, uniq, D)
     sc = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
     emb = emb.at[wb].set(p_rows, **sc)
@@ -382,8 +382,9 @@ def test_key_half_and_gradient_half_equal_the_whole_bitwise(case, k):
     keys = jax.jit(lambda idx, nv: sparse_mod.sort_keys(
         idx, D, n_slots, nv, raw))(idx, nv)
     assert {n: (v.shape, v.dtype.name) for n, v in keys.items()} == {
-        "inv": ((N * C,), "int32"), "seg": ((N * C,), "int32"),
-        "uniq": ((n_slots,), "int32"), "n_live": ((), "int32")}
+        "inv": ((N * C,), "int32"), "order": ((N * C,), "int32"),
+        "seg": ((N * C,), "int32"), "uniq": ((n_slots,), "int32"),
+        "head": ((n_slots,), "int32"), "n_live": ((), "int32")}
     for got in (jax.jit(halves)(dl, idx, nv),
                 jax.jit(halves)(dl, idx, nv, keys)):
         for a, b in zip(got, want):
@@ -397,11 +398,13 @@ def test_key_half_and_gradient_half_equal_the_whole_bitwise(case, k):
 def test_sort_keys_invariants(case):
     """``sort_keys`` against a numpy oracle of the same keys: ``inv`` is
     each occurrence's rank in the STABLE sort (occurrences of one row keep
-    their original order — the exactness contract), dead occurrences come
-    last, segment ids are dense and non-decreasing, ``uniq`` is strictly
-    increasing over ``[0, n_live)`` and -1 after, ``n_live`` is the number
-    of distinct live keys, and ``sort_keys_bytes`` is what the dict's
-    arrays hold."""
+    their original order — the exactness contract) and ``order`` the
+    permutation it inverts, dead occurrences come last, segment ids are
+    dense and non-decreasing, ``uniq`` is strictly increasing over
+    ``[0, n_live)`` and -1 after, ``head`` is each live segment's first
+    sorted place and M (out of range) after, ``n_live`` is the number of
+    distinct live keys, and ``sort_keys_bytes`` is what the dict's arrays
+    hold."""
     D, n_valid, draw, vw = _KEY_CASES[case]
     N, C = 12, 4
     rng = np.random.default_rng(zlib.crc32(f"inv/{case}".encode()))
@@ -418,7 +421,7 @@ def test_sort_keys_invariants(case):
     keys = jax.device_get(jax.jit(lambda idx, nv: sparse_mod.sort_keys(
         idx, D, n_slots, nv, None if raw is None else jnp.asarray(raw)))(
         jnp.asarray(idx), jnp.int32(n_valid)))
-    assert set(keys) == {"inv", "seg", "uniq", "n_live"}
+    assert set(keys) == {"inv", "order", "seg", "uniq", "head", "n_live"}
     assert sparse_mod.sort_keys_bytes(N, C, D) == sum(
         v.nbytes for n, v in keys.items() if n != "n_live")
     inv, seg, uniq, n_live = (keys[n] for n in
@@ -426,6 +429,8 @@ def test_sort_keys_invariants(case):
     flat = np.where(dead, D, idx).reshape(-1)
     order = np.argsort(flat, kind="stable")
     np.testing.assert_array_equal(inv, np.argsort(order))
+    # ... each occurrence (row i, column c) named by its column-major place
+    np.testing.assert_array_equal(keys["order"], order % C * N + order // C)
     s_idx = flat[order]
     n_dead = int(dead.sum())
     assert (s_idx[len(s_idx) - n_dead:] == D).all()         # dead ones last
@@ -439,6 +444,198 @@ def test_sort_keys_invariants(case):
     assert n_live == len(live)
     np.testing.assert_array_equal(uniq[:n_live], live)   # strictly increasing
     assert (uniq[n_live:] == -1).all()
+    head = keys["head"]
+    np.testing.assert_array_equal(
+        head[:n_live], np.searchsorted(seg, np.arange(n_live)))
+    assert (head[n_live:] == N * C).all()
+
+
+#: weights the bit-delta forward must carry untouched: both zeros,
+#: denormals, the largest and smallest normals, +-3e38 — drawn at random
+#: beside ordinary values, so that neighbouring slots' bit patterns differ
+#: by more than 2^31 either way and the running sum wraps past 2^32
+_SPECIAL = np.array(
+    [-0.0, 0.0, 1e-45, -1e-45, 1e-40, -1e-40, 3e38, -3e38,
+     np.finfo(np.float32).tiny, -np.finfo(np.float32).tiny,
+     np.finfo(np.float32).max, -np.finfo(np.float32).max], np.float32)
+
+
+def _bits(a):
+    return np.ascontiguousarray(np.asarray(a, np.float32)).view(np.uint32)
+
+
+def _gathered_rows(emb, keys, n_rows, n_cat):
+    """``touched_rows`` as gathers: the distinct rows by ``uniq``, then
+    every sorted occurrence's row by its segment, put at the occurrence's
+    own place — kept here, not in the package, as what the scatter, the
+    prefix sum and the sort must equal bit for bit."""
+    rows = jnp.take(emb, jnp.maximum(keys["uniq"], 0), axis=0)
+    occ = jnp.zeros((n_rows * n_cat, emb.shape[1]), emb.dtype).at[
+        keys["order"]].set(jnp.take(rows, keys["seg"], axis=0))
+    return rows, occ.reshape(n_cat, n_rows, -1)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("k", [1, 3], ids=["k=1", "k=3"])
+@pytest.mark.parametrize("case", list(_KEY_CASES))
+def test_forward_from_one_read_of_each_row_equals_the_gather(monkeypatch,
+                                                            case, k, dtype):
+    """The sparse step's forward (``_touched_logits``: each distinct row
+    read once, its bits carried to the occurrences by a delta scatter, a
+    uint32 prefix sum and a sort) against the gather it replaces. Every
+    live occurrence holds its row's bits — -0.0, denormals, +-3e38 and
+    neighbours whose patterns wrap when differenced included — the rows
+    handed to the update are the table's, and the logits equal bit for
+    bit those of the same sums over GATHERED rows (``_gathered_rows``).
+    Against ``_hashed_logits`` itself (what ``predict`` and the dense
+    twins run: another program, whose fused gather-and-reduce XLA:CPU
+    adds in its own order, with its own multiply-adds) the live rows'
+    logits agree to float32 rounding. ``SLOT_BLOCK`` is 16, so the live
+    prefix of the larger cases spans 2-3 trips and the delta's carry
+    crosses a block."""
+    import orange3_spark_tpu.models.hashed_linear as hl
+
+    monkeypatch.setattr(sparse_mod, "SLOT_BLOCK", 16)
+    D, n_valid, draw, vw = _KEY_CASES[case]
+    N, C, n_dense = 12, 4, 0 if vw else 3
+    rng = np.random.default_rng(zlib.crc32(f"fwd/{case}/{k}".encode()))
+    idx = {"random": lambda: rng.integers(0, D, (N, C)),
+           "distinct": lambda: rng.permutation(D)[:N * C].reshape(N, C),
+           "equal": lambda: np.full((N, C), 5)}[draw]().astype(np.int32)
+    live = np.broadcast_to(np.arange(N)[:, None] < n_valid, (N, C)).copy()
+    raw = vals = None
+    if vw:
+        raw = rng.integers(0, 1000, (N, C)).astype(np.float32)
+        raw[rng.permutation(N)[:5], rng.integers(0, C, 5)] = -1.0
+        vals = rng.uniform(0.5, 1.5, (N, C)).astype(np.float32)
+        vals[raw < 0] = 0.0               # the (-1, 0) padding convention
+        live &= raw >= 0
+        raw, vals = jnp.asarray(raw), jnp.asarray(vals)
+    tame = rng.normal(size=(D, k)).astype(np.float32)
+    emb = tame.copy()
+    at = rng.random((D, k)) < 0.5
+    emb[at] = rng.choice(_SPECIAL, int(at.sum()))
+    theta = {"emb": jnp.asarray(emb),
+             "coef": jnp.asarray(rng.normal(size=(n_dense, k)), jnp.float32),
+             "intercept": jnp.asarray(rng.normal(size=(k,)), jnp.float32)}
+    dense = jnp.asarray(rng.normal(size=(N, n_dense)), jnp.float32)
+    idx_d, nv = jnp.asarray(idx), jnp.int32(n_valid)
+    n_slots = sparse_mod.sort_slots(N, C, D)
+    assert n_slots % 16 == 0 and n_slots > 16        # a loop of blocks
+    cd = jnp.dtype(dtype)
+
+    def forward(theta, dense, idx, nv):
+        keys = sparse_mod.sort_keys(idx, D, n_slots, nv, raw)
+        rows, occ = hl.touched_rows(theta["emb"], keys, N, C)
+        _, logits = hl._touched_logits(theta, dense, keys, (N, C), cd, vals)
+        return keys, rows, occ, logits
+
+    keys, rows, occ, logits = jax.device_get(
+        jax.jit(forward)(theta, dense, idx_d, nv))
+    n_live = int(keys["n_live"])
+    assert n_live == len(set(idx[live].tolist()))
+    assert -(-n_live // 16) >= {"all-distinct": 3, "all-distinct-vw": 3,
+                                "padding-rows": 2, "vw-dead-pairs": 2,
+                                }.get(case, 0)        # trips of the loop
+    np.testing.assert_array_equal(_bits(rows[:n_live]),
+                                  _bits(emb[keys["uniq"][:n_live]]))
+    assert occ.shape == (C, N, k)
+    np.testing.assert_array_equal(_bits(occ)[live.T],
+                                  _bits(emb[idx.T])[live.T])
+    assert np.isfinite(occ).all()           # dead ones inherit a live row
+    with monkeypatch.context() as m:
+        m.setattr(hl, "touched_rows", _gathered_rows)
+        _, rows_g, occ_g, logits_g = jax.device_get(
+            jax.jit(forward)(theta, dense, idx_d, nv))
+    np.testing.assert_array_equal(_bits(rows_g[:n_live]),
+                                  _bits(rows[:n_live]))
+    np.testing.assert_array_equal(_bits(occ_g)[live.T], _bits(occ)[live.T])
+    np.testing.assert_array_equal(_bits(logits[:n_valid]),
+                                  _bits(logits_g[:n_valid]))
+    # ... and _hashed_logits itself, on weights whose sums do not overflow
+    theta = {**theta, "emb": jnp.asarray(tame)}
+    got = jax.jit(forward)(theta, dense, idx_d, nv)[3]
+    want = jax.jit(lambda theta, dense, idx: hl._hashed_logits(
+        theta, dense, idx, cd, vals))(theta, dense, idx_d)
+    np.testing.assert_allclose(np.asarray(got)[:n_valid],
+                               np.asarray(want)[:n_valid],
+                               rtol=2e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("kind", ["adagrad", "ftrl"])
+def test_small_fit_equals_the_gather_form_bitwise(monkeypatch, kind):
+    """A whole small fit as ``fit_stream`` dispatches it — a streamed step
+    a chunk, then the fused replay with its keys hoisted — leaves ``emb``,
+    the rule's slot tables, ``t``, ``coef``, the intercept, the block
+    count and every loss with the bits of the same fit whose forward
+    GATHERS (``_gathered_rows``: two gathers where ``touched_rows`` has
+    a scatter, a prefix sum and a sort), in blocks of 256 slots so that
+    the forward's loop and the update's take four trips."""
+    import orange3_spark_tpu.models.hashed_linear as hl
+
+    monkeypatch.setattr(sparse_mod, "SLOT_BLOCK", 256)
+    session = _layout_session("one-device")
+    p = StreamingHashedLinearEstimator(
+        n_dims=1 << 10, n_dense=4, n_cat=6, epochs=4, step_size=0.05,
+        chunk_rows=1024, reg_param=1e-3, l1_param=1e-4,
+        loss="squared_hinge", label_in_chunk=True,
+        optim_update=f"sparse_{kind}", sparse_lowering="sort").params
+    jits = [f for j in (hl._hashed_step, hl._hashed_replay_epochs)
+            for f in (j.donated, j.plain)]
+
+    def fit():
+        for f in jits:                # trace anew: the forward is patched
+            f.clear_cache()
+        theta, opt, stacks, salts, kw = _replay_state(
+            p, session, n_chunks=3, n_valid_last=700, seed=36)
+        hyper = (jnp.float32(p.reg_param), jnp.float32(p.step_size),
+                 jnp.float32(p.l1_param))
+        losses = []
+        for c in range(3):
+            theta, opt, loss = hl._hashed_step(
+                theta, opt, *jax.tree.map(lambda a: a[c], stacks), salts,
+                *hyper, **kw)
+            losses.append(loss)
+        theta, opt, replayed = hl._hashed_replay_epochs(
+            theta, opt, stacks, salts, *hyper, n_epochs=3, hoist_keys=True,
+            **kw)
+        return jax.device_get((theta, opt, losses, replayed))
+
+    new = fit()
+    with monkeypatch.context() as m:
+        m.setattr(hl, "touched_rows", _gathered_rows)
+        old = fit()
+    for f in jits:
+        f.clear_cache()
+    theta, opt = new[:2]
+    assert int(opt["step"]) == 12 and int(opt["blocks"]) >= 12 * 4 - 3
+    assert np.abs(theta["emb"]).max() > 1e-3
+    for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(old)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_hoist_is_admitted_at_the_cells_sizes():
+    """``sort_keys_bytes`` counts all five arrays of ``sort_keys`` (the
+    forward's ``order`` and ``head`` among them: 140.5 MB a chunk at the
+    Criteo cells' shape, 84 before), and the default cache budget still
+    holds the six cached chunks' keys beside the cache and its stack at
+    2^29 and 2^30 rows — so both replay cells keep ``step_sort_share``
+    12/48 — while a budget that ends one byte short of the keys sends
+    every step back to sorting for itself."""
+    from orange3_spark_tpu.models.hashed_linear import _hoist_sort_keys
+    from orange3_spark_tpu.optim.sparse import sort_keys_bytes, sort_slots
+
+    rows, C, chunks, budget = 1 << 18, 26, 6, 8 << 30   # fit_stream's default
+    cache = 100 << 20             # six packed chunks, rounded up (PERF.md)
+    for n_dims in (1 << 29, 1 << 30):
+        one = sort_keys_bytes(rows, C, n_dims)
+        assert sort_slots(rows, C, n_dims) == 7 << 20
+        assert one == 4 * (3 * rows * C + 2 * (7 << 20)) == 140_509_184
+        kw = {"sparse_lowering": "sort", "n_dims": n_dims}
+        assert _hoist_sort_keys(kw, rows, C, chunks, cache, budget)
+        tight = 2 * cache + chunks * one
+        assert _hoist_sort_keys(kw, rows, C, chunks, cache, tight)
+        assert not _hoist_sort_keys(kw, rows, C, chunks, cache, tight - 1)
 
 
 def test_slot_blocks_are_counted_per_fit(session, data):

@@ -215,12 +215,18 @@ def _at_dims(hashed, n_dims: int):
 
 
 def _no_occurrence_gather(text: str):
-    """A permutation of a chunk's M occurrences is a sort's payload, never
-    an M-index gather (optim/sparse.py; on the chip 12 ms against 49): the
-    program's gathers are the forward's ([rows, 26] out of the table) and
-    the block loop's three and no other — none has an [M]-long result."""
-    assert " gather(" in text
-    assert not re.search(rf"= \w+\[{M}[,\]][^ ]* gather\(", text)
+    """A chunk's M occurrences are reached through sorts, never through
+    an M-index gather (optim/sparse.py; on the chip 12 ms against 49 for
+    a permutation, 92 for the forward's read out of the 2 GB table): the
+    program's gathers are the block loops' — the forward's read of the
+    distinct rows, the update's of the rule's slots and the last-seen
+    steps, ``SLOT_BLOCK`` indices each — and no other: none has an
+    [M]-long or [rows, 26] result."""
+    shapes = re.findall(r"= \w+\[([\d,]*)\][^ ]* gather\(", text)
+    assert shapes
+    for dims in shapes:
+        assert np.prod([int(d) for d in dims.split(",") if d]) <= 1 << 20, (
+            shapes)
 
 
 def _tables_stay_in_place(compiled, n_dims: int):
@@ -289,16 +295,19 @@ def test_hashed_replay_epochs_compiles(one_chip, hashed, n_dims, hoist):
     (``hoist_keys``, what a fit whose cache budget holds them runs) — and
     as a fit with a tight budget runs it, the sort inside each step.
 
-    By hand at 2^29 (PR 31, this sandbox; PR 29 read temp 1,055,901,696
-    hoisted and 332,963,840 with the sort in the step): args 6,645,879,296
-    / temp 1,001,020,928 / alias 6,442,454,016 bytes — 0.503 GB of it the
-    stacked keys (three i32 vectors a chunk, lane-tiled [6, M/128, 128]:
-    stacked [6, M] the TPU's (8, 128) tiling pads 6 chunks to 8 and the
-    temp read 1,169,083,392); four sorts in the program: the key sort,
-    the sort that inverts its permutation and the ``uniq`` scatter's own
-    in the loop that builds the keys, and ONE under the epoch scan — the
-    sort that carries a step's per-occurrence gradients to sorted order.
-    ``_hashed_step`` at 2^29: temp 303,973,376 (PR 29: 331,204,096)."""
+    By hand at 2^29 (PR 36, this sandbox; PR 31 read temp 1,001,020,928
+    hoisted, PR 29 1,055,901,696): args 6,645,879,296 / temp
+    1,340,952,576 / alias 6,442,454,016 bytes — 0.843 GB of it the
+    stacked keys (five i32 vectors a chunk, lane-tiled [6, M/128, 128]:
+    stacked [6, M] the TPU's (8, 128) tiling pads 6 chunks to 8); five
+    sorts in the program: the key sort, the sort that inverts its
+    permutation and the one that compacts the segments' first places and
+    table rows (``uniq``, ``head``: where an unsorted scatter and ITS
+    sort stood) in the loop that builds the keys, and TWO under the epoch
+    scan — the sort that carries the forward's row bits from sorted order
+    to the occurrences and the sort that carries a step's per-occurrence
+    gradients the other way. ``_hashed_step`` at 2^29: temp 274,322,944
+    (PR 31: 303,973,376)."""
     from orange3_spark_tpu.models.hashed_linear import _hashed_replay_epochs
 
     (theta, opt, X, nv, y, w, salts, reg, lr), kw = _step_args(
@@ -312,10 +321,10 @@ def test_hashed_replay_epochs_compiles(one_chip, hashed, n_dims, hoist):
     text = compiled.as_text()
     in_scan = _sorts_in_loops_over_tables(text, n_dims)
     assert in_scan and text.count(" sort(") >= 3
-    # a step under the epoch scan runs exactly one sort, the carrier,
-    # where the keys were hoisted; with the sort in the step it runs the
-    # key sort, its inverse's and the carrier (and the uniq scatter's own)
-    assert max(in_scan) == 1 if hoist else max(in_scan) >= 3
+    # a step under the epoch scan runs exactly two sorts, the forward's
+    # carrier and the gradients', where the keys were hoisted; with the
+    # sort in the step it runs the key half's three as well
+    assert max(in_scan) == 2 if hoist else max(in_scan) == 5
 
 
 def test_hashed_predict_compiles_at_bucket(one_chip, hashed):
@@ -380,7 +389,8 @@ def _tables_stay_sharded(compiled, n_dims: int):
     (27 MB, ahead of the sort that carries them to sorted order: a sort
     along an axis wants it whole) and nothing else; no all-reduce is
     M-long. The gathers and write-backs are per-shard masked lookups
-    whose partial rows an all-reduce over `model` adds up."""
+    whose partial rows an all-reduce over `model` adds up; the forward's
+    delta scatter, prefix sum and carrier sort run whole on every chip."""
     text = compiled.as_text()
     m = compiled.memory_analysis()
     half = 3 * 4 * n_dims // 2
@@ -417,7 +427,11 @@ def test_hashed_replay_epochs_compiles_model_sharded_at_2_30(topo, hashed):
     step): args 6,569,333,248 / temp 178,806,272 / alias 6,442,454,016
     bytes a device, the step's six collectives and no other, no operation
     of the table's whole shape. PR 29, keys hoisted (replicated: 0.503 GB a
-    device): temp 774,028,288, everything else as it was."""
+    device): temp 774,028,288, everything else as it was. PR 36 (five key
+    vectors, 0.843 GB a device): temp 1,139,561,984; the forward's read of
+    the distinct rows is one more [SLOT_BLOCK]-row all-reduce over `model`
+    where the [131072, 26]-row one stood, and its carrier sort's result
+    reaches the `data` shards through one all-to-all of 13.6 MB."""
     from orange3_spark_tpu.models.hashed_linear import _hashed_replay_epochs
 
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
@@ -430,7 +444,7 @@ def test_hashed_replay_epochs_compiles_model_sharded_at_2_30(topo, hashed):
     _fits(compiled)
     _tables_stay_sharded(compiled, MESH_DIMS)
     in_scan = _sorts_in_loops_over_tables(compiled.as_text(), MESH_DIMS // 2)
-    assert in_scan and max(in_scan) == 1         # the carrier, a step
+    assert in_scan and max(in_scan) == 2         # the two carriers, a step
 
 
 # ------------------------------------------------------------------- kmeans
