@@ -434,8 +434,8 @@ def bench_criteo(n_rows: int, epochs: int = EPOCHS, *, dims: int = N_DIMS,
         "total_bytes": _dm.get("total_bytes"),
         "peak_bytes_fit": _dm.get("peak_bytes_fit"),
         "cache_entry_bytes": _dm.get("cache_entry_bytes"),
-        "reconcile_delta_bytes": (_dm.get("reconciliation") or {}
-                                  ).get("delta_vs_live_bytes"),
+        "unnamed_bytes": (_dm.get("high_water") or {}
+                          ).get("unnamed_bytes"),
     } if _dm else None)
 
     # -------- self-diagnosis probes (outside the timed window) --------

@@ -38,6 +38,7 @@ from orange3_spark_tpu.core.domain import (
     Variable,
 )
 from orange3_spark_tpu.core.session import TpuSession
+from orange3_spark_tpu.obs import prof
 
 
 class TpuTable:
@@ -120,7 +121,12 @@ class TpuTable:
             metas = np.asarray(metas, dtype=object)
             if metas.ndim == 1:
                 metas = metas[:, None]
-        return cls(domain, Xd, Yd, Wd, metas, n, session)
+        table = cls(domain, Xd, Yd, Wd, metas, n, session)
+        # the HBM account (obs/prof.py): the arrays put here are a ledger
+        # entry for as long as the table lives, and the put is a mark
+        prof.ledger_set_owned("tables", table, (Xd, Yd, Wd))
+        prof.hbm_mark("table_put")
+        return table
 
     @classmethod
     def from_arrays(cls, X, Y=None, *, attr_names=None, class_name="y",

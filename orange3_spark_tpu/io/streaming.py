@@ -828,9 +828,14 @@ class _DeviceCache:
         weakref.finalize(self, prof.ledger_release_on_gc, "cache_chunks",
                          self.ledger_key)
 
-    def _ledger_sync(self) -> None:
+    def _ledger_sync(self, added: tuple | None = None) -> None:
+        """The entry's bytes, and the cached arrays themselves (a census
+        of the live arrays names them by identity): ``added`` joins those
+        the entry has, else the whole list takes their place."""
         prof.ledger_set("cache_chunks", self.ledger_key,
-                        self.chip_nbytes, self.nbytes)
+                        self.chip_nbytes, self.nbytes,
+                        arrays=self.batches if added is None else added,
+                        extend=added is not None)
 
     def offer(self, batch: tuple) -> None:
         if not self.enabled:
@@ -859,7 +864,7 @@ class _DeviceCache:
             self.batches.append(batch)
             self.nbytes += sz
             self.chip_nbytes += prof.tree_chip_bytes(batch)
-            self._ledger_sync()
+            self._ledger_sync(added=batch)
         else:
             if self.first_miss is None:
                 self.first_miss = self.offered - 1

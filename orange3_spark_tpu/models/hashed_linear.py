@@ -774,11 +774,11 @@ class HashedLinearModel(Model):
         salts = jnp.asarray(self.salts)
         kind = _row_loss_kind(p)
         tot = None
-        with span("evaluate"):
+        with span("evaluate", hbm=True):
             for i, chunk in enumerate(device_chunks):
                 Xd, n_valid, yd, wd = chunk[:4]
                 count_dispatch()
-                with span("eval_chunk", i):
+                with span("eval_chunk", i, hbm=True):
                     out = _hashed_eval_chunk(
                         self.theta, Xd, n_valid, yd, wd, salts,
                         loss_kind=kind, n_dims=p.n_dims, n_dense=p.n_dense,
@@ -1320,6 +1320,11 @@ class StreamingHashedLinearEstimator(Estimator):
         # classification + the five-way wall decomposition; None under
         # OTPU_PROF=0 (every downstream hook no-ops on the contextvar)
         acc = prof.begin_fit()
+        # the HBM account (obs/prof.py): a mark here, before the state is
+        # made, closes the interval since the last fit's last mark (what
+        # the caller did between fits, what the last job left) and opens
+        # this fit's; every span below built with hbm=True marks as it closes
+        prof.hbm_mark("between_fits", first=True)
         session = session or TpuSession.active()
         k = _effective_k(p)
         n_cols = _chunk_cols(p)
@@ -1332,7 +1337,15 @@ class StreamingHashedLinearEstimator(Estimator):
         # growth. Re-set to theta-only at fit end (slots die with the
         # fit); released when the fitted model itself dies.
         state_key = f"hashed-{next(_FIT_LEDGER_SEQ)}"
-        prof.ledger_set_tree("model_state", state_key, (theta, opt_state))
+
+        def state_now():
+            """What the entry holds at this moment: every step donates
+            the state and hands back new arrays (asked at a census)."""
+            return theta, opt_state
+
+        prof.ledger_set_tree("model_state", state_key, (theta, opt_state),
+                             now=state_now)
+        prof.hbm_mark("model_state")
         # what this fit runs on: the mesh's shape and where each table
         # stands (gauge otpu_mesh_devices + one "mesh" event in the trace)
         prof.note_mesh(session.mesh, **_table_specs(theta, opt_state))
@@ -1585,7 +1598,7 @@ class StreamingHashedLinearEstimator(Estimator):
         def run_step(dev_chunk):
             nonlocal theta, opt_state, n_steps, last_loss
             Xd, n_valid, yd, wd = dev_chunk
-            with span("chunk", n_steps):
+            with span("chunk", n_steps, hbm=True):
                 theta, opt_state, loss = _hashed_step(
                     theta, opt_state, Xd, n_valid, yd, wd, salts, reg, lr,
                     l1, **static_kw,
@@ -1802,7 +1815,7 @@ class StreamingHashedLinearEstimator(Estimator):
                         run_step(dev_chunk)
             # non-finite guard (resilience/numerics.py) BEFORE the save:
             # a divergent epoch raises typed, never checkpoints NaN state
-            with span("finite_check", final=False):
+            with span("finite_check", final=False, hbm=True):
                 check_finite_training(
                     last_loss, theta, epoch=epoch, chunk=n_steps,
                     estimator="StreamingHashedLinearEstimator")
@@ -1849,7 +1862,7 @@ class StreamingHashedLinearEstimator(Estimator):
                     # resume-at-completion edge
                     n_steps += n_rep * spe
                     break
-                with stage("replay_stack") as stacked:
+                with stage("replay_stack", hbm=True) as stacked:
                     # stack the WHOLE chunk tuple as one pytree
                     stacks = jax.tree.map(
                         lambda *xs: jnp.stack(xs), *cache.batches)
@@ -1866,7 +1879,7 @@ class StreamingHashedLinearEstimator(Estimator):
                     prof.ledger_set_tree("replay_plans", rp_key, stacks)
                 hoist = _hoist_sort_keys(static_kw, pad_rows, p.n_cat, spe,
                                          cache.nbytes, cache_device_bytes)
-                with stage("replay", n_epochs=n_rep,
+                with stage("replay", hbm=True, n_epochs=n_rep,
                            steps=n_rep * spe) as replayed:
                     if p.replay_granularity == "epoch":
                         # one n_epochs=1 scan dispatch per epoch over the
@@ -1879,7 +1892,8 @@ class StreamingHashedLinearEstimator(Estimator):
 
                         def _disp(n_ep):
                             nonlocal theta, opt_state, sorts_saved
-                            with span("replay_dispatch", n_epochs=n_ep):
+                            with span("replay_dispatch", hbm=True,
+                                      n_epochs=n_ep):
                                 theta, opt_state, chunk_losses = \
                                     _hashed_replay_epochs(
                                         theta, opt_state, stacks, salts,
@@ -1912,7 +1926,7 @@ class StreamingHashedLinearEstimator(Estimator):
                         n_steps += n_rep * spe
                     del stacks
                     prof.ledger_release("replay_plans", rp_key)
-                    with stage("replay_drain") as drained:
+                    with stage("replay_drain", hbm=True) as drained:
                         jax.block_until_ready(last_loss)
                 # the drain blocks on the WHOLE fused replay — it is the
                 # one place the driver observes the replay's device
@@ -1930,7 +1944,7 @@ class StreamingHashedLinearEstimator(Estimator):
             spill.delete()
         # fused replay breaks out past the per-epoch guard: final check
         # (loss AND theta — a last-step divergence only shows in theta)
-        with span("finite_check", final=True):
+        with span("finite_check", final=True, hbm=True):
             check_finite_training(
                 last_loss, theta, epoch=p.epochs - 1, chunk=n_steps,
                 final=True, estimator="StreamingHashedLinearEstimator")
@@ -1938,7 +1952,7 @@ class StreamingHashedLinearEstimator(Estimator):
             # settle the lazy decay the table still owes (rows untouched
             # since their last step) so the returned model equals the
             # dense schedule's — predictions/serving read theta directly
-            with span("finalize"):
+            with span("finalize", hbm=True):
                 theta = finalize_lazy_decay(
                     theta, opt_state, p.step_size, p.reg_param,
                     optim_resolved)
@@ -2012,6 +2026,7 @@ class StreamingHashedLinearEstimator(Estimator):
         # (the abort guard hands ownership to the model's finalizer)
         _state_guard.finalizer.detach()
         prof.ledger_set_tree("model_state", state_key, theta)
+        prof.hbm_mark("model_handover")
         import weakref
 
         weakref.finalize(model, prof.ledger_release_on_gc, "model_state",

@@ -30,7 +30,9 @@ Six pieces, one kill-switch (``OTPU_OBS=0``):
 * ``prof``      — the goodput & memory attribution plane (its own
   kill-switch, ``OTPU_PROF``): five-way step-time decomposition with
   per-epoch bottleneck classification, the named device-memory ledger
-  (``otpu_device_bytes{owner=}``), and on-demand deep-profile capture
+  (``otpu_device_bytes{owner=}``) with its account against the allocator
+  (marks where spans close, the interval that set the peak, a census of
+  the live arrays), and on-demand deep-profile capture
   (``POST /debug/profile``) — docs/observability.md §goodput.
 """
 

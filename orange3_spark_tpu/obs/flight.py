@@ -210,9 +210,7 @@ def collect_bundle(reason: str, error: BaseException | None = None,
         # and the ledger itself is cheap to snapshot (one lock, no IO)
         from orange3_spark_tpu.obs.prof import LEDGER
 
-        dm = LEDGER.snapshot()
-        dm["reconciliation"] = LEDGER.reconcile()
-        bundle["device_memory"] = dm
+        bundle["device_memory"] = LEDGER.snapshot()
     except Exception:  # noqa: BLE001 - diagnostics only
         pass
     bundle.update(_control_plane(context))

@@ -36,13 +36,21 @@ behavior bitwise — no accounting, no ledger ticks, deep capture refused):
   (codec-aware bytes, the owner ``cache_chunks``), model/optimizer
   state (``model_state``), serving ``ExecutableCache`` entries
   (``serve_executables``, bytes best-effort via the executable's
-  ``memory_analysis``), and the fused replay's stack of the cache
-  (``replay_plans``). Live bytes per owner ride
-  ``otpu_device_bytes{owner=}``; per-fit peak watermarks land in the
-  report's ``device_memory`` section; :meth:`reconcile` compares the
-  ledger total against ``jax.live_arrays()`` and the backend's
-  ``memory_stats()`` where available — the delta is *reported*, never
-  asserted (JAX holds internal buffers the ledger doesn't name).
+  ``memory_analysis``), the fused replay's stack of the cache
+  (``replay_plans``), tables put from the host (``tables``) and the
+  table a staged canvas hands back (``canvas_out``). Live bytes per
+  owner ride ``otpu_device_bytes{owner=}``; per-fit peak watermarks land
+  in the report's ``device_memory`` section. **The HBM account**: where a
+  fit closes a span built with ``hbm=True`` the ledger takes a *mark*
+  (:func:`hbm_mark`) — the allocator's own ``memory_stats()`` of the
+  fullest device beside the ledger's total, never a wait for the
+  device — names the interval in which the allocator's peak rose after
+  the span that closed it (``high_water``: the bytes that lived only
+  inside it are its ``transient_bytes``), and, when the peak has risen
+  64 MiB over the last one, takes a *census* of ``jax.live_arrays()`` on
+  that device: every array under the owner whose entry was handed that
+  very array, ``unnamed`` otherwise, and what the allocator holds beyond
+  them as ``runtime_held_bytes``.
 
 * **On-demand deep capture** (:func:`capture`) — ``POST
   /debug/profile?duration_ms=`` on the obs server (loopback only,
@@ -59,8 +67,10 @@ behavior bitwise — no accounting, no ledger ticks, deep capture refused):
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
+import itertools
 import json
 import logging
 import os
@@ -79,6 +89,7 @@ __all__ = [
     "DeviceMemoryLedger",
     "GoodputAccountant",
     "LEDGER",
+    "LiveArraysAllocator",
     "PROF_SCHEMA_VERSION",
     "STAGES",
     "attach_fit_report",
@@ -89,9 +100,11 @@ __all__ = [
     "end_fit",
     "force_disabled",
     "force_enabled",
+    "hbm_mark",
     "last_goodput",
     "ledger_release",
     "ledger_set",
+    "ledger_set_owned",
     "ledger_set_tree",
     "note_input_wait",
     "note_mesh",
@@ -131,7 +144,8 @@ _M_GOODPUT = REGISTRY.gauge(
 _M_DEVICE_BYTES = REGISTRY.gauge(
     "otpu_device_bytes",
     "live device-resident bytes per ledger owner (cache_chunks / "
-    "model_state / serve_executables / replay_plans)")
+    "model_state / serve_executables / replay_plans / tables / "
+    "canvas_out)")
 _M_MESH_DEVICES = REGISTRY.gauge(
     "otpu_mesh_devices",
     "devices along each axis of the mesh the last started fit ran on "
@@ -444,12 +458,93 @@ def last_goodput() -> dict | None:
 
 
 # ===================================================== device-memory ledger
+#: a census is taken at a mark whose live peak stands this far above the
+#: peak at the last census: a few times in a warm job, never in a steady one
+CENSUS_RISE_BYTES = 64 << 20
+#: groups a census keeps, largest first
+CENSUS_GROUPS = 16
+#: marks kept a fit, the newest (the high-water interval is found as the
+#: marks are taken and does not need the list)
+MARKS_PER_FIT = 128
+
+
+def _device_stats() -> list | None:
+    """The allocator's own bookkeeping, one dict a local device
+    (``bytes_in_use``, ``peak_bytes_in_use``, ``peak_bytes_reserved``):
+    read on the host, no wait for the device. None where a backend keeps
+    none (the CPU)."""
+    import jax
+
+    out = []
+    for d in jax.local_devices():
+        st = d.memory_stats()
+        if not st:
+            return None
+        out.append(st)
+    return out
+
+
+def _shard_bytes(x) -> int:
+    """What one device holds of a jax array: one shard of
+    ``sharding.shard_shape`` (a replicated axis repeats the shard, a
+    sharded one divides it) AS THE DEVICE LAYS IT OUT — an ``f32[N, 5]``
+    that the TPU pads to eight sublanes takes the bytes of ``[N, 8]``, and
+    the allocator counts those. The buffer's size is known when it is
+    enqueued: no wait. A backend that does not say counts the shard's
+    elements."""
+    import math
+
+    sharding = x.sharding
+    try:
+        return x.on_device_size_in_bytes() // len(sharding.device_set)
+    except Exception:  # noqa: BLE001 - not every backend / array kind says
+        return math.prod(sharding.shard_shape(x.shape)) * x.dtype.itemsize
+
+
+def _live_by_device() -> dict:
+    """Every live array under each device that holds a shard of it, with
+    that shard's bytes (:func:`tree_chip_bytes`' rule):
+    ``{device: [(array, bytes)]}``. Shapes and shardings only: no wait."""
+    import jax
+
+    out: dict = {}
+    for a in jax.live_arrays():
+        nbytes = _shard_bytes(a)
+        for d in a.sharding.device_set:
+            out.setdefault(d, []).append((a, nbytes))
+    return out
+
+
+class LiveArraysAllocator:
+    """Stands in for ``memory_stats()`` where a backend keeps none (the
+    CPU: tests, rehearsals) — ``LEDGER.allocator = LiveArraysAllocator()``:
+    a device's bytes in use are the per-chip sum of ``jax.live_arrays()``,
+    its live peak their running maximum over the calls, and there is no
+    temp. A walk of the live arrays a call: not for a hot path."""
+
+    def __init__(self):
+        self._peaks: dict = {}
+
+    def __call__(self) -> list:
+        import jax
+
+        live = _live_by_device()
+        out = []
+        for d in jax.local_devices():
+            in_use = sum(n for _a, n in live.get(d, ()))
+            peak = self._peaks[d] = max(self._peaks.get(d, 0), in_use)
+            out.append({"bytes_in_use": in_use, "peak_bytes_in_use": peak,
+                        "peak_bytes_reserved": 0})
+        return out
+
+
 class DeviceMemoryLedger:
     """Named device-resident allocations: ``set(owner, name, nbytes)`` /
     ``release(owner, name)``, live bytes per owner on
     ``otpu_device_bytes{owner=}``, a running peak, per-fit peaks via
-    :meth:`watermark`, and best-effort reconciliation against the JAX
-    runtime. The bytes are PER CHIP: a sharded array counts by what its
+    :meth:`watermark`, and the account against the runtime's allocator
+    (:meth:`mark`: marks, the high-water interval, the census). The bytes
+    are PER CHIP: a sharded array counts by what its
     shards take on the fullest device (:func:`tree_chip_bytes`), since a
     chip runs out of its own 16 GB and not of the mesh's sum; the array's
     global size is kept beside it (``global_nbytes=``, ``peak_global()``,
@@ -462,6 +557,7 @@ class DeviceMemoryLedger:
     def __init__(self):
         self._lock = threading.Lock()
         self._entries: dict[tuple[str, str], int] = {}
+        self._owners: dict[str, int] = {}
         self._total = 0
         self._peak = 0
         # the same entries by their arrays' global size (see the class
@@ -469,6 +565,12 @@ class DeviceMemoryLedger:
         self._global: dict[tuple[str, str], int] = {}
         self._total_global = 0
         self._peak_global = 0
+        # the arrays an entry was handed, weakly (the census names a live
+        # array by them), and where an owner trades its arrays for new ones
+        # every step, a weak reference to the function that says which it
+        # holds now: neither keeps an array alive
+        self._held: dict[tuple[str, str], list] = {}
+        self._held_now: dict[tuple[str, str], object] = {}
         self._watermarks: dict[int, "DeviceMemoryLedger._Watermark"] = {}
         self._wm_seq = 0
         # GC-finalizer inbox: weakref.finalize callbacks run
@@ -477,9 +579,27 @@ class DeviceMemoryLedger:
         # lock, since the methods allocate while holding it. Finalizers
         # therefore only append here (deque.append is atomic, no lock)
         # and every ledger operation drains the inbox at lock entry.
-        import collections
+        self._pending: collections.deque = collections.deque()
+        #: where a mark reads the allocator: a function -> one stats dict a
+        #: local device, or None (then no mark is taken). The tests' scripted
+        #: allocators and :class:`LiveArraysAllocator` go here.
+        self.allocator = _device_stats
+        self._reset_marks_locked()
 
-        self._pending: "collections.deque" = collections.deque()
+    def _reset_marks_locked(self) -> None:
+        self._marks = collections.deque(maxlen=MARKS_PER_FIT)
+        self._marks_last: list = []
+        self._fit_seq = 0
+        self._mark_seq = 0
+        self._last_mark: dict | None = None
+        # (bytes in use, live peak, temp peak) a device at the last mark
+        self._prev_stats: list = []
+        self._high_since_mark = self._total
+        self._high_water: dict | None = None
+        self._high_water_temp: dict | None = None
+        self._census: dict | None = None
+        self._census_peak = 0
+        self.censuses_taken = 0
 
     # ------------------------------------------- finalizer-safe deferral
     def defer_release(self, owner: str, name: str) -> None:
@@ -498,17 +618,23 @@ class DeviceMemoryLedger:
             except IndexError:
                 break
             if kind == "release":
-                prev = self._entries.pop((a, b), None)
-                if prev is not None:
-                    self._total -= prev
-                    self._total_global -= self._global.pop((a, b), prev)
+                if self._forget_locked((a, b)) is not None:
                     touched.add(a)
             else:
                 self._watermarks.pop(a, None)
         for owner in touched:
-            owner_total = sum(v for (o, _n), v in self._entries.items()
-                              if o == owner)
-            _M_DEVICE_BYTES.set(owner_total, owner=owner)
+            _M_DEVICE_BYTES.set(self._owners[owner], owner=owner)
+
+    def _forget_locked(self, key: tuple[str, str]) -> int | None:
+        """Drop one entry with everything kept beside it; -> its bytes."""
+        prev = self._entries.pop(key, None)
+        if prev is not None:
+            self._total -= prev
+            self._total_global -= self._global.pop(key, prev)
+            self._owners[key[0]] -= prev
+            self._held.pop(key, None)
+            self._held_now.pop(key, None)
+        return prev
 
     class _Watermark:
         """Max ledger total observed since creation (a fit's HBM peak)."""
@@ -542,12 +668,27 @@ class DeviceMemoryLedger:
     # (and the ROADMAP-3 autoscaler) reads until the owner next moves.
     # Lock order is ledger -> metric; nothing takes them the other way.
     def set(self, owner: str, name: str, nbytes: int,
-            global_nbytes: int | None = None) -> None:
+            global_nbytes: int | None = None, *, arrays=None,
+            extend: bool = False, now=None) -> None:
+        """``arrays``: the pytree the bytes were counted from — the entry
+        keeps weak references to its leaves, by which a census knows them
+        from strangers of the same shape (``extend=True`` adds them to
+        those it has; else they take their place, and an entry set from a
+        byte count alone holds none). ``now``: for an owner that trades
+        its arrays for new ones at every step (a donated state), a
+        function -> the pytree it holds at this moment, asked at a census
+        only and itself held weakly: it lives with its owner's frame."""
         if not prof_enabled():
             return
+        import weakref
+
+        import jax
+
         nbytes = max(int(nbytes), 0)
         global_nbytes = (nbytes if global_nbytes is None
                          else max(int(global_nbytes), 0))
+        refs = [weakref.ref(x) for x in jax.tree.leaves(arrays)
+                if isinstance(x, jax.Array)]
         with self._lock:
             self._drain_pending_locked()
             key = (owner, name)
@@ -556,25 +697,27 @@ class DeviceMemoryLedger:
             self._total_global += global_nbytes - self._global.get(key, prev)
             self._entries[key] = nbytes
             self._global[key] = global_nbytes
+            if extend:
+                self._held.setdefault(key, []).extend(refs)
+            else:
+                self._held[key] = refs
+            if now is not None:
+                self._held_now[key] = weakref.ref(now)
+            else:
+                self._held_now.pop(key, None)
             self._peak = max(self._peak, self._total)
             self._peak_global = max(self._peak_global, self._total_global)
+            self._high_since_mark = max(self._high_since_mark, self._total)
             for wm in self._watermarks.values():
                 wm.high = max(wm.high, self._total)
-            owner_total = sum(v for (o, _n), v in self._entries.items()
-                              if o == owner)
-            _M_DEVICE_BYTES.set(owner_total, owner=owner)
+            self._owners[owner] = self._owners.get(owner, 0) + nbytes - prev
+            _M_DEVICE_BYTES.set(self._owners[owner], owner=owner)
 
     def release(self, owner: str, name: str) -> None:
         with self._lock:
             self._drain_pending_locked()
-            prev = self._entries.pop((owner, name), None)
-            if prev is None:
-                return
-            self._total -= prev
-            self._total_global -= self._global.pop((owner, name), prev)
-            owner_total = sum(v for (o, _n), v in self._entries.items()
-                              if o == owner)
-            _M_DEVICE_BYTES.set(owner_total, owner=owner)
+            if self._forget_locked((owner, name)) is not None:
+                _M_DEVICE_BYTES.set(self._owners[owner], owner=owner)
 
     # ------------------------------------------------------------- views
     def get(self, owner: str, name: str, *,
@@ -628,6 +771,15 @@ class DeviceMemoryLedger:
             total, peak = self._total, self._peak
             total_global, peak_global = (self._total_global,
                                          self._peak_global)
+            # the account against the allocator: the marks of the last
+            # finished fit and of the current one, oldest first, the
+            # interval that set each of the allocator's two peaks, and the
+            # last census (all None / empty where no mark was taken)
+            marks = [dict(m) for m in self._marks_last]
+            marks += [dict(m) for m in self._marks]
+            high_water, high_water_temp, census = (
+                self._high_water, self._high_water_temp, self._census)
+            censuses_taken = self.censuses_taken
         dropped = max(len(entries) - max_entries, 0)
         out = {
             "prof_schema": PROF_SCHEMA_VERSION,
@@ -637,37 +789,161 @@ class DeviceMemoryLedger:
             "total_global_bytes": total_global,
             "peak_global_bytes": peak_global,
             "entries": entries[:max_entries],
+            "marks": marks,
+            "high_water": high_water,
+            "high_water_temp": high_water_temp,
+            "census": census,
+            "censuses_taken": censuses_taken,
         }
         if dropped:
             out["entries_truncated"] = dropped
         return out
 
-    def reconcile(self) -> dict:
-        """Ledger total vs what the runtime reports — DELTA reported,
-        never asserted: ``jax.live_arrays()`` includes every array the
-        process holds (constants, RNG keys, results the caller kept) and
-        backend ``memory_stats()`` exists only on some runtimes."""
-        out: dict = {"ledger_bytes": self.total(),
-                     "jax_live_bytes": None,
-                     "backend_bytes_in_use": None,
-                     "delta_vs_live_bytes": None}
+    # ------------------------------------------- the account (HBM marks)
+    def mark(self, name: str, *, first: bool = False) -> dict | None:
+        """Read the allocator where the program stands (``name``: the span
+        it has just closed) and account for the interval since the previous
+        mark. One record: the fullest device's ``bytes_in_use``,
+        ``peak_bytes_in_use`` (live buffers' high-water mark) and
+        ``peak_bytes_reserved`` (program temp's), the ledger's total and
+        the highest total since the previous mark. Where a peak rose in the
+        interval, the interval is kept as the one that set it
+        (``high_water`` / ``high_water_temp``: ``span`` this mark's name,
+        ``since`` the previous one's). ``first=True`` opens a
+        fit: the marks so far become the last fit's. No mark where the
+        allocator tells nothing (``memory_stats()`` is None on the CPU).
+        NEVER waits for the device; rides the spans (off with
+        ``OTPU_OBS=0``) and the ``OTPU_PROF`` switch; diagnostics only, so
+        it never raises."""
+        if not (_trace.enabled() and prof_enabled()):
+            return None
         try:
-            import jax
+            stats = self.allocator()
+            if stats is None:       # a backend whose allocator tells nothing
+                return None
+            stats = [(int(st.get("bytes_in_use", 0)),
+                      int(st.get("peak_bytes_in_use", 0)),
+                      int(st.get("peak_bytes_reserved", 0))) for st in stats]
+            # the fullest device by the harness's rule (both peaks, the
+            # later device on a tie)
+            dev = max(range(len(stats)),
+                      key=lambda i: (stats[i][1] + stats[i][2], i))
+            in_use, peak, temp = stats[dev]
+            with self._lock:
+                self._drain_pending_locked()
+                if first and self._marks:
+                    self._marks_last = list(self._marks)
+                    self._marks.clear()
+                    self._fit_seq += 1
+                before = self._last_mark
+                in_use_a, peak_a, temp_a = (
+                    self._prev_stats[dev] if dev < len(self._prev_stats)
+                    else (0, 0, 0))
+                self._mark_seq += 1
+                rec = {"n": self._mark_seq, "name": name,
+                       "fit": self._fit_seq, "t_ns": time.perf_counter_ns(),
+                       "device": dev, "bytes_in_use": in_use,
+                       "peak_bytes_in_use": peak,
+                       "peak_bytes_reserved": temp,
+                       "ledger_bytes": self._total,
+                       "ledger_high_bytes": max(self._high_since_mark,
+                                                self._total)}
+                self._high_since_mark = self._total
+                if peak > peak_a:
+                    # named at the fuller of the interval's two marks;
+                    # what the peak stood above both lived inside the span
+                    after = in_use >= in_use_a or before is None
+                    named = (rec if after else before)["ledger_bytes"]
+                    fuller = max(in_use, in_use_a)
+                    self._high_water = {
+                        "mark": rec["n"], "span": name,
+                        "since": before["name"] if before else None,
+                        "fit": rec["fit"],
+                        "device": dev, "peak_bytes_in_use": peak,
+                        "rise_bytes": peak - peak_a,
+                        "bytes_in_use_before": in_use_a,
+                        "bytes_in_use_after": in_use,
+                        "fuller_mark": "after" if after else "before",
+                        "named_bytes": named,
+                        "unnamed_bytes": fuller - named,
+                        "transient_bytes": peak - fuller,
+                        "ledger_high_bytes": rec["ledger_high_bytes"]}
+                if temp > temp_a:
+                    self._high_water_temp = {
+                        "mark": rec["n"], "span": name,
+                        "since": before["name"] if before else None,
+                        "fit": rec["fit"],
+                        "device": dev, "peak_bytes_reserved": temp,
+                        "rise_bytes": temp - temp_a}
+                self._prev_stats = stats
+                self._last_mark = rec
+                self._marks.append(rec)
+                census_due = peak - self._census_peak > CENSUS_RISE_BYTES
+                if census_due:
+                    self._census_peak = peak
+            if census_due:
+                self._take_census(rec)
+            _trace.instant("hbm_mark", span=name, **{
+                k: rec[k] for k in ("bytes_in_use", "peak_bytes_in_use",
+                                    "peak_bytes_reserved", "ledger_bytes",
+                                    "ledger_high_bytes")})
+            return rec
+        except Exception as e:  # noqa: BLE001 - the account is best-effort
+            log.debug("prof: hbm mark %r failed (%s: %s)", name,
+                      type(e).__name__, e)
+            return None
 
-            live = sum(getattr(a, "nbytes", 0) for a in jax.live_arrays())
-            out["jax_live_bytes"] = int(live)
-            out["delta_vs_live_bytes"] = int(live) - out["ledger_bytes"]
-            stats = None
-            devs = jax.local_devices()
-            if devs:
-                ms = getattr(devs[0], "memory_stats", None)
-                stats = ms() if callable(ms) else None
-            if stats:
-                out["backend_bytes_in_use"] = int(
-                    stats.get("bytes_in_use", 0))
-        except Exception:  # noqa: BLE001 - reconciliation is best-effort
-            pass
-        return out
+    def _take_census(self, rec: dict) -> None:
+        """Walk ``jax.live_arrays()`` once and account for the mark's
+        device: each array by its shard's bytes there, under the owner
+        whose entry was handed THAT array (``unnamed`` otherwise), grouped
+        by (owner, dtype, shard shape); what the allocator holds beyond
+        them (in-flight outputs, buffers a donated call has yet to hand
+        back) is ``runtime_held_bytes``."""
+        import jax
+
+        device = jax.local_devices()[rec["device"]]
+        holders: dict[int, tuple] = {}      # id -> (array, owner): alive
+        with self._lock:
+            for (owner, _name), refs in self._held.items():
+                for ref in refs:
+                    x = ref()
+                    if x is not None:
+                        holders[id(x)] = (x, owner)
+            asked = [(owner, ref()) for (owner, _name), ref
+                     in self._held_now.items()]
+        for owner, fn in asked:
+            if fn is not None:
+                for x in jax.tree.leaves(fn()):
+                    holders[id(x)] = (x, owner)
+        owners: dict[str, int] = {}
+        groups: dict[tuple, list] = {}
+        live = n_arrays = 0
+        for a, nbytes in _live_by_device().get(device, ()):
+            held = holders.get(id(a))
+            owner = held[1] if held and held[0] is a else "unnamed"
+            owners[owner] = owners.get(owner, 0) + nbytes
+            shape = tuple(a.sharding.shard_shape(a.shape))
+            g = groups.setdefault((owner, str(a.dtype), shape), [0, 0])
+            g[0] += 1
+            g[1] += nbytes
+            live += nbytes
+            n_arrays += 1
+        ranked = sorted(groups.items(), key=lambda kv: -kv[1][1])
+        census = {
+            "mark": rec["n"], "span": rec["name"], "fit": rec["fit"],
+            "device": rec["device"], "bytes_in_use": rec["bytes_in_use"],
+            "live_bytes": live, "arrays": n_arrays,
+            "runtime_held_bytes": rec["bytes_in_use"] - live,
+            "owners": dict(sorted(owners.items())),
+            "groups": [{"owner": o, "dtype": dt, "shape": list(sh),
+                        "count": c, "bytes": b}
+                       for (o, dt, sh), (c, b) in ranked[:CENSUS_GROUPS]],
+            "groups_dropped": max(len(ranked) - CENSUS_GROUPS, 0),
+        }
+        with self._lock:
+            self._census = census
+            self.censuses_taken += 1
 
     def clear(self) -> None:
         """Tests only: forget every entry (gauges re-zero per owner)."""
@@ -676,8 +952,12 @@ class DeviceMemoryLedger:
             owners = {o for (o, _n) in self._entries}
             self._entries.clear()
             self._global.clear()
+            self._owners.clear()
+            self._held.clear()
+            self._held_now.clear()
             self._total = self._total_global = 0
             self._peak = self._peak_global = 0
+            self._reset_marks_locked()
             for o in owners:
                 _M_DEVICE_BYTES.set(0, owner=o)
 
@@ -726,38 +1006,58 @@ def tree_device_bytes(tree) -> int:
 
 def tree_chip_bytes(tree) -> int:
     """What a pytree's array leaves take on ONE chip — the ledger's sizing
-    rule. A jax array holds one shard of ``sharding.shard_shape`` on every
-    device it lives on (a replicated axis repeats the shard, a sharded one
-    divides it), so its per-chip bytes are that shard's; anything else, and
-    an array on one device, counts its ``nbytes`` as before."""
-    import math
-
+    rule. A jax array counts by the shard one device holds of it, as the
+    device lays it out (:func:`_shard_bytes`); anything else counts its
+    ``nbytes`` as before."""
     import jax
 
-    total = 0
-    for x in jax.tree.leaves(tree):
-        sharding = getattr(x, "sharding", None)
-        if sharding is None:
-            total += getattr(x, "nbytes", 0)
-        else:
-            total += (math.prod(sharding.shard_shape(x.shape))
-                      * x.dtype.itemsize)
-    return int(total)
+    return int(sum(_shard_bytes(x) if isinstance(x, jax.Array)
+                   else getattr(x, "nbytes", 0)
+                   for x in jax.tree.leaves(tree)))
 
 
-def ledger_set_tree(owner: str, name: str, tree) -> None:
+def ledger_set_tree(owner: str, name: str, tree, *, now=None) -> None:
     """One entry for a pytree of device arrays: per chip, with its global
-    size beside it."""
-    LEDGER.set(owner, name, tree_chip_bytes(tree), tree_device_bytes(tree))
+    size beside it, and the arrays themselves known to the entry (weakly:
+    see ``DeviceMemoryLedger.set`` for ``now``)."""
+    LEDGER.set(owner, name, tree_chip_bytes(tree), tree_device_bytes(tree),
+               arrays=tree, now=now)
 
 
 def ledger_set(owner: str, name: str, nbytes: int,
-               global_nbytes: int | None = None) -> None:
-    LEDGER.set(owner, name, nbytes, global_nbytes)
+               global_nbytes: int | None = None, *, arrays=None,
+               extend: bool = False) -> None:
+    LEDGER.set(owner, name, nbytes, global_nbytes, arrays=arrays,
+               extend=extend)
 
 
 def ledger_release(owner: str, name: str) -> None:
     LEDGER.release(owner, name)
+
+
+_OWNED_SEQ = itertools.count()
+
+
+def ledger_set_owned(owner: str, obj, tree) -> None:
+    """An entry of its own for ``tree`` that goes when ``obj`` dies (a
+    table and its arrays): set here, released by ``obj``'s finalizer."""
+    if not prof_enabled():
+        return
+    import weakref
+
+    name = f"{type(obj).__name__}-{next(_OWNED_SEQ)}"
+    ledger_set_tree(owner, name, tree)
+    weakref.finalize(obj, LEDGER.defer_release, owner, name)
+
+
+def hbm_mark(name: str, *, first: bool = False) -> None:
+    """One mark of the device-memory ledger (``DeviceMemoryLedger.mark``):
+    what a span built with ``hbm=True`` calls as it closes, and what a site
+    without a span of its own calls by hand."""
+    LEDGER.mark(name, first=first)
+
+
+_trace._hbm_mark = hbm_mark
 
 
 def attach_fit_report(report, acc: GoodputAccountant | None, *,
@@ -776,7 +1076,6 @@ def attach_fit_report(report, acc: GoodputAccountant | None, *,
     result = acc.finish(encode_s=encode_s)
     dm = LEDGER.snapshot()
     dm["peak_bytes_fit"] = result["peak_device_bytes"]
-    dm["reconciliation"] = LEDGER.reconcile()
     if cache_key is not None:
         dm["cache_entry_bytes"] = LEDGER.get("cache_chunks", cache_key,
                                              global_size=True)
@@ -870,8 +1169,9 @@ def _capture_session():
 def capture_snapshot(reason: str, duration_ms: float | None = None,
                      **extra) -> dict:
     """The JSON half of a deep capture: the last goodput decomposition,
-    the ledger table + reconciliation, the full registry and the
-    resolved knob table — everything a profile needs for context."""
+    the ledger table with its account against the allocator (marks,
+    high-water interval, census), the full registry and the resolved knob
+    table — everything a profile needs for context."""
     snap = {
         "prof_schema": PROF_SCHEMA_VERSION,
         "written_at": time.time(),
@@ -880,7 +1180,6 @@ def capture_snapshot(reason: str, duration_ms: float | None = None,
         "duration_ms": duration_ms,
         "goodput": last_goodput(),
         "ledger": LEDGER.snapshot(),
-        "reconciliation": LEDGER.reconcile(),
         "registry": REGISTRY.snapshot(),
         "knobs": knobs.resolved(),
     }

@@ -45,7 +45,9 @@ Span taxonomy (docs/observability.md): ``fit`` ⊃ ``epoch`` ⊃ ``chunk`` ⊃
 its consumer, ``serve``/``mb_flush``/``serve_dispatch`` on the serving
 path, ``timed:*`` for ``@timed`` functions; instants ``retry``/``fault``/
 ``wedge``/``crc_failure``/``shed``/``divergence``/``brownout`` from the
-resilience subsystem; flows ``req`` across the micro-batcher's threads.
+resilience subsystem and ``hbm_mark`` from the device-memory ledger (one
+as each span built with ``hbm=True`` closes: the allocator's bytes on the
+spans' own timeline); flows ``req`` across the micro-batcher's threads.
 
 Ring-event layout (consumed by flight.py and the tests):
 ``(ph, name, t0_ns, dur_ns, thread_ident, args, trace_id, span_id,
@@ -102,6 +104,10 @@ _ANNOTATION = jax.profiler.TraceAnnotation
 #: the twin annotation's name is this + the ring name: what a reduction of
 #: a profiler trace selects the program's spans by
 ANNOTATION_PREFIX = "otpu:"
+#: what a span built with ``hbm=True`` calls with its name once it has
+#: closed: ``obs.prof.hbm_mark``, set by that module when it is imported
+#: (it imports this one). None until then: no mark.
+_hbm_mark = None
 
 
 def enabled() -> bool:
@@ -241,14 +247,16 @@ def _open_stack() -> list:
 
 
 class _Span:
-    __slots__ = ("name", "args", "t0", "ann", "uniq",
+    __slots__ = ("name", "args", "t0", "ann", "uniq", "hbm",
                  "trace_id", "span_id", "parent_id", "_buf", "seconds")
 
-    def __init__(self, name: str, args: dict | None, uniq: bool = False):
+    def __init__(self, name: str, args: dict | None, uniq: bool = False,
+                 hbm: bool = False):
         self.name = name
         self.args = args
         self.ann = None
         self.uniq = uniq
+        self.hbm = hbm
         self.t0 = None
         self.trace_id = None
         self.span_id = None
@@ -299,24 +307,30 @@ class _Span:
             self.ann.__exit__(*exc)
         if self.uniq:
             _TLS.open.discard(self.name)
+        if self.hbm and _hbm_mark is not None:
+            # after the duration is taken: a mark costs the span nothing
+            _hbm_mark(self.name)
         return False
 
 
-def span(name: str, index=None, unique: bool = False, **args):
+def span(name: str, index=None, unique: bool = False, hbm: bool = False,
+         **args):
     """Context manager timing one named region; ``index`` is shorthand for
     the ``i=`` arg (``span("epoch", 3)``). No-op (shared instance, zero
     allocation) when obs is disabled. ``unique=True`` records only the
     OUTERMOST same-named span per thread — ``Estimator.fit`` brackets a
     streaming ``fit_stream`` that opens its own "fit" span, and a trace
     with fit ⊃ fit would double-count fit time for anyone aggregating by
-    span name."""
+    span name. ``hbm=True``: once the span has closed, the device-memory
+    ledger reads the allocator and names the interval since its previous
+    mark after this span (``obs.prof.hbm_mark``)."""
     if not _enabled:
         return _NULL
     if unique and name in getattr(_TLS, "open", ()):
         return _NULL
     if index is not None:
         args["i"] = index
-    return _Span(name, args or None, uniq=unique)
+    return _Span(name, args or None, uniq=unique, hbm=hbm)
 
 
 def _accumulate(into, key: str, seconds: float) -> None:
@@ -333,8 +347,9 @@ class _Stage(_Span):
 
     __slots__ = ("into", "key")
 
-    def __init__(self, name: str, args: dict | None, into, key):
-        super().__init__(name, args)
+    def __init__(self, name: str, args: dict | None, into, key,
+                 hbm: bool = False):
+        super().__init__(name, args, hbm=hbm)
         self.into, self.key = into, key
 
     def __exit__(self, *exc):
@@ -364,7 +379,8 @@ class _Timer:
         pass
 
 
-def stage(name: str, into=None, key: str | None = None, index=None, **args):
+def stage(name: str, into=None, key: str | None = None, index=None,
+          hbm: bool = False, **args):
     """A :func:`span` whose duration is ALSO added to ``into[key]`` (a
     dict entry, or the attribute ``key`` of an object such as
     ``PipelineStats``) and left on the returned object as ``.seconds``
@@ -372,12 +388,13 @@ def stage(name: str, into=None, key: str | None = None, index=None, **args):
     of the durations of the spans it recorded, read off one clock.
     Unlike ``span`` it always times: with obs off nothing is recorded
     and the accumulator still fills (a caller that wants no timing at
-    all then does not call it)."""
+    all then does not call it). ``hbm=True`` as for :func:`span`: the
+    marks ride the spans, and go with them when obs is off."""
     if not _enabled:
         return _Timer(into, key)
     if index is not None:
         args["i"] = index
-    return _Stage(name, args or None, into, key)
+    return _Stage(name, args or None, into, key, hbm=hbm)
 
 
 def span_iter(name: str, iterable: Iterable) -> Iterator:

@@ -25,6 +25,7 @@ from typing import Callable
 import jax
 
 from orange3_spark_tpu.core.table import TpuTable
+from orange3_spark_tpu.obs import prof
 from orange3_spark_tpu.obs.context import trace_scope
 from orange3_spark_tpu.obs.registry import REGISTRY
 from orange3_spark_tpu.obs.trace import span
@@ -252,11 +253,18 @@ class StagedGraph:
         if self._refit_nodes is None:
             return self._dispatch(replacements)
         with trace_scope("canvas", reuse=True), span("canvas_refit"):
-            with span("canvas_dispatch"):
+            # the HBM account (obs/prof.py): this mark closes what the
+            # caller did since the last refit, the three spans mark as they
+            # close, and the table handed back is a ledger entry while it
+            # lives (the states are a few hundred bytes: no entry)
+            prof.hbm_mark("between_fits", first=True)
+            with span("canvas_dispatch", hbm=True):
                 table, states = self._dispatch(replacements)
-            with span("canvas_drain"):
+            prof.ledger_set_owned("canvas_out", table,
+                                  (table.X, table.Y, table.W))
+            with span("canvas_drain", hbm=True):
                 jax.block_until_ready((table.X, table.Y, table.W, states))
-            with span("canvas_models"):
+            with span("canvas_models", hbm=True):
                 for nid, state in states.items():
                     outs = self._refit_nodes[nid].outputs
                     if outs is None:        # invalidated since staging

@@ -206,6 +206,41 @@ def test_spans_once_a_call_under_one_trace_id(call, tmp_path):
     assert all(e[6] == root[6] for e in staged)
 
 
+def test_refit_marks_its_spans_and_names_its_tables(tmp_path, monkeypatch):
+    """The HBM account of a staged refit (obs/prof.py): a mark before the
+    dispatch and one as each of its three spans closes; the resident table
+    is a ``tables`` entry, the table handed back a ``canvas_out`` entry for
+    as long as it lives, both known to a census by their arrays."""
+    import gc
+
+    from orange3_spark_tpu.obs import prof
+
+    monkeypatch.setenv("OTPU_PROF", "1")
+    led = prof.DeviceMemoryLedger()
+    led.allocator = prof.LiveArraysAllocator()      # the CPU's tells nothing
+    monkeypatch.setattr(prof, "LEDGER", led)
+    job, _ = make_job(tmp_path, 11)
+    before = len(led.snapshot()["marks"])
+    table, _states = job.staged.run(replacements={job.src: job.table})
+    snap = led.snapshot()
+    assert [m["name"] for m in snap["marks"][before:]] == [
+        "between_fits", "canvas_dispatch", "canvas_drain", "canvas_models"]
+    out_bytes = prof.tree_chip_bytes((table.X, table.Y, table.W))
+    assert snap["owners"]["canvas_out"] == out_bytes
+    assert snap["owners"]["tables"] >= prof.tree_chip_bytes(
+        (job.table.X, job.table.W))
+    drain = snap["marks"][-2]
+    assert drain["ledger_bytes"] - snap["marks"][-4]["ledger_bytes"] \
+        == out_bytes
+    led._take_census(led.mark("census"))
+    census = led.snapshot()["census"]["owners"]
+    assert census["canvas_out"] == out_bytes
+    assert census["tables"] == snap["owners"]["tables"]
+    del table
+    gc.collect()
+    assert "canvas_out" not in led.snapshot()["owners"]
+
+
 def test_without_refit_a_staged_call_is_as_before(fitted):
     from orange3_spark_tpu.widgets.catalog import WIDGET_REGISTRY, OWTable
     from orange3_spark_tpu.workflow.graph import WorkflowGraph

@@ -49,6 +49,36 @@ def prof_env(tmp_path, monkeypatch):
     prof.reset_rate_limit()
 
 
+@pytest.fixture()
+def fresh_ledger(monkeypatch):
+    """A ledger of this test's own: the process-wide one carries the marks
+    and peaks of every test before it."""
+    led = prof.DeviceMemoryLedger()
+    # the CPU's allocator tells nothing: the live arrays' own sum stands in
+    led.allocator = prof.LiveArraysAllocator()
+    monkeypatch.setattr(prof, "LEDGER", led)
+    return led
+
+
+class ScriptedAllocator:
+    """``memory_stats()`` of one device for a ledger's marks: ``in_use()``
+    says the bytes in use, the live peak follows them as an allocator's
+    would (``bump`` lifts it above them: bytes that came and went between
+    two marks), ``temp`` is the reserved region's."""
+
+    def __init__(self, in_use):
+        self.in_use, self.peak, self.temp = in_use, 0, 0
+
+    def bump(self, nbytes):
+        self.peak = max(self.peak, self.in_use() + nbytes)
+
+    def __call__(self):
+        self.peak = max(self.peak, self.in_use())
+        return [{"bytes_in_use": self.in_use(),
+                 "peak_bytes_in_use": self.peak,
+                 "peak_bytes_reserved": self.temp}]
+
+
 def _fit_hashed(session, epochs=3, rows=4096, prof_on=True):
     from orange3_spark_tpu.io.streaming import array_chunk_source
     from orange3_spark_tpu.models.hashed_linear import (
@@ -95,7 +125,10 @@ def test_fit_goodput_fractions_partition_the_wall(session, prof_env):
     assert abs(total - 1.0) <= 0.02
 
 
-def test_fit_ledger_cache_entry_matches_stage_times(session, prof_env):
+def test_fit_ledger_cache_entry_matches_stage_times(session, prof_env,
+                                                    fresh_ledger,
+                                                    monkeypatch):
+    monkeypatch.setattr(prof, "CENSUS_RISE_BYTES", 1024)
     from orange3_spark_tpu.io.streaming import array_chunk_source
     from orange3_spark_tpu.models.hashed_linear import (
         StreamingHashedLinearEstimator,
@@ -127,10 +160,25 @@ def test_fit_ledger_cache_entry_matches_stage_times(session, prof_env):
     assert "model_state" in dm["owners"]
     assert dm["peak_bytes_fit"] >= on_chip
     assert dm["peak_global_bytes"] >= dm["cache_entry_bytes"]
-    # reconciliation is REPORTED, never asserted — but it must be there
-    rec = dm["reconciliation"]
-    assert rec["ledger_bytes"] >= on_chip
-    assert "delta_vs_live_bytes" in rec
+    # the account against the allocator rides every report: the fit's
+    # marks, the interval that set the live peak, and a census whose
+    # owners (named + unnamed) and runtime-held bytes add up to what the
+    # allocator had in use at its mark
+    assert {"between_fits", "model_state", "chunk", "replay_stack",
+            "model_handover"} <= {m["name"] for m in dm["marks"]}
+    hw = dm["high_water"]
+    assert (hw["named_bytes"] + hw["unnamed_bytes"] + hw["transient_bytes"]
+            == hw["peak_bytes_in_use"])
+    census = dm["census"]
+    assert set(census) >= {"mark", "span", "fit", "device", "bytes_in_use",
+                           "live_bytes", "runtime_held_bytes", "owners",
+                           "groups", "groups_dropped", "arrays"}
+    assert (sum(census["owners"].values()) + census["runtime_held_bytes"]
+            == census["bytes_in_use"])
+    at = next(m for m in dm["marks"] if m["n"] == census["mark"])
+    assert at["bytes_in_use"] == census["bytes_in_use"]
+    assert census["owners"]["cache_chunks"] > 0
+    assert census["owners"]["model_state"] > 0
 
 
 # ------------------------------------------------- hysteresis classifier
@@ -200,6 +248,7 @@ def test_ledger_register_release_snapshot_race(monkeypatch):
     snapshot must be internally consistent and the final state exact."""
     monkeypatch.setenv("OTPU_PROF", "1")
     led = prof.DeviceMemoryLedger()
+    led.allocator = prof.LiveArraysAllocator()
     errors: list = []
     stop = threading.Event()
 
@@ -220,7 +269,8 @@ def test_ledger_register_release_snapshot_race(monkeypatch):
                 assert snap["total_bytes"] >= 0
                 assert sum(snap["owners"].values()) == snap["total_bytes"]
                 assert snap["peak_bytes"] >= snap["total_bytes"]
-                led.reconcile()
+                rec = led.mark("race")
+                assert rec["ledger_high_bytes"] >= rec["ledger_bytes"] >= 0
         except Exception as e:  # noqa: BLE001
             errors.append(e)
 
@@ -254,6 +304,378 @@ def test_ledger_watermark_tracks_fit_peak(monkeypatch):
     led.set("a", "z", 50)
     assert wm.close() == 1000
     assert led.total() == 150
+
+
+# ------------------------------------------------------ the HBM account
+def _ledger_in_use(led):
+    """An allocator that has exactly what the ledger was told in use."""
+    return ScriptedAllocator(led.total)
+
+
+def test_census_names_handed_array_not_a_stranger(fresh_ledger,
+                                                  monkeypatch):
+    """The array handed to ``ledger_set_tree`` counts under its owner; one
+    of the same shape and dtype that no entry was handed is ``unnamed``."""
+    import jax.numpy as jnp
+
+    monkeypatch.setenv("OTPU_PROF", "1")
+    monkeypatch.setattr(prof, "CENSUS_RISE_BYTES", 0)
+    mine = jnp.full((1000, 3), 7.0, jnp.float32)
+    stranger = jnp.full((1000, 3), 9.0, jnp.float32)
+    prof.ledger_set_tree("model_state", "mine", {"w": mine})
+    fresh_ledger.mark("handed")
+    census = fresh_ledger.snapshot()["census"]
+    groups = {(g["owner"], g["dtype"], tuple(g["shape"])): g
+              for g in census["groups"]}
+    assert groups[("model_state", "float32", (1000, 3))]["count"] == 1
+    assert groups[("model_state", "float32", (1000, 3))]["bytes"] == 12000
+    assert groups[("unnamed", "float32", (1000, 3))]["count"] >= 1
+    assert census["owners"]["model_state"] == 12000
+    assert census["owners"]["unnamed"] >= stranger.nbytes
+    # released: the same array is a stranger now
+    prof.ledger_release("model_state", "mine")
+    fresh_ledger.mark("released")
+    fresh_ledger._take_census(fresh_ledger.snapshot()["marks"][-1])
+    assert "model_state" not in fresh_ledger.snapshot()["census"]["owners"]
+    del mine, stranger
+
+
+def test_census_follows_a_donated_state_through_now(fresh_ledger,
+                                                    monkeypatch):
+    """An owner whose arrays are donated away every step says which it
+    holds ``now``: the census names the successor, and the ledger keeps
+    neither the function nor the arrays alive."""
+    import gc
+    import weakref
+
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setenv("OTPU_PROF", "1")
+    state = jnp.zeros((2048,), jnp.float32)
+
+    def now():
+        return state
+
+    prof.ledger_set_tree("model_state", "s", state, now=now)
+    state = jax.jit(lambda x: x + 1, donate_argnums=0)(state)
+    fresh_ledger._take_census(fresh_ledger.mark("step"))
+    assert fresh_ledger.snapshot()["census"]["owners"]["model_state"] == 8192
+    gone = weakref.ref(now)
+    del now
+    gc.collect()
+    assert gone() is None
+    fresh_ledger._take_census(fresh_ledger.mark("frame_gone"))
+    assert "model_state" not in fresh_ledger.snapshot()["census"]["owners"]
+
+
+def test_census_counts_a_shard_on_the_fullest_device(fresh_ledger,
+                                                     monkeypatch):
+    """On a (2,2) mesh a table sharded over ``model`` counts by the half
+    one device holds, on the device the allocator says is fullest."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    monkeypatch.setenv("OTPU_PROF", "1")
+    devices = jax.local_devices()
+    mesh = Mesh(np.array(devices[:4]).reshape(2, 2), ("data", "model"))
+    table = jax.device_put(np.ones((4096, 4), np.float32),
+                           NamedSharding(mesh, P("model", None)))
+    alone = jax.device_put(np.ones((512,), np.float32), devices[5])
+    prof.ledger_set_tree("model_state", "t", table)
+    assert fresh_ledger.total() == 4096 * 4 * 4 // 2
+    # the allocator: device 3 the fullest (it holds a shard), then device 5
+    stats = [{"bytes_in_use": 0, "peak_bytes_in_use": 0,
+              "peak_bytes_reserved": 0} for _ in devices]
+    stats[3] = {"bytes_in_use": 40000, "peak_bytes_in_use": 50000,
+                "peak_bytes_reserved": 1000}
+    fresh_ledger.allocator = lambda: stats
+    rec = fresh_ledger.mark("put")
+    assert rec["device"] == 3 and rec["peak_bytes_reserved"] == 1000
+    fresh_ledger._take_census(rec)
+    census = fresh_ledger.snapshot()["census"]
+    assert census["owners"]["model_state"] == 4096 * 4 * 4 // 2
+    named = [g for g in census["groups"] if g["owner"] == "model_state"]
+    assert named == [{"owner": "model_state", "dtype": "float32",
+                      "shape": [2048, 4], "count": 1, "bytes": 32768}]
+    assert (sum(census["owners"].values()) + census["runtime_held_bytes"]
+            == 40000)
+    # an array that lives on another device is not on this one's account
+    stats[5] = {"bytes_in_use": 60000, "peak_bytes_in_use": 60000,
+                "peak_bytes_reserved": 0}
+    rec = fresh_ledger.mark("elsewhere")
+    assert rec["device"] == 5
+    fresh_ledger._take_census(rec)
+    census = fresh_ledger.snapshot()["census"]
+    assert "model_state" not in census["owners"]
+    assert census["owners"]["unnamed"] >= alone.nbytes
+
+
+def test_transient_bytes_name_the_closing_span(fresh_ledger, monkeypatch):
+    """The live peak rises between two marks whose ``bytes_in_use`` are
+    equal: every byte of the rise lived inside the span the second mark
+    closes, and the interval carries its name; the temp peak's interval
+    is kept separately."""
+    monkeypatch.setenv("OTPU_PROF", "1")
+    alloc = fresh_ledger.allocator = _ledger_in_use(fresh_ledger)
+    fresh_ledger.set("model_state", "m", 6_000_000)
+    fresh_ledger.mark("replay_drain")
+    hw = fresh_ledger.snapshot()["high_water"]
+    assert hw["span"] == "replay_drain" and hw["transient_bytes"] == 0
+    assert hw["since"] is None          # the process's first mark
+    alloc.bump(2_000_000)               # an undonated output, dropped
+    fresh_ledger.mark("finalize")
+    snap = fresh_ledger.snapshot()
+    hw = snap["high_water"]
+    assert hw["span"] == "finalize" and hw["rise_bytes"] == 2_000_000
+    assert hw["since"] == "replay_drain"
+    assert hw["transient_bytes"] == 2_000_000
+    assert hw["named_bytes"] == 6_000_000 and hw["unnamed_bytes"] == 0
+    assert hw["peak_bytes_in_use"] == 8_000_000
+    assert snap["high_water_temp"] is None
+    # temp rises in a later interval; the live peak's interval stays
+    alloc.temp = 1_000_000
+    fresh_ledger.mark("replay")
+    snap = fresh_ledger.snapshot()
+    assert snap["high_water"]["span"] == "finalize"
+    assert snap["high_water_temp"]["span"] == "replay"
+    assert snap["high_water_temp"]["mark"] != snap["high_water"]["mark"]
+    # an entry set and released inside an interval: the ledger's high
+    fresh_ledger.set("replay_plans", "stack", 500_000)
+    fresh_ledger.release("replay_plans", "stack")
+    rec = fresh_ledger.mark("replay_stack")
+    assert rec["ledger_bytes"] == 6_000_000
+    assert rec["ledger_high_bytes"] == 6_500_000
+    # where the fuller mark is the earlier one, the named bytes are its
+    fresh_ledger.set("cache_chunks", "c", 3_000_000)
+    fresh_ledger.mark("chunk")
+    fresh_ledger.release("cache_chunks", "c")
+    alloc.bump(4_000_000)
+    fresh_ledger.mark("evaluate")
+    hw = fresh_ledger.snapshot()["high_water"]
+    assert hw["fuller_mark"] == "before" and hw["named_bytes"] == 9_000_000
+    assert hw["transient_bytes"] == 1_000_000
+    assert (hw["named_bytes"] + hw["unnamed_bytes"] + hw["transient_bytes"]
+            == hw["peak_bytes_in_use"] == 10_000_000)
+
+
+def test_second_fit_of_same_shapes_takes_no_census(session, prof_env,
+                                                   fresh_ledger,
+                                                   monkeypatch):
+    """A census is a walk of ``jax.live_arrays()``: the first fit takes a
+    few as its peak rises, a second fit of the same shapes none."""
+    import gc
+
+    import jax
+
+    monkeypatch.setattr(prof, "CENSUS_RISE_BYTES", 4096)
+    fresh_ledger.allocator = _ledger_in_use(fresh_ledger)
+    walks = []
+    real = jax.live_arrays
+    monkeypatch.setattr(jax, "live_arrays",
+                        lambda *a: walks.append(1) or real(*a))
+    model = _fit_hashed(session)
+    first = len(walks)
+    assert first == fresh_ledger.censuses_taken >= 1
+    del model
+    gc.collect()
+    model = _fit_hashed(session)
+    assert len(walks) == first == fresh_ledger.censuses_taken
+    marks = fresh_ledger.snapshot()["marks"]
+    assert {m["fit"] for m in marks} == {0, 1}
+
+
+def test_fit_marks_where_its_spans_close(session, prof_env, fresh_ledger):
+    """Every site of the hashed fit, in the order the fit passes them, and
+    each mark an ``hbm_mark`` instant with the same numbers in the ring."""
+    from orange3_spark_tpu.io.streaming import array_chunk_source
+    from orange3_spark_tpu.models.hashed_linear import (
+        StreamingHashedLinearEstimator,
+    )
+    from orange3_spark_tpu.obs import trace
+
+    rng = np.random.default_rng(5)
+    X = np.concatenate([
+        rng.standard_normal((2048, 4)).astype(np.float32),
+        rng.integers(0, 500, (2048, 4)).astype(np.float32)], axis=1)
+    y = (rng.random(2048) < 0.3).astype(np.float32)
+    trace.clear()
+    with prof.force_enabled():
+        model = StreamingHashedLinearEstimator(
+            n_dims=1 << 12, n_dense=4, n_cat=4, epochs=3, step_size=0.05,
+            chunk_rows=512, optim_update="sparse_adagrad",
+        ).fit_stream(array_chunk_source(X, y, chunk_rows=512),
+                     session=session, cache_device=True, holdout_chunks=1)
+        model.evaluate_device(model.holdout_chunks_)
+    marks = fresh_ledger.snapshot()["marks"]
+    assert [m["name"] for m in marks] == (
+        ["between_fits", "model_state"] + ["chunk"] * 3 + ["finite_check",
+         "replay_stack", "replay_drain", "replay", "finite_check",
+         "finalize", "model_handover", "eval_chunk", "evaluate"])
+    assert [m["n"] for m in marks] == list(range(1, len(marks) + 1))
+    # the stack is an entry while it lives: the ledger's high of its span
+    stack = next(m for m in marks if m["name"] == "replay_stack")
+    assert stack["ledger_bytes"] > marks[5]["ledger_bytes"]
+    instants = [e for e in trace.events()
+                if e[0] == "i" and e[1] == "hbm_mark"]
+    assert [e[5]["span"] for e in instants] == [m["name"] for m in marks]
+    for e, m in zip(instants, marks):
+        assert e[5]["bytes_in_use"] == m["bytes_in_use"]
+        assert e[5]["peak_bytes_in_use"] == m["peak_bytes_in_use"]
+        assert e[5]["ledger_bytes"] == m["ledger_bytes"]
+
+
+def test_prof_off_takes_no_mark_and_fit_is_bitwise_same(session, prof_env,
+                                                        fresh_ledger):
+    off = _fit_hashed(session, prof_on=False)
+    snap = fresh_ledger.snapshot()
+    assert snap["marks"] == [] and snap["high_water"] is None
+    assert snap["census"] is None and snap["censuses_taken"] == 0
+    with prof.force_disabled():
+        assert fresh_ledger.mark("off") is None
+    on = _fit_hashed(session, prof_on=True)
+    assert fresh_ledger.snapshot()["marks"]
+    import jax
+
+    for a, b in zip(jax.tree.leaves(off.theta), jax.tree.leaves(on.theta)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_marks_ride_the_spans_off_with_obs(fresh_ledger, monkeypatch):
+    from orange3_spark_tpu.obs import trace
+
+    monkeypatch.setenv("OTPU_PROF", "1")
+    with trace.force_disabled():
+        with trace.span("chunk", hbm=True):
+            pass
+        assert fresh_ledger.mark("by_hand") is None
+    assert fresh_ledger.snapshot()["marks"] == []
+    with trace.force_enabled():
+        with trace.span("chunk", hbm=True):
+            pass
+        with trace.stage("replay", hbm=True):
+            pass
+        with trace.span("parse"):
+            pass
+    assert [m["name"] for m in fresh_ledger.snapshot()["marks"]] == [
+        "chunk", "replay"]
+
+
+@pytest.mark.parametrize("allocator", ["scripted", "live_arrays"])
+def test_mark_never_waits_for_the_device(fresh_ledger, monkeypatch,
+                                         allocator):
+    """Neither a mark nor its census may block on the device: with every
+    wait patched to raise, both still come through — from the allocator's
+    own numbers and from the live arrays' sum where there is none."""
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setenv("OTPU_PROF", "1")
+    monkeypatch.setattr(prof, "CENSUS_RISE_BYTES", 0)
+    x = jnp.ones((4096,), jnp.float32)
+    prof.ledger_set_tree("model_state", "x", x)
+    if allocator == "scripted":
+        fresh_ledger.allocator = _ledger_in_use(fresh_ledger)
+
+    def no_wait(*a, **kw):
+        raise AssertionError("a mark waited for the device")
+
+    monkeypatch.setattr(jax, "block_until_ready", no_wait)
+    monkeypatch.setattr(jax, "device_get", no_wait)
+    monkeypatch.setattr(type(x), "block_until_ready", no_wait)
+    monkeypatch.setattr(np, "asarray", no_wait)
+    rec = fresh_ledger.mark("chunk")
+    assert rec is not None and rec["bytes_in_use"] >= x.nbytes
+    census = fresh_ledger.snapshot()["census"]
+    assert census["mark"] == rec["n"]
+    assert census["owners"]["model_state"] == x.nbytes
+
+
+def test_marks_are_bounded(fresh_ledger, monkeypatch):
+    monkeypatch.setenv("OTPU_PROF", "1")
+    fresh_ledger.allocator = _ledger_in_use(fresh_ledger)
+    for fit in range(3):
+        fresh_ledger.mark("between_fits", first=True)
+        for i in range(3 * prof.MARKS_PER_FIT):
+            fresh_ledger.mark("chunk")
+    marks = fresh_ledger.snapshot()["marks"]
+    assert len(marks) == 2 * prof.MARKS_PER_FIT
+    assert {m["fit"] for m in marks} == {1, 2}       # the last two fits
+    assert marks[-1]["n"] == 3 * (3 * prof.MARKS_PER_FIT + 1)
+    # a failing allocator costs the fit nothing: no mark, no exception
+
+    def broken():
+        raise RuntimeError("no stats today")
+
+    fresh_ledger.allocator = broken
+    assert fresh_ledger.mark("chunk") is None
+    # nor does a backend without allocator statistics (the CPU's own)
+    fresh_ledger.allocator = prof._device_stats
+    assert fresh_ledger.mark("chunk") is None
+    assert fresh_ledger.snapshot()["marks"][-1]["n"] == marks[-1]["n"]
+
+
+def test_cache_hands_its_arrays_to_the_ledger(fresh_ledger, monkeypatch):
+    """The census knows the cached chunks by identity — offered one by
+    one, the holdout's taken out again — and a chunk of the same shape
+    that the cache never held is a stranger."""
+    import jax.numpy as jnp
+
+    from orange3_spark_tpu.io.streaming import _DeviceCache
+
+    monkeypatch.setenv("OTPU_PROF", "1")
+
+    def chunk(v):
+        return ({"dense": jnp.full((256, 4), v, jnp.bfloat16),
+                 "cat": jnp.full((256, 3), v, jnp.uint32)},
+                jnp.int32(256), jnp.zeros((1,)), jnp.zeros((1,)))
+
+    def cached_bytes():
+        fresh_ledger._take_census(fresh_ledger.mark("offer"))
+        snap = fresh_ledger.snapshot()
+        return (snap["census"]["owners"].get("cache_chunks", 0),
+                snap["owners"].get("cache_chunks", 0))
+
+    cache = _DeviceCache(True, 1 << 20, may_exclude_tail=1)
+    chunks = [chunk(i) for i in range(4)]
+    stranger = chunk(9)
+    for c in chunks:
+        cache.offer(c)
+    one = prof.tree_chip_bytes(chunks[0])
+    assert cached_bytes() == (4 * one, 4 * one)
+    cache.exclude({id(chunks[-1][0])})          # the holdout tail
+    assert cached_bytes() == (3 * one, 3 * one)
+    census = fresh_ledger.snapshot()["census"]
+    assert census["owners"]["unnamed"] >= 2 * one   # holdout + stranger
+    del cache, stranger
+    import gc
+
+    gc.collect()
+    assert cached_bytes() == (0, 0)
+
+
+def test_owned_entry_goes_with_its_object(session, fresh_ledger,
+                                          monkeypatch):
+    """A table put from the host is a ``tables`` entry while it lives and
+    its put a mark; the entry goes with the table."""
+    import gc
+
+    from orange3_spark_tpu.core.table import TpuTable
+
+    monkeypatch.setenv("OTPU_PROF", "1")
+    t = TpuTable.from_arrays(np.ones((64, 3), np.float32), session=session)
+    snap = fresh_ledger.snapshot()
+    assert snap["owners"]["tables"] == prof.tree_chip_bytes((t.X, t.W))
+    assert snap["marks"][-1]["name"] == "table_put"
+    fresh_ledger._take_census(fresh_ledger.mark("census"))
+    assert (fresh_ledger.snapshot()["census"]["owners"]["tables"]
+            == snap["owners"]["tables"])
+    del t
+    gc.collect()
+    assert "tables" not in fresh_ledger.snapshot()["owners"]
+    with prof.force_disabled():
+        TpuTable.from_arrays(np.ones((8, 3), np.float32), session=session)
+    assert "tables" not in fresh_ledger.snapshot()["owners"]
 
 
 # ------------------------------------------------- /debug/profile contract

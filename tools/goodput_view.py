@@ -4,7 +4,7 @@ HBM go", rendered.
 Renders the obs/prof.py attribution surfaces as a readable report: the
 five-way wall decomposition as an ASCII bar per stage, the per-epoch
 bottleneck classification, and the device-memory ledger table (per-owner
-bytes + the largest named entries + the runtime reconciliation delta).
+bytes + the largest named entries + the high-water interval and census).
 
 Three input shapes, sniffed automatically:
 
@@ -44,8 +44,10 @@ def _bar(frac: float) -> str:
 def ledger_lines(device_memory: dict, *, max_entries: int = 10) -> list:
     """The ONE device-memory-ledger table rendering (shared with
     tools/flight_view.py — a ledger-schema change edits one place):
-    per-owner totals, the largest named entries, the reconciliation
-    delta (reported, never asserted)."""
+    per-owner totals, the largest named entries, and the account against
+    the allocator: the interval that set each peak and the last census of
+    the live arrays (absent from a report written before the ledger took
+    marks: rendered without)."""
     dm = device_memory
     lines = [f"device-memory ledger "
              f"(live {dm.get('total_bytes', 0)/1e6:.2f} MB, "
@@ -55,12 +57,31 @@ def ledger_lines(device_memory: dict, *, max_entries: int = 10) -> list:
     for e in (dm.get("entries") or [])[:max_entries]:
         lines.append(f"    {e['owner']}/{e['name']:<26} "
                      f"{e['bytes']/1e6:10.3f} MB")
-    rec = dm.get("reconciliation") or {}
-    if rec.get("jax_live_bytes") is not None:
-        lines.append(f"  reconcile: ledger={rec['ledger_bytes']} "
-                     f"jax_live={rec['jax_live_bytes']} "
-                     f"delta={rec.get('delta_vs_live_bytes')} "
-                     f"(reported, never asserted)")
+    hw = dm.get("high_water") or {}
+    if hw:
+        lines.append(
+            f"  live peak {hw['peak_bytes_in_use']/1e6:.2f} MB set in "
+            f"'{hw['span']}' (fit {hw['fit']}): named "
+            f"{hw['named_bytes']/1e6:.2f} + unnamed "
+            f"{hw['unnamed_bytes']/1e6:.2f} + transient "
+            f"{hw['transient_bytes']/1e6:.2f} MB")
+    hwt = dm.get("high_water_temp") or {}
+    if hwt:
+        lines.append(
+            f"  temp peak {hwt['peak_bytes_reserved']/1e6:.2f} MB set in "
+            f"'{hwt['span']}' (fit {hwt['fit']})")
+    census = dm.get("census") or {}
+    if census:
+        lines.append(
+            f"  census at '{census['span']}' (fit {census['fit']}, "
+            f"{census['arrays']} arrays): live "
+            f"{census['live_bytes']/1e6:.2f} MB, runtime-held "
+            f"{census['runtime_held_bytes']/1e6:.2f} MB")
+        for g in (census.get("groups") or [])[:max_entries]:
+            shape = "x".join(map(str, g["shape"])) or "scalar"
+            lines.append(f"    {g['owner']:<18} {g['count']:>4} x "
+                         f"{g['dtype']}[{shape}]"
+                         f" {g['bytes']/1e6:10.3f} MB")
     return lines
 
 
@@ -101,12 +122,7 @@ def _load(path: str) -> tuple[dict | None, dict | None, str]:
     with open(path) as f:
         d = json.load(f)
     if "prof_schema" in d and "ledger" in d:      # capture snapshot.json
-        led = dict(d.get("ledger") or {})
-        # captures store reconciliation as the ledger's SIBLING; fold
-        # it in so the renderer's one shape covers both input kinds
-        if "reconciliation" in d:
-            led.setdefault("reconciliation", d["reconciliation"])
-        return d.get("goodput"), led, "capture"
+        return d.get("goodput"), d.get("ledger"), "capture"
     # RunReport dict: goodput/device_memory sections (absent under
     # OTPU_PROF=0 — rendered as such, never a crash)
     return d.get("goodput"), d.get("device_memory"), "report"
