@@ -4,11 +4,20 @@ MLlib runs Lloyd's algorithm with k-means|| initialization, one
 treeAggregate per iteration to sum per-cluster centroids (SURVEY.md §2b row
 "KMeans"; reconstructed, mount empty). TPU-native redesign:
 
-* assignment = argmin of pairwise squared distances computed with the matmul
-  identity  |x-c|² = |x|² - 2x·c + |c|²  — the 2x·c term is an [N,d]@[d,k]
-  MXU matmul, not a broadcast subtract (HBM-bandwidth friendly);
-* center update = one-hot(assign)ᵀ @ X — another MXU matmul whose row-axis
-  contraction GSPMD all-reduces over ICI (the treeAggregate moment);
+* at small d and d·k (``_on_vpu``) an iteration is ONE pass over the rows on
+  the vector unit: each row's Σ(x−c)² to every centre, its nearest centre,
+  and the per-cluster weighted sums, weights and cost, accumulated in the
+  same pass (a Pallas kernel on the TPU, ``_pass_pallas``) — no matmul, no
+  [N, k] one-hot, one read of the rows;
+* above it the MXU's tools: assignment by the matmul identity
+  |x-c|² = |x|² - 2x·c + |c|² (the 2x·c term an [N,d]@[d,k] matmul), the
+  centre update one-hot(assign)ᵀ @ X in ``rows_dot``'s blocked products;
+* either way the row-axis sums are float32 partials of at most
+  ``ROW_BLOCK`` rows added as float32 values, all-reduced over the data
+  axis (the treeAggregate moment). GSPMD does not partition a Pallas
+  call, so over a row-split table each device runs the kernel on its own
+  rows (``shard_map``); ``_rows_of`` reads that split off a concrete
+  table, and a traced table's caller passes its session's;
 * the whole Lloyd loop is a single jitted ``lax.while_loop`` with the MLlib
   convergence test (all center moves < tol).
 
@@ -25,12 +34,16 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from orange3_spark_tpu.core.domain import DiscreteVariable, Domain
 from orange3_spark_tpu.core.table import TpuTable
 from orange3_spark_tpu.exec.donate import donating_jit
 from orange3_spark_tpu.models.base import concrete_or_none, Estimator, Model, Params
 from orange3_spark_tpu.obs.registry import REGISTRY
+from orange3_spark_tpu.ops import stats
 from orange3_spark_tpu.ops.stats import rows_dot
 
 _M_ITERATIONS = REGISTRY.counter(
@@ -38,6 +51,35 @@ _M_ITERATIONS = REGISTRY.counter(
     "Lloyd iterations run by finished KMeans fits, by fit: eager (counted "
     "at the end of the fit) | staged (a staged refit, workflow/staging.py: "
     "counted when its states come back)")
+_M_PASSES = REGISTRY.counter(
+    "otpu_kmeans_passes_total",
+    "Lloyd passes over the rows of finished KMeans fits (the iterations and "
+    "the final assignment), by how: vpu (_on_vpu: d <= VPU_MAX_D and d*k <= "
+    "VPU_MAX_DK, one vector-unit pass) | mxu (matmul assignment + blocked "
+    "one-hot products)")
+
+#: d·k and width d at or under which a Lloyd pass runs on the vector unit:
+#: its work is ~6·d·k operations a row, where the MXU's cross term is
+#: under-filled until d and k reach the hundreds; past 8 columns its
+#: per-cluster accumulators outgrow the registers and it loses at every k.
+#: The sweeps of PERF.md §6 (TPU v5e, ms a pass, vector unit / MXU), over
+#: 2^24 rows: (4, 10) 0.91 / 4.92, (8, 16) 2.51 / 5.07, (8, 32) 4.77 /
+#: 9.10, (16, 64) 27.6 / 17.9, (32, 128) 100.7 / 65.4; over 2^22 + 77 rows
+#: (padded): (1, 256) 2.58 / 32.7, (2, 128) 1.91 / 22.7, (4, 64) 1.50 /
+#: 9.71, (8, 32) 1.63 / 5.33, (16, 16) 6.78 / 3.67, (32, 8) 12.3 / 4.01;
+#: over 2^22 rows: (16, 4) 1.42 / 1.02, (32, 2) 2.74 / 1.56
+VPU_MAX_DK = 256
+VPU_MAX_D = 8
+
+_LANES = 128
+#: bytes of the rows one grid step of ``_pass_pallas`` reads
+_STEP_BYTES = 1 << 21
+#: vregs of the rows one inner step of ``_pass_kernel`` holds
+_X_VREGS = 16
+
+
+def _on_vpu(d: int, k: int) -> bool:
+    return d <= VPU_MAX_D and d * k <= VPU_MAX_DK
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,9 +106,8 @@ def live_cluster_sizes(W, assign, num_segments: int):
     return jnp.sum(hit, axis=0, dtype=jnp.int32).astype(jnp.float32)
 
 
-@partial(jax.jit, static_argnames=("compute_dtype",))
-def _assign(X, centers, w, compute_dtype=jnp.float32):
-    """Nearest-center ids + weighted cost. Distances via the matmul identity."""
+def _assign_mxu(X, centers, w, compute_dtype):
+    """Nearest-center ids + weighted cost by the matmul identity."""
     Xc = X.astype(compute_dtype)
     Cc = centers.astype(compute_dtype)
     # [N,k] on the MXU. HIGHEST: the TPU's default rounds both operands of
@@ -82,9 +123,263 @@ def _assign(X, centers, w, compute_dtype=jnp.float32):
     return assign, cost
 
 
-@donating_jit(static_argnames=("k", "max_iter", "compute_dtype"),
+def _pass_mxu(X, w, centers, compute_dtype, sums: bool):
+    """The MXU's pass: the matmul assignment, then (``sums``) the centre
+    update one-hot(assign)ᵀ @ X -> ``(sums [k, d], weights [k], cost)``,
+    else ``(assign [N], cost)``."""
+    assign, cost = _assign_mxu(X, centers, w, compute_dtype)
+    if not sums:
+        return assign, cost
+    onehot = jax.nn.one_hot(assign, centers.shape[0],
+                            dtype=jnp.float32) * w[:, None]      # [N,k]
+    # [k,d] MXU matmuls over row blocks, all-reduced by GSPMD
+    return rows_dot(onehot, X), jnp.sum(onehot, axis=0), cost
+
+
+def _pass_kernel(c_ref, x_ref, w_ref, *refs, k, d, compute_dtype, sums,
+                 tail):
+    """One grid step of ``_pass_pallas``: ``x_ref`` holds ``lane_rows``
+    groups of 128 table rows, each as d lane-vectors (row ``t*d + i`` is
+    column i of group t), ``w_ref`` their weights, ``c_ref`` the centres
+    as ``[k, d, 8, 128]`` vregs. ``_tile_groups(d)`` groups at a time
+    (several vregs an operation, so that the chains of dependent operations
+    interleave), the loop over centres unrolled: Σ_i (x_i − c_ji)² for every
+    centre j, the first nearest (strict ``<``, argmin's tie rule), then
+    either the assignment (``sums=False``) or each cluster's weighted sums
+    and weight, and the cost, into accumulators that a ``ROW_BLOCK``-row
+    block empties into one float32 partial each: lane b of the step's
+    ``part`` row e (e = j·(d+1) + i for the sums and weights, the last row
+    for the cost). The last step holds ``tail`` groups of the table: the
+    rest of its buffers, whatever they hold, is zeroed first (weight 0)."""
+    outs, acc_ref = refs[:-1], refs[-1]
+    part = outs[-1]
+    cost_row = acc_ref.shape[0] - 1
+    block = stats.ROW_BLOCK
+    n_blocks = w_ref.shape[0] * _LANES // block
+    g = _tile_groups(d)
+    tile = 8 * g
+    tiles = block // (tile * _LANES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+
+    if tail < w_ref.shape[0]:
+        @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
+        def _():
+            x_ref[tail * d:, :] = jnp.zeros(
+                (x_ref.shape[0] - tail * d, _LANES), jnp.float32)
+            w_ref[tail:, :] = jnp.zeros(
+                (w_ref.shape[0] - tail, _LANES), jnp.float32)
+
+    def fold(v):            # [..., g, 8, 128] -> [..., 8, 128]
+        return v.sum(axis=-3)
+
+    def one_block(b, totals):
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        def one_tile(t, carry):
+            t0 = pl.multiple_of((b * tiles + t) * tile, tile)
+            cols = jnp.stack([x_ref[pl.ds(t0 * d + i, tile, stride=d), :]
+                              for i in range(d)]).reshape(d, g, 8, _LANES)
+            w = w_ref[pl.ds(t0, tile), :].reshape(g, 8, _LANES)
+            xa = cols
+            if np.dtype(compute_dtype) != np.float32:
+                xa = cols.astype(compute_dtype).astype(jnp.float32)
+            best = a = None
+            for j in range(k):
+                dj = jnp.sum(jnp.square(xa - c_ref[j][:, None]), axis=0)
+                if best is None:
+                    best, a = dj, jnp.zeros(dj.shape, jnp.int32)
+                else:
+                    lt = dj < best
+                    best, a = jnp.where(lt, dj, best), jnp.where(lt, j, a)
+            acc_ref[cost_row] += fold(best * w)
+            if not sums:
+                outs[0][pl.ds(t0, tile), :] = a.reshape(tile, _LANES)
+                return carry
+            cols1 = jnp.concatenate([cols, jnp.ones_like(w)[None]])
+            for j in range(k):
+                hw = jnp.where(a == j, w, 0.0)
+                acc_ref[pl.ds(j * (d + 1), d + 1)] += fold(hw * cols1)
+            return carry
+
+        jax.lax.fori_loop(0, tiles, one_tile, 0)
+        tot = jnp.sum(jnp.sum(acc_ref[...], axis=1), axis=1, keepdims=True)
+        return jnp.where(lane == b, tot, totals)
+
+    part[0] = jax.lax.fori_loop(0, n_blocks, one_block,
+                                jnp.zeros(part.shape[1:], jnp.float32))
+
+
+def _tile_groups(d: int) -> int:
+    """Groups of 1,024 rows one inner step of the kernel holds: about
+    ``_X_VREGS`` vregs of the rows, a power of two dividing a block."""
+    g = 1
+    while 2 * g * d <= _X_VREGS and 2 * g * 8 * _LANES <= stats.ROW_BLOCK:
+        g *= 2
+    return g
+
+
+@partial(jax.jit, static_argnames=("compute_dtype", "sums", "interpret"))
+def _pass_pallas(X, w, centers, compute_dtype, sums, interpret=False):
+    """The vector-unit pass as a Pallas kernel over one device's rows. A
+    jit of its own, so that a program lowers each kernel once: the fit's
+    last pass and a transform of the same rows by the same centres are
+    then one call, which XLA merges (two lowerings of one kernel differ
+    in their call sites and are not merged).
+
+    The rows are read as ``[N/128 · d, 128]``: on the TPU an ``[N, d]``
+    array of d = 1, 2, 4 or 8 columns and N a multiple of 1,024 rows is
+    laid out that way already (its rows minor, in tiles of d x 128), so
+    the view is a bitcast and the pass reads the table where it lies;
+    other widths are relaid out. Any other N is padded to a multiple of
+    1,024, a copy of the table each pass: XLA's own relayout of such an
+    N is slower still and compiles for a time that grows with N. The
+    grid's last step may hang over the table's end (the kernel zeroes
+    what it holds past it). ``interpret`` runs the kernel on any backend
+    (tests)."""
+    n, d = X.shape
+    k = centers.shape[0]
+    block = stats.ROW_BLOCK
+    per_block = block // _LANES
+    pad = -n % (8 * _LANES)
+    if pad:
+        X = jnp.pad(X, ((0, pad), (0, 0)))
+        w = jnp.pad(w, (0, pad))
+    groups = (n + pad) // _LANES
+    lane_rows = max(per_block, _STEP_BYTES // (4 * _LANES * d)
+                    // per_block * per_block)
+    lane_rows = min(lane_rows, -(-groups // per_block) * per_block)
+    steps = -(-groups // lane_rows)
+    Xp = X.reshape(groups, _LANES, d).transpose(0, 2, 1).reshape(
+        groups * d, _LANES)
+    wp = w.reshape(groups, _LANES)
+    c = jnp.broadcast_to(
+        centers.astype(compute_dtype).astype(jnp.float32)[:, :, None, None],
+        (k, d, 8, _LANES))
+    # the cost's row last; without sums a spare row before it (Mosaic
+    # reduces no accumulator of a single row)
+    n_part = 1 + (k * (d + 1) if sums else 1)
+
+    def rows(s):
+        return s, 0
+
+    out_shape = [jax.ShapeDtypeStruct((steps, n_part, _LANES), jnp.float32)]
+    out_specs = [pl.BlockSpec((1, n_part, _LANES), lambda s: (s, 0, 0))]
+    if not sums:
+        out_shape.insert(0, jax.ShapeDtypeStruct((groups, _LANES), jnp.int32))
+        out_specs.insert(0, pl.BlockSpec((lane_rows, _LANES), rows))
+    vmem = 4 * 8 * _LANES * (                        # bytes of the vregs:
+        2 * lane_rows // 8 * (d + 1 + (not sums))    # rows, weights (and
+                                                     # assignment), x2
+        + 2 * k * d                                  # centres, x2
+        + n_part                                     # accumulators
+        + 2 * n_part // 8 + 2)                       # partials, x2
+    outs = pl.pallas_call(
+        partial(_pass_kernel, k=k, d=d, compute_dtype=compute_dtype,
+                sums=sums, tail=groups - (steps - 1) * lane_rows),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((k, d, 8, _LANES), lambda s: (0, 0, 0, 0)),
+                  pl.BlockSpec((lane_rows * d, _LANES), rows),
+                  pl.BlockSpec((lane_rows, _LANES), rows)],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((n_part, 8, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=max(32 << 20, 2 * vmem)),
+        interpret=interpret,
+    )(c, Xp, wp)
+    tot = jnp.sum(outs[-1][:, :, :lane_rows // per_block], axis=(0, 2))
+    if not sums:
+        assign = outs[0].reshape(groups * _LANES)
+        return (assign[:n] if pad else assign), tot[-1]
+    sc = tot[:-1].reshape(k, d + 1)
+    return sc[:, :d], sc[:, d], tot[-1]
+
+
+def _local_pass(X, w, centers, compute_dtype, sums: bool):
+    """One device's vector-unit pass: the Pallas kernel on the TPU, the
+    MXU's pass on every other backend."""
+    return jax.lax.platform_dependent(
+        X, w, centers,
+        tpu=partial(_pass_pallas, compute_dtype=compute_dtype, sums=sums),
+        default=partial(_pass_mxu, compute_dtype=compute_dtype, sums=sums))
+
+
+def _row_axis(rows):
+    """The mesh axis ``rows`` splits the table's rows over, or None where
+    no axis of more than one device splits them."""
+    axis = rows.spec[0] if rows is not None and rows.spec else None
+    names = axis if isinstance(axis, tuple) else (axis,)
+    if axis is None or np.prod([rows.mesh.shape[a] for a in names]) == 1:
+        return None
+    return axis
+
+
+def _vpu_pass(X, w, centers, compute_dtype, sums: bool, rows):
+    """One pass of the rows on the vector unit -> ``(sums [k, d], weights
+    [k], cost)``, or ``(assign [N], cost)`` with ``sums=False``. Where
+    ``rows`` splits them over devices, each device passes its own rows
+    (``shard_map``) and the partials are all-reduced: a Pallas call is
+    not partitioned by GSPMD."""
+    compute_dtype = jnp.dtype(compute_dtype)
+    axis = _row_axis(rows)
+    if axis is None:
+        return _local_pass(X, w, centers, compute_dtype, sums)
+
+    def shard(X, w, centers):
+        out = _local_pass(X, w, centers, compute_dtype, sums)
+        if sums:
+            return jax.lax.psum(out, axis)
+        return out[0], jax.lax.psum(out[1], axis)
+
+    return jax.shard_map(
+        shard, mesh=rows.mesh, in_specs=(P(axis, None), P(axis), P()),
+        out_specs=P() if sums else (P(axis), P()),
+        check_vma=False)(X, w, centers)
+
+
+def _pass(X, w, centers, compute_dtype, sums: bool, rows):
+    """A Lloyd pass by the path ``_on_vpu`` picks. ``rows`` is the
+    sharding of the table's rows (``_rows_of``). It is None only for a
+    traced table whose caller gave none: in a process of more than one
+    device those rows may be split, so the pass is then the MXU's, which
+    GSPMD partitions."""
+    k, d = centers.shape
+    if _on_vpu(d, k) and (rows is not None or jax.device_count() == 1):
+        return _vpu_pass(X, w, centers, compute_dtype, sums, rows)
+    return _pass_mxu(X, w, centers, compute_dtype, sums)
+
+
+def _rows_of(X, rows):
+    """The sharding of the table's rows a pass runs under: ``rows`` where
+    the caller gives it (a traced table's session), else a concrete
+    array's own; None where neither is known."""
+    if rows is not None or isinstance(X, jax.core.Tracer):
+        return rows
+    s = getattr(X, "sharding", None)
+    if isinstance(s, NamedSharding):
+        return NamedSharding(s.mesh, P(s.spec[0] if s.spec else None, None))
+    if s is not None and len(s.device_set) == 1:     # one device holds them
+        return NamedSharding(Mesh(np.asarray(list(s.device_set)), ("rows",)),
+                             P(None, None))
+    return None
+
+
+@partial(jax.jit, static_argnames=("compute_dtype", "rows"))
+def _assign_fused(X, centers, w, compute_dtype=jnp.float32, rows=None):
+    return _pass(X, w, centers, compute_dtype, False, rows)
+
+
+def _assign(X, centers, w, compute_dtype=jnp.float32, rows=None):
+    """Nearest-center ids + weighted cost: one vector-unit pass where
+    ``_on_vpu``, else the matmul identity. ``rows``: the sharding of the
+    table's rows, needed where X is traced (``_rows_of``)."""
+    return _assign_fused(X, centers, w, compute_dtype, _rows_of(X, rows))
+
+
+@donating_jit(static_argnames=("k", "max_iter", "compute_dtype", "rows"),
               donate_argnums=(2,))
-def _lloyd(X, w, centers0, tol, *, k: int, max_iter: int, compute_dtype=jnp.float32):
+def _lloyd_fused(X, w, centers0, tol, *, k: int, max_iter: int,
+                 compute_dtype=jnp.float32, rows=None):
     """Fused Lloyd loop. ``centers0`` is DONATED — every caller builds the
     seed centers fresh (host kmeans++ / device D² sampling), and the loop
     round-trips a same-shaped centers array, so XLA reuses the buffer. The
@@ -92,11 +387,7 @@ def _lloyd(X, w, centers0, tol, *, k: int, max_iter: int, compute_dtype=jnp.floa
     tracing is a no-op)."""
     def body(carry):
         centers, _, it, _ = carry
-        assign, cost = _assign(X, centers, w, compute_dtype)
-        onehot = jax.nn.one_hot(assign, k, dtype=jnp.float32) * w[:, None]  # [N,k]
-        # [k,d] MXU matmuls over row blocks, all-reduced by GSPMD
-        sums = rows_dot(onehot, X)
-        counts = jnp.sum(onehot, axis=0)
+        sums, counts, cost = _pass(X, w, centers, compute_dtype, True, rows)
         new_centers = jnp.where(
             counts[:, None] > 0, sums / jnp.maximum(counts, 1e-12)[:, None], centers
         )
@@ -112,8 +403,18 @@ def _lloyd(X, w, centers0, tol, *, k: int, max_iter: int, compute_dtype=jnp.floa
         keep_going, body, (centers0, jnp.float32(jnp.inf), 0, False)
     )
     # final stats at the converged centers
-    assign, cost = _assign(X, centers, w, compute_dtype)
+    assign, cost = _pass(X, w, centers, compute_dtype, False, rows)
     return centers, assign, cost, n_iter
+
+
+def _lloyd(X, w, centers0, tol, *, rows=None, **kw):
+    """``_lloyd_fused`` under the sharding of the table's rows: ``rows``,
+    needed where X is traced, else X's own (``_rows_of``)."""
+    return _lloyd_fused(X, w, centers0, tol, rows=_rows_of(X, rows), **kw)
+
+
+_lloyd.donated, _lloyd.plain = _lloyd_fused.donated, _lloyd_fused.plain
+_lloyd.__wrapped__ = _lloyd_fused.__wrapped__
 
 
 def kmeanspp_seed(sample: np.ndarray, k: int, rng) -> np.ndarray:
@@ -172,29 +473,36 @@ class KMeansModel(Model):
         self.training_cost_ = concrete_or_none(summary["cost"])
         if self.n_iter_ is not None:
             _M_ITERATIONS.inc(self.n_iter_, fit=fit)
+            k, d = self.centers.shape
+            _M_PASSES.inc(self.n_iter_ + 1,
+                          how="vpu" if _on_vpu(d, k) else "mxu")
 
     @property
     def cluster_centers_(self) -> np.ndarray:
         return np.asarray(self.centers)
 
     def predict(self, table: TpuTable) -> np.ndarray:
-        assign, _ = _assign(table.X, self.centers, table.W)
+        assign, _ = _assign(table.X, self.centers, table.W,
+                            rows=table.session.row_sharding)
         return np.asarray(assign)[: table.n_rows]
 
     def _device_predict(self, table: TpuTable):
         """Serving hook (serve/context.py): per-row cluster ids, device-pure
         — assignment is row-wise (argmin over centers), so bucket padding
         cannot perturb live rows."""
-        assign, _ = _assign(table.X, self.centers, table.W)
+        assign, _ = _assign(table.X, self.centers, table.W,
+                            rows=table.session.row_sharding)
         return assign
 
     def compute_cost(self, table: TpuTable) -> float:
-        _, cost = _assign(table.X, self.centers, table.W)
+        _, cost = _assign(table.X, self.centers, table.W,
+                          rows=table.session.row_sharding)
         return float(cost)
 
     def transform(self, table: TpuTable) -> TpuTable:
         """Append the 'cluster' prediction column (Spark's predictionCol)."""
-        assign, _ = _assign(table.X, self.centers, table.W)
+        assign, _ = _assign(table.X, self.centers, table.W,
+                            rows=table.session.row_sharding)
         k = self.centers.shape[0]
         new_attrs = list(table.domain.attributes) + [
             DiscreteVariable("cluster", tuple(str(i) for i in range(k)))
@@ -335,7 +643,8 @@ class KMeans(Estimator):
     def _fit(self, table: TpuTable) -> KMeansModel:
         p = self.params
         lloyd_kw = dict(k=p.k, max_iter=p.max_iter,
-                        compute_dtype=jnp.dtype(p.compute_dtype))
+                        compute_dtype=jnp.dtype(p.compute_dtype),
+                        rows=table.session.row_sharding)
         tol = jnp.float32(p.tol)
         if p.n_init <= 1:
             with jax.named_scope("kmeans/init"):

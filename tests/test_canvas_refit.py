@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from benchmark import harness
+from orange3_spark_tpu.models.kmeans import VPU_MAX_D, VPU_MAX_DK
 from orange3_spark_tpu.obs import trace
 from orange3_spark_tpu.obs.registry import REGISTRY
 
@@ -54,6 +55,8 @@ def counters() -> dict:
         "eager": get("otpu_canvas_dispatches_total").value(mode="eager"),
         "fallbacks": get("otpu_canvas_refit_fallbacks_total").total(),
         "iterations": get("otpu_kmeans_iterations_total").value(fit="staged"),
+        "passes_vpu": get("otpu_kmeans_passes_total").value(how="vpu"),
+        "passes_mxu": get("otpu_kmeans_passes_total").value(how="mxu"),
     }
 
 
@@ -164,6 +167,37 @@ def test_one_dispatch_a_refit_zero_fallbacks_and_its_iterations(fitted):
     assert after["iterations"] - before["iterations"] == sum(
         a["n_iter"] for a in answers)
     assert job.staged.refit_fallbacks == []
+
+
+def test_refit_counts_its_lloyd_passes_on_the_vector_unit(fitted):
+    """d = 4, k = 10: every iteration and the final assignment is one
+    vector-unit pass."""
+    job = fitted[0]
+    before = counters()
+    answers = [job.run()["answer"] for _ in range(2)]
+    after = counters()
+    assert after["passes_vpu"] - before["passes_vpu"] == sum(
+        a["n_iter"] + 1 for a in answers)
+    assert after["passes_mxu"] == before["passes_mxu"]
+
+
+@pytest.mark.parametrize("d", [VPU_MAX_DK // 2 + 1, VPU_MAX_D + 1],
+                         ids=["d_k_over", "d_over"])
+def test_a_fit_over_the_threshold_counts_mxu_passes(session, d):
+    """k = 2: d * k over ``VPU_MAX_DK``, or d over ``VPU_MAX_D`` alone."""
+    from orange3_spark_tpu.core.domain import ContinuousVariable, Domain
+    from orange3_spark_tpu.core.table import TpuTable
+    from orange3_spark_tpu.models.kmeans import KMeans
+
+    X = np.random.default_rng(5).standard_normal((512, d))
+    table = TpuTable.from_numpy(
+        Domain([ContinuousVariable(f"c{i}") for i in range(d)]), X,
+        session=session)
+    before = counters()
+    model = KMeans(k=2, max_iter=5, seed=0).fit(table)
+    after = counters()
+    assert after["passes_mxu"] - before["passes_mxu"] == model.n_iter_ + 1
+    assert after["passes_vpu"] == before["passes_vpu"]
 
 
 def test_eager_run_counts_a_dispatch_a_table_widget(fitted, session):

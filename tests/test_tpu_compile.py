@@ -448,7 +448,15 @@ def test_hashed_replay_epochs_compiles_model_sharded_at_2_30(topo, hashed):
 
 
 # ------------------------------------------------------------------- kmeans
-def test_kmeans_lloyd_compiles(one_chip):
+@pytest.fixture(scope="module")
+def one_chip_rows(topo):
+    """The rows of a one-chip session's table (``KMeans`` passes its
+    session's row sharding to ``_lloyd``)."""
+    return NamedSharding(Mesh(np.asarray(topo.devices[:1]), ("data",)),
+                         P("data", None))
+
+
+def test_kmeans_lloyd_compiles(one_chip, one_chip_rows):
     from orange3_spark_tpu.models.kmeans import _lloyd
 
     n, d, k = 2_097_152, 8, 10
@@ -456,20 +464,74 @@ def test_kmeans_lloyd_compiles(one_chip):
     compiled = _lloyd.donated.lower(
         sds((n, d), jnp.float32), sds((n,), jnp.float32),
         sds((k, d), jnp.float32), sds((), jnp.float32),
-        k=k, max_iter=10).compile()
+        k=k, max_iter=10, rows=one_chip_rows).compile()
     _fits(compiled)
 
 
+def test_kmeans_lloyd_row_sharded_compiles_on_four_chip_mesh(topo):
+    """KMeans on four chips at the canvas cell's shape (2^27 rows a chip):
+    each chip's kernel passes its own rows inside ``shard_map`` and the
+    partials are all-reduced; nothing gathers the table to one chip."""
+    from orange3_spark_tpu.models.kmeans import _lloyd
+
+    n, d, k = 1 << 29, 4, 10
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+    rows = NamedSharding(mesh, P("data", None))
+    rep = functools.partial(jax.ShapeDtypeStruct,
+                            sharding=NamedSharding(mesh, P()))
+    compiled = _lloyd.donated.lower(
+        jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=rows),
+        jax.ShapeDtypeStruct((n,), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data"))),
+        rep((k, d), jnp.float32), rep((), jnp.float32),
+        k=k, max_iter=20, rows=rows).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    assert "all-gather" not in text
+    assert f"[{n},{d}]" not in text and f"[{n // 4},{d}]" in text
+
+
+@pytest.mark.parametrize("n, d, k", [(1 << 27, 4, 10), (1 << 20, 16, 64),
+                                     (1 << 20, 16, 4)],
+                         ids=["cell-vpu", "over-threshold-mxu", "wide-mxu"])
+def test_kmeans_lloyd_pass_by_shape(one_chip, one_chip_rows, n, d, k):
+    """At the canvas cell's shapes an iteration is the vector-unit kernel:
+    no matmul and no ``[N, k]`` buffer (distances, one-hot) anywhere in
+    the program. Over ``VPU_MAX_DK``, or ``VPU_MAX_D`` alone, the MXU's
+    cross term is compiled."""
+    from orange3_spark_tpu.models.kmeans import _lloyd, _on_vpu
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = _lloyd.donated.lower(
+        sds((n, d), jnp.float32), sds((n,), jnp.float32),
+        sds((k, d), jnp.float32), sds((), jnp.float32),
+        k=k, max_iter=20, rows=one_chip_rows).compile()
+    text = compiled.as_text()
+    matmul = re.search(r" (dot|convolution)\(", text) is not None
+    if (d, k) == (4, 10):
+        assert _on_vpu(d, k)
+        assert not matmul and "tpu_custom_call" in text
+        assert f"[{n},{k}]" not in text
+        _fits(compiled)
+    else:
+        assert not _on_vpu(d, k)
+        assert matmul and "tpu_custom_call" not in text
+
+
 # ------------------------------------------------------------------- canvas
-def test_staged_canvas_refit_compiles_at_the_cells_size(one_chip, session):
+def test_staged_canvas_refit_compiles_at_the_cells_size(one_chip):
     """The benchmark's canvas (scaler -> PCA(4) -> KMeans(10),
     ``stage_graph(refit=True)``) at the cell's 2^27 x 8: the ONE program
     fits the chip beside nothing but its table, and holds none of what
     made it unusable there (PERF.md section 6, PR 35): no copy of the table
     by a column take (4,096 gathers and minutes of compile), no sort of
     all N rows for the seeding's sample, no scatter-add of N values for
-    the cluster sizes, and the row contractions as blocked products."""
+    the cluster sizes, and the row contractions as blocked products. The
+    canvas is built in a one-device session, as the cell's one chip is:
+    KMeans passes each device's rows under the session's mesh."""
     from orange3_spark_tpu.core.domain import ContinuousVariable, Domain
+    from orange3_spark_tpu.core.session import TpuSession
     from orange3_spark_tpu.core.table import TpuTable
     from orange3_spark_tpu.widgets.catalog import WIDGET_REGISTRY, OWTable
     from orange3_spark_tpu.workflow.graph import WorkflowGraph
@@ -479,6 +541,7 @@ def test_staged_canvas_refit_compiles_at_the_cells_size(one_chip, session):
     domain = Domain([ContinuousVariable(f"c{i}") for i in range(8)])
     head = np.random.default_rng(0).standard_normal((1024, 8))
     g = WorkflowGraph()
+    session = TpuSession(Mesh(np.asarray(jax.devices()[:1]), ("data",)))
     src = g.add(OWTable(TpuTable.from_numpy(domain, head, session=session)))
     sc = g.add(WIDGET_REGISTRY["OWStandardScaler"](with_mean=True))
     pca = g.add(WIDGET_REGISTRY["OWPCA"](k=4))
@@ -497,4 +560,6 @@ def test_staged_canvas_refit_compiles_at_the_cells_size(one_chip, session):
     assert text.count(" gather(") < 16, text.count(" gather(")
     assert not re.search(r" sort\([^)]*\[134217728\]", text)
     assert not re.search(r"= \S+\[10\]\S* scatter\(", text)
-    assert "8192,10,4" in text and "8192,8,8" in text   # rows_dot's partials
+    assert "8192,8,8" in text                  # the Gramian's blocked partials
+    # KMeans' iterations are the vector-unit kernel, not rows_dot's products
+    assert "tpu_custom_call" in text and "8192,10,4" not in text
